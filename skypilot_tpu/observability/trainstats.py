@@ -145,24 +145,24 @@ _DUMPS = metrics.counter(
 
 
 def peak_flops_for_device(device: Any) -> float:
-    """Per-chip peak dense FLOP/s for a jax device (0.0 = unknown,
-    e.g. CPU). Matches on ``device_kind`` substrings; 'v5 lite' is
-    v5e, bare 'v5' defaults to v5p."""
+    """Per-chip peak dense FLOP/s for a jax device, by the generation
+    its ``device_kind`` names ('TPU v5 lite' is how JAX reports a v5e).
+    A kind that is not in the table is unknown: 0.0, never a guess —
+    the MFU gauge then reads None and ``bench.py`` treats it as an
+    error."""
     kind = str(getattr(device, "device_kind", device) or "").lower()
+    if "v5 lite" in kind or "v5lite" in kind:
+        return PEAK_FLOPS["v5e"]
     for name, flops in PEAK_FLOPS.items():
         if name in kind:
             return flops
-    if "v5 lite" in kind or "v5lite" in kind:
-        return PEAK_FLOPS["v5e"]
-    if "v5" in kind:
-        return PEAK_FLOPS["v5p"]
     return 0.0
 
 
 def detect_peak_flops() -> float:
     """This process's aggregate peak FLOP/s: per-chip peak x local
     device count. Lazy jax import (configure time, not hot path);
-    0.0 when the platform is unknown (CPU smoke runs → MFU=None)."""
+    0.0 when the device is not in the table (a CPU run → MFU=None)."""
     try:
         import jax
         devs = jax.local_devices()

@@ -30,6 +30,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from skypilot_tpu.ops import attention as attention_ops
+
 _NEG_INF = -1e30
 # Softmax runs in the exp2 domain: log2(e) is folded into the logit
 # scale once, so every per-element transcendental is exp2 (cheaper on
@@ -45,6 +47,13 @@ DEFAULT_BLOCK_K = 1024
 
 LSE_PAD = 8    # trailing tile dim for the lse output (tiling constraint)
 _STAT = 128    # lane width for the (m, l) scratch carries
+
+
+def _interpret() -> bool:
+    """Pallas interpret mode: only where the process runs on the CPU
+    platform (the tests). One function, so a test that compiles the
+    kernels for a described chip steers exactly this."""
+    return jax.default_backend() == "cpu"
 
 
 def _causal_mask(s, q_start, k_start):
@@ -167,7 +176,7 @@ def _flash_fwd_streamed(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=jax.default_backend() == "cpu",
+        interpret=_interpret(),
     )(qt, kt, vt)
     # keep_lse_pad: the (B,H,S,LSE_PAD) layout feeds the bwd kernels
     # directly (already lane-tileable); [..., 0] is the logical value.
@@ -292,7 +301,7 @@ def _flash_bwd_streamed(res, do, *, causal: bool, scale: float,
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     nq, nk = s // block_q, s // block_k
-    interpret = jax.default_backend() == "cpu"
+    interpret = _interpret()
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -534,7 +543,7 @@ def _flash_fwd_tri(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=jax.default_backend() == "cpu",
+        interpret=_interpret(),
     )(jnp.asarray(qmap), jnp.asarray(kmap), qt, kt, vt)
     return out.transpose(0, 2, 1, 3), (lse if keep_lse_pad
                                        else lse[..., 0])
@@ -675,7 +684,7 @@ def _flash_bwd_tri(res, do, *, scale: float, block_q: int,
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     nq, nk = s // block_q, s // block_k
-    interpret = jax.default_backend() == "cpu"
+    interpret = _interpret()
 
     # Same q pre-scale as the tri forward (kills the per-step s*scale
     # pass); the dkv kernel's _finish divides the factor back out of dk.
@@ -755,6 +764,13 @@ def _flash_bwd_tri(res, do, *, scale: float, block_q: int,
 # for short/medium sequences, but the full-sequence staging caps length.
 # The streamed family above keeps O(block) VMEM and scales to 64k+.
 # --------------------------------------------------------------------------
+
+# Scoped-VMEM limit for the resident kernels. At the top of the resident
+# range (S 2048, D 256) the dk/dv kernel stages 16.6 MiB, which the
+# v5e compiler refuses under its 16 MiB default once the batch is 3 or
+# more; the chip has 128 MiB.
+_RESIDENT_VMEM_LIMIT = 32 * 1024 * 1024
+
 
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 scale: float, block_k: int, causal: bool, seq_len: int):
@@ -850,8 +866,9 @@ def _flash_fwd_resident(q: jax.Array, k: jax.Array, v: jax.Array, *,
             jax.ShapeDtypeStruct((b, h, s, LSE_PAD), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=jax.default_backend() == "cpu",
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
+        interpret=_interpret(),
     )(qt, kt, vt)
     # keep_lse_pad: the (B,H,S,LSE_PAD) layout feeds the bwd kernels
     # directly (already lane-tileable); [..., 0] is the logical value.
@@ -978,7 +995,7 @@ def _flash_bwd_resident(res, do, *, causal: bool, scale: float,
     groups = h // kvh
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    interpret = jax.default_backend() == "cpu"
+    interpret = _interpret()
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -1001,7 +1018,8 @@ def _flash_bwd_resident(res, do, *, causal: bool, scale: float,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
         interpret=interpret,
     )(qt, kt, vt, ot, dot_, lse_pad)
 
@@ -1024,7 +1042,8 @@ def _flash_bwd_resident(res, do, *, causal: bool, scale: float,
             jax.ShapeDtypeStruct((b, kvh, s, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
         interpret=interpret,
     )(qt, kt, vt, ot, dot_, lse_pad)
 
@@ -1133,7 +1152,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             block_q % 8 or block_k % 8 or d % 8):
         # Irregular/misaligned shapes: fall back to the XLA reference path
         # (Mosaic requires 8-sublane-aligned blocks).
-        from skypilot_tpu.ops import attention as attention_ops
+        attention_ops.TRACES.labels(impl="reference").inc()
         return attention_ops._reference_attention(q, k, v, causal=causal,
                                                   scale=scale)
+    attention_ops.TRACES.labels(impl="kernel").inc()
     return _flash(q, k, v, causal, scale, block_q, block_k)

@@ -164,6 +164,12 @@ class ShardingRules:
             return None
         return present if len(present) > 1 else present[0]
 
+    def axis_size(self, logical: Optional[str], mesh: Mesh) -> int:
+        """How many ways ``logical`` is split on ``mesh`` (1 = whole)."""
+        axis = self.resolve_axis(logical, mesh)
+        names = (axis,) if isinstance(axis, str) else tuple(axis or ())
+        return math.prod(mesh.shape[a] for a in names)
+
     def spec(self, logical_axes: Sequence[Optional[str]],
              mesh: Mesh) -> P:
         resolved = []
@@ -267,3 +273,25 @@ def tree_shardings(mesh: Mesh, rules: ShardingRules,
         specs_tree,
         is_leaf=lambda s: isinstance(s, tuple) and all(
             a is None or isinstance(a, str) for a in s))
+
+
+def device_info() -> dict:
+    """What this process runs on, as JAX reports it — the statement
+    every result and every server makes for itself, so that a number
+    from a CPU run is never read as the chip's."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def bytes_per_device(tree: Any) -> dict:
+    """Device id -> bytes of ``tree`` resident on that device, from the
+    leaves' addressable shards (a replicated leaf counts on each device
+    that holds a copy). How the serving and training recipes show that
+    a sharded model is spread over its mesh and not held by one chip."""
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            out[shard.device.id] = (out.get(shard.device.id, 0) +
+                                    shard.data.nbytes)
+    return out

@@ -4,7 +4,7 @@ Implements the provision SPI (skypilot_tpu/provision/__init__.py) against
 ``tpu.googleapis.com``. Reference analog:
 sky/provision/gcp/instance_utils.py:1185-1620 (GCPTPUVMInstance — node API
 create/stop/delete, state machine READY/CREATING/..., label filtering) and
-the failover error taxonomy in sky/backends/cloud_vm_ray_backend.py:997-1051
+the failover error classification in sky/backends/cloud_vm_ray_backend.py:997-1051
 (quota → region blocklist, stockout/code 8 → zone blocklist, preempted
 during creation/code 3, insufficient reservation/code 9).
 

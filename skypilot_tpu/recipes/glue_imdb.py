@@ -20,6 +20,7 @@ from flax import linen as nn
 
 from skypilot_tpu.recipes import synthetic_data
 from skypilot_tpu.train import distributed
+from skypilot_tpu.utils import compile_cache
 
 
 class EncoderBlock(nn.Module):
@@ -66,6 +67,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     distributed.initialize_from_env()
+    compile_cache.enable()
     model = TextClassifier()
     tokens, labels = synthetic_data.imdb_like(args.seed, 4096,
                                               seq_len=args.seq_len)

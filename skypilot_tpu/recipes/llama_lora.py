@@ -39,10 +39,12 @@ import optax
 
 from skypilot_tpu.models import llama
 from skypilot_tpu.observability import trainstats
+from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.recipes import synthetic_data
 from skypilot_tpu.train import checkpoint as checkpoint_lib
 from skypilot_tpu.train import distributed, trainer
+from skypilot_tpu.utils import compile_cache
 from skypilot_tpu.utils import fault_injection
 
 
@@ -76,6 +78,22 @@ def merge_params(base: dict, lora: dict) -> dict:
 
 def num_params(tree) -> int:
     return sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))
+
+
+def device_report(base_bytes: dict) -> dict:
+    """What the run used, as JAX reports it: the device, and per local
+    device the base parameters' bytes resident there (``base_bytes``,
+    from their shards) and the allocator's peak (None where the
+    backend keeps none, e.g. the CPU). Host values only."""
+    devices = jax.local_devices()
+    return {
+        "device": mesh_lib.device_info(),
+        "base_bytes_per_device": [base_bytes.get(d.id, 0)
+                                  for d in devices],
+        "peak_bytes_per_device": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices],
+    }
 
 
 def build_arg_parser(model_choices, default_model) -> argparse.ArgumentParser:
@@ -121,14 +139,18 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     its module + config here)."""
     setup_t0 = time.perf_counter()
     ctx = distributed.initialize_from_env()
+    compile_cache.enable()
     if args.seq_len > cfg.max_seq_len:
         raise SystemExit(f"--seq-len {args.seq_len} exceeds model max "
                          f"{cfg.max_seq_len}")
 
     mesh = mesh_lib.make_mesh({"fsdp": -1})
     rules = mesh_lib.DEFAULT_RULES
+    device = mesh_lib.device_info()
     print(f"{recipe_name}: model={args.model} "  # noqa: stpu-host-sync startup banner of host ints, before the loop
-          f"devices={jax.device_count()} "
+          f"platform={device['platform']} "
+          f"device_kind={device['kind']!r} "
+          f"devices={device['count']} "
           f"rank={ctx.rank}/{ctx.num_nodes}", flush=True)
 
     # Base params: sharded by the rule table (fsdp over embed axes); the
@@ -138,6 +160,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     base = jax.jit(lambda k: model_lib.init(cfg, k),
                    out_shardings=base_shardings)(
                        jax.random.PRNGKey(args.seed))
+    base_bytes = mesh_lib.bytes_per_device(base)
     lora = init_lora(cfg, args.lora_rank, jax.random.PRNGKey(args.seed + 1))
     tx = optax.adamw(args.lr)
     opt_state = tx.init(lora)
@@ -219,6 +242,14 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     t0 = time.time()
     loss = None
     losses = []
+    first_loss_s = None
+
+    def record_loss(value: float) -> None:
+        nonlocal first_loss_s
+        if not losses:
+            first_loss_s = time.perf_counter() - setup_t0
+        losses.append(value)
+
     # One-step-delayed loss fetch: each iteration fetches the PREVIOUS
     # step's loss (already resident by then) so logging never syncs
     # the hot loop — float(loss) here would stall every step.
@@ -250,7 +281,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
                 if prev is not None:
                     host_loss = jax.device_get(prev)
                     fetched = float(host_loss)
-                    losses.append(fetched)
+                    record_loss(fetched)
                 device_s = None
                 if trainstats.ENABLED and trainstats.sync_due():
                     device_s = trainstats.sampled_sync(loss)
@@ -297,7 +328,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
             final = delayed.drain()
             if final is not None:
                 host_loss = jax.device_get(final)
-                losses.append(float(host_loss))
+                record_loss(float(host_loss))
     except (Exception, KeyboardInterrupt) as e:
         if trainstats.ENABLED:
             trainstats.dump_flight("train_crash", error=repr(e))
@@ -324,6 +355,15 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
         "final_loss": losses[-1] if losses else None,
         "tokens_per_second": round(tokens_seen / wall, 1) if wall else 0,
         "wall_seconds": round(wall, 2),
+        # Start of run_lora to the first loss on the host: parameter
+        # init, the step's compile and one step.
+        "start_to_first_loss_seconds": (
+            round(first_loss_s, 2) if first_loss_s is not None else None),
+        # Which attention implementation the step was traced into
+        # (ops/attention.py TRACES): a shape that fell back to the
+        # O(S^2) reference shows here, not only in the step time.
+        "attention_traces": attention_ops.trace_counts(),
+        **device_report(base_bytes),
     }
     if trainstats.ENABLED:
         snap = trainstats.snapshot()
@@ -332,7 +372,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
         metrics["train_step_seconds"] = snap["step_seconds_mean"]
         metrics["train_tokens_per_sec"] = snap["tokens_per_sec"]
         trainstats.flush()
-    print(json.dumps(metrics), flush=True)
+    print(json.dumps(metrics), flush=True)  # noqa: stpu-host-sync end-of-run report of host values, after the loop
     return metrics
 
 

@@ -21,6 +21,7 @@ from skypilot_tpu.observability import trainstats
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.recipes import synthetic_data
 from skypilot_tpu.train import distributed, trainer
+from skypilot_tpu.utils import compile_cache
 
 
 def main(argv=None) -> dict:
@@ -39,6 +40,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     ctx = distributed.initialize_from_env()
+    compile_cache.enable()
     cfg = (mixtral.MixtralConfig.mixtral_8x7b() if args.model == "8x7b"
            else mixtral.MixtralConfig.tiny())
 

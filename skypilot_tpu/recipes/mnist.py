@@ -22,6 +22,7 @@ from flax import linen as nn
 from skypilot_tpu import callbacks as sky_callback
 from skypilot_tpu.recipes import synthetic_data
 from skypilot_tpu.train import distributed
+from skypilot_tpu.utils import compile_cache
 
 
 class CNN(nn.Module):
@@ -48,6 +49,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     ctx = distributed.initialize_from_env()
+    compile_cache.enable()
     print(f"mnist: devices={jax.devices()} rank={ctx.rank}/"
           f"{ctx.num_nodes}", flush=True)
 

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from skypilot_tpu.observability import trainstats
 from skypilot_tpu.recipes import synthetic_data
 from skypilot_tpu.train import distributed
+from skypilot_tpu.utils import compile_cache
 
 
 class ResNetBlock(nn.Module):
@@ -101,6 +102,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     ctx = distributed.initialize_from_env()
+    compile_cache.enable()
     if args.tiny:
         model = ResNet(stage_sizes=(1, 1), width=8, n_classes=10)
         args.image_size = 32

@@ -34,6 +34,7 @@ import subprocess
 import sys
 import threading
 import time
+import traceback
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import jax
@@ -45,10 +46,12 @@ from skypilot_tpu.observability import metrics
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
 from skypilot_tpu.observability import tracing
+from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.serve import decode_engine
 from skypilot_tpu.serve import gang_replica
 from skypilot_tpu.serve import load_balancing_policies
 from skypilot_tpu.train import distributed
+from skypilot_tpu.utils import compile_cache
 from skypilot_tpu.utils import fault_injection
 
 
@@ -149,6 +152,20 @@ def _ceil_to(n: int, b: int) -> int:
     return ((n + b - 1) // b) * b
 
 
+def _device_memory(param_bytes: dict, engine) -> list:
+    kv_bytes = engine.cache_bytes_per_device() if engine else {}
+    rows = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        rows.append({"id": d.id,
+                     "param_bytes": param_bytes.get(d.id, 0),
+                     "kv_bytes": kv_bytes.get(d.id, 0),
+                     "bytes_in_use": stats.get("bytes_in_use"),
+                     "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                     "bytes_limit": stats.get("bytes_limit")})
+    return rows
+
+
 def _pick(logits_row: jax.Array, temperature: float,
           key: jax.Array) -> jax.Array:
     if temperature > 0.0:
@@ -240,7 +257,13 @@ class _Handler(BaseHTTPRequestHandler):
             ready = ctx["ready"].is_set()
             engine = ctx.get("engine")
             gang = ctx.get("gang")
-            if not ready:
+            if ctx["warmup_error"]:
+                # Terminal: the warm-up raised (the compiler refused a
+                # program, the gang never formed). Never "warming" for
+                # ever — probes and the smoke read the cause here.
+                self._json(500, {"status": "warmup_failed",
+                                 "error": ctx["warmup_error"]})
+            elif not ready:
                 self._json(503, {"status": "warming"})
             elif gang is not None and not gang.healthy():
                 # Gang replicas probe as ONE unit: host 0's /health
@@ -255,7 +278,7 @@ class _Handler(BaseHTTPRequestHandler):
                 # blackholes its share of traffic.
                 self._json(503, {"status": "engine_down"})
             else:
-                payload = {"status": "ok"}
+                payload = {"status": "ok", "device": ctx["device"]}
                 notice = ctx.get("preempt_notice")
                 if notice is not None and notice.is_set():
                     # Preemption notice observed: the replica is still
@@ -301,6 +324,12 @@ class _Handler(BaseHTTPRequestHandler):
     def _perf_payload(self) -> dict:
         ctx = self.server_ctx
         doc = stepstats.snapshot()
+        # What the replica runs on, as JAX reports it, and where its
+        # bytes are: per device, the parameter and KV bytes resident
+        # there (from the arrays' shards) and the allocator's own
+        # counters (None where the backend keeps none, e.g. the CPU).
+        doc["device"] = dict(ctx["device"], memory=_device_memory(
+            ctx["param_bytes"], ctx.get("engine")))
         engine = ctx.get("engine")
         if engine is not None:
             doc["engine"] = {
@@ -815,6 +844,8 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
            "ready": ready_event or threading.Event(), "engine": None,
            "stream_timeout": float(stream_timeout),
            "draining": threading.Event(), "gang": gang,
+           "warmup_error": None, "device": mesh_lib.device_info(),
+           "param_bytes": mesh_lib.bytes_per_device(params),
            "gang_admit_lock": threading.Lock(),
            "preempt_notice": threading.Event(),
            "inflight": [0], "inflight_lock": threading.Lock()}
@@ -854,16 +885,25 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
     httpd.gang = gang
 
     def warmup():
-        if gang is not None and not gang.wait_ready():
-            # Probes keep seeing "warming" → the replica manager's
-            # initial-delay deadline replaces the half-formed gang.
+        try:
+            if gang is not None and not gang.wait_ready():
+                raise gang_replica.GangError(
+                    f"the serving gang of {gang.topology.hosts} hosts "
+                    f"did not form: {gang.members_info()}")
+            if ctx["engine"] is not None:
+                ctx["engine"].warmup()
+            else:
+                buf = jnp.zeros((PROMPT_BUCKET,), jnp.int32)
+                _decode_locked(ctx, buf, 8, GEN_BUCKET, 0.0,
+                               0).block_until_ready()
+        except Exception as e:  # noqa: BLE001 — thread boundary: a
+            # warm-up that dies in silence leaves /health "warming"
+            # for ever; record the cause where probes read it.
+            traceback.print_exc()
+            print(f"serve_llm: warm-up failed: {type(e).__name__}: {e}",
+                  file=sys.stderr, flush=True)
+            ctx["warmup_error"] = f"{type(e).__name__}: {e}"
             return
-        if ctx["engine"] is not None:
-            ctx["engine"].warmup()
-        else:
-            buf = jnp.zeros((PROMPT_BUCKET,), jnp.int32)
-            _decode_locked(ctx, buf, 8, GEN_BUCKET, 0.0,
-                           0).block_until_ready()
         ctx["ready"].set()
 
     threading.Thread(target=warmup, daemon=True).start()
@@ -919,7 +959,8 @@ def _resolve_topology(args) -> "gang_replica.ReplicaTopology":
             or gang_replica.ReplicaTopology())
 
 
-def _build_model(args):
+def model_config(model: str, dtype: str = None):
+    """The config a ``--model`` name (and ``--dtype`` override) selects."""
     cfg = {
         "tiny": llama.LlamaConfig.tiny,
         "8b": llama.LlamaConfig.llama3_8b,
@@ -928,13 +969,26 @@ def _build_model(args):
         "gemma-tiny": gemma.GemmaConfig.tiny,
         "gemma-2b": gemma.GemmaConfig.gemma_2b,
         "gemma-7b": gemma.GemmaConfig.gemma_7b,
-    }[args.model]()
-    if args.dtype:
+    }[model]()
+    if dtype:
         cfg = dataclasses.replace(
             cfg, dtype={"bfloat16": jnp.bfloat16,
-                        "float32": jnp.float32}[args.dtype])
-    params = model_api(cfg).init(cfg, jax.random.PRNGKey(args.seed))
-    return cfg, params
+                        "float32": jnp.float32}[dtype])
+    return cfg
+
+
+def init_params(cfg, seed: int, mesh=None, rules=None):
+    """Random parameters, created where they will live: under a mesh
+    each leaf is generated directly into its sharding, so a model
+    larger than one chip (gemma-7b over tp=4) never materialises on
+    one. The values do not depend on the sharding."""
+    api = model_api(cfg)
+    shardings = None
+    if mesh is not None:
+        shardings = mesh_lib.tree_shardings(mesh, rules,
+                                            api.param_specs(cfg))
+    return jax.jit(functools.partial(api.init, cfg),
+                   out_shardings=shardings)(jax.random.PRNGKey(seed))
 
 
 def _spawn_follower_cmd(args, rank: int, topology, leader_port: int):
@@ -990,7 +1044,12 @@ def main(argv=None):
                         "env STPU_REPLICA_TOPOLOGY or 1). Outside a "
                         "gang launch, host 0 self-spawns the follower "
                         "processes — the single-machine dev analog of "
-                        "a gang-scheduled slice")
+                        "a gang-scheduled slice. Every process of a "
+                        "gang needs devices of its own: on one host "
+                        "that works on the CPU platform only, since a "
+                        "host's TPU chips belong to the first process "
+                        "that takes them; a gang that cannot form "
+                        "turns /health into warmup_failed")
     p.add_argument("--tp", type=int, default=None,
                    help="tensor-parallel degree over the replica's "
                         "devices (params + KV cache sharded via "
@@ -1091,10 +1150,14 @@ def main(argv=None):
     # Bring up jax.distributed from the gang env contract (federates
     # every host's chips on a real slice; non-fatal no-op elsewhere).
     distributed.initialize_from_env()
-    cfg, params = _build_model(args)
+    compile_cache.enable()
+    device = mesh_lib.device_info()
+    print(f"serve_llm: model={args.model} platform={device['platform']} "
+          f"device_kind={device['kind']!r} devices={device['count']} "
+          f"topology={topology.label()}", flush=True)
+    cfg = model_config(args.model, args.dtype)
     mesh, rules = gang_replica.build_mesh(topology)
-    if mesh is not None:
-        params = gang_replica.shard_params(cfg, params, mesh, rules)
+    params = init_params(cfg, args.seed, mesh, rules)
 
     kv = _resolve_kv(args)
     # The handshake compares EFFECTIVE geometry (auto-sized pool

@@ -235,15 +235,8 @@ def cache_shardings(cfg, mesh, rules):
     specs.setdefault("k_scale", ("layers", None, "kv_heads"))
     specs.setdefault("v_scale", ("layers", None, "kv_heads"))
 
-    def axis_size(logical: str) -> int:
-        axis = rules.resolve_axis(logical, mesh)
-        if axis is None:
-            return 1
-        names = (axis,) if isinstance(axis, str) else axis
-        return int(math.prod(mesh.shape[a] for a in names))
-
     def fix(spec: tuple):
-        tp = axis_size("kv_heads")
+        tp = rules.axis_size("kv_heads", mesh)
         if "kv_heads" not in spec or cfg.n_kv_heads % tp == 0:
             return rules.sharding(spec, mesh)
         resolved = [None] * len(spec)

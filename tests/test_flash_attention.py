@@ -129,3 +129,48 @@ def test_tri_family_unequal_blocks_kq(monkeypatch):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=5e-3)
+
+
+def test_irregular_shape_fallback_is_counted():
+    """The shape fallback stays a correctness path but must be visible:
+    the trace counter says which implementation a call compiled to."""
+    before = attention_ops.trace_counts()
+    # Shapes no other test uses, so each call is a fresh trace.
+    q, k, v = _make_qkv(jax.random.key(4), s=104)   # 8-aligned: kernel
+    fa.flash_attention(q, k, v, causal=True)
+    q, k, v = _make_qkv(jax.random.key(4), s=136)   # 8-aligned: kernel
+    fa.flash_attention(q, k, v, causal=True)
+    q, k, v = _make_qkv(jax.random.key(4), s=100)   # not 8-aligned
+    fa.flash_attention(q, k, v, causal=True)
+    after = attention_ops.trace_counts()
+    assert after["kernel"] - before["kernel"] == 2
+    assert after["reference"] - before["reference"] == 1
+
+
+@pytest.mark.parametrize("axes,h,kvh", [
+    ({"fsdp": 2, "tp": 2}, 4, 2),    # batch and heads both split
+    ({"fsdp": 4}, 4, 1),             # the LoRA recipe's mesh, MQA
+    ({"dp": 1, "tp": 4}, 4, 1),      # MQA: one KV head shared by shards
+    ({"dp": 1, "tp": 4}, 8, 2),      # ratio breaks groups: heads whole
+])
+def test_kernel_under_mesh_matches_reference(axes, h, kvh):
+    """A Mosaic kernel is not partitioned by the compiler: under an
+    ambient mesh attention() wraps it in a shard_map over the batch and
+    heads axes, forward and backward."""
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.make_mesh(axes, devices=jax.devices()[:4])
+    rules = mesh_lib.DEFAULT_RULES
+    q, k, v = _make_qkv(jax.random.key(5), b=4, s=128, h=h, kvh=kvh)
+
+    def loss(impl):
+        def f(q, k, v):
+            with mesh_lib.use_mesh(mesh, rules):
+                return jnp.sum(attention_ops.attention(
+                    q, k, v, causal=True, impl=impl) ** 2)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    (lk, gk), (lr, gr) = loss("pallas")(q, k, v), loss("reference")(q, k, v)
+    np.testing.assert_allclose(float(lk), float(lr), rtol=2e-3)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-3)

@@ -140,8 +140,10 @@ def test_peak_flops_for_device():
         _Dev("TPU v5e")) == trainstats.PEAK_FLOPS["v5e"]
     assert trainstats.peak_flops_for_device(
         _Dev("TPU v5 lite")) == trainstats.PEAK_FLOPS["v5e"]
+    # A bare "v5" names no generation in the table: unknown, no guess.
+    assert trainstats.peak_flops_for_device(_Dev("TPU v5")) == 0.0
     assert trainstats.peak_flops_for_device(
-        _Dev("TPU v5")) == trainstats.PEAK_FLOPS["v5p"]
+        _Dev("TPU v5p")) == trainstats.PEAK_FLOPS["v5p"]
     assert trainstats.peak_flops_for_device(_Dev("TPU v4")) == \
         trainstats.PEAK_FLOPS["v4"]
     assert trainstats.peak_flops_for_device(_Dev("cpu")) == 0.0

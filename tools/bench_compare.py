@@ -50,7 +50,6 @@ DEFAULT_METRICS = (
     # default through this same leg, so a drop here means the manifest
     # went stale for the device this round ran on.
     "detail.serving.*_engine_tuned_tok_s",
-    "detail.serving.*_engine_tp_tok_s",
     "detail.serving.*_engine_prefix_tok_s",
     "detail.serving.*_prefix_hit_rate",
     # Host-RAM KV spill tier: decode throughput with spill/re-admit
