@@ -19,6 +19,14 @@ head, head 256, MLP 16384, vocab 256,000; random weights from a seed):
              own greedy tokens leave the engine's only at a position
              under that margin. (In bf16 a near-tie's argmax depends on
              the prefill tiling, so identity is not asked below it.)
+             A random tied-embedding model echoes its last prompt token
+             whatever its cache holds, so the same child also drives the
+             recipe's engine itself (``serve_llm.serve()``: paged pool,
+             batched, the repeated prompt from the prefix cache) and
+             holds every row of logits it samples from to the
+             reference's within LOGIT_BOUND of the row's range — with a
+             control, the same tokens over another request's cache,
+             that has to show twice that.
   train      ``python -m skypilot_tpu.recipes.gemma_lora --model 2b`` at
              seq 2048: the Pallas flash kernels forward and backward,
              loss finite and falling, one checkpoint written and read
@@ -46,7 +54,8 @@ that no chip holds the whole model.
 ``--tiny`` rehearses the same control flow at tiny size where there is
 no chip (``JAX_PLATFORMS=cpu``, and for ``--chips 4`` also
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``). The phases run
-and are checked; the verdict is still "not ok", because it is not a TPU.
+and are checked; the verdict is "not ok" whatever it runs on, because
+a tiny run says nothing about the published widths.
 """
 from __future__ import annotations
 
@@ -82,6 +91,15 @@ MARGIN = 1.0
 # The comparison must not be vacuous: at least this many positions,
 # over all requests, have to clear the margin.
 MIN_COMPARED = 16
+# Engine logits against the reference's along the same tokens: the
+# largest difference over the vocabulary in any row, as a share of that
+# row's logit range (max - min). The two compute the same function in
+# bf16 with different tilings (64-token paged chunks and one-token
+# steps against one 1,024-token pass), so they differ by rounding; a
+# crossed slot, a wrong block table or a broken prefix restore differs
+# by the context. The control (the same tokens over another request's
+# cache) has to show at least twice the bound, or the rule is blind.
+LOGIT_BOUND = 0.03
 # Sharded against one-device first-step loss, relative.
 LOSS_RTOL = 1e-2
 # Steps until "the loss fell" is not a coin toss. At the recipe's
@@ -438,9 +456,10 @@ def check_train(m: dict, need_tpu: bool) -> None:
     if not final < first:
         raise Failed(f"train: loss did not fall: {first} -> {final}")
     traces = m["attention_traces"]
-    if need_tpu and (traces["kernel"] < 1 or traces["reference"]):
+    if need_tpu and (traces["kernel"] < 1 or traces["reference"] or
+                     traces["kernel_replicated"]):
         raise Failed(f"train: the step was not traced into the flash "
-                     f"kernel alone: {traces}")
+                     f"kernel alone, split over the mesh: {traces}")
     say(f"train: loss {first:.4f} -> {final:.4f} over {m['steps']} "
         f"steps, {m['tokens_per_second']} tok/s by the recipe's own "
         f"clock, first loss {m['start_to_first_loss_seconds']} s after "
@@ -480,9 +499,83 @@ def train_phase(out: pathlib.Path, deadline: float, need_tpu: bool,
 
 
 # ----------------------------------------------- children that touch JAX
+def engine_logits(cfg, params, mesh, rules, topology, reqs) -> dict:
+    """Drive the recipe's own engine in this process — ``serve()``
+    with its defaults, so the server's geometry: paged pool, prefix
+    trie — and keep every row of logits it samples from.
+
+    The engine hands out tokens only. ``decode_engine._sample`` is the
+    one place where both the first token (host side, after the last
+    prefill chunk) and every decode step (inside the jitted step) turn
+    logits into a token, and it is called with the request's seed and
+    the token's absolute position; so a tap there, keyed by distinct
+    seeds, names each row whatever slot the request landed in."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+
+    from skypilot_tpu.recipes import serve_llm
+    from skypilot_tpu.serve import decode_engine
+
+    seeds = {1001 + i: r for i, r in enumerate(reqs)}
+    rows: dict = {}
+
+    def keep(logits, seed, position):
+        for row, sd, pos in zip(np.asarray(logits), np.asarray(seed),
+                                np.asarray(position)):
+            if int(sd) in seeds:
+                rows[(int(sd), int(pos))] = np.array(row, np.float32)
+
+    sample = decode_engine._sample
+
+    def tapped(logits, seed, position, temps):
+        jax.debug.callback(keep, logits, seed, position)
+        return sample(logits, seed, position, temps)
+
+    ready = threading.Event()
+    with mock.patch.object(decode_engine, "_sample", tapped):
+        httpd = serve_llm.serve(cfg, params, 0, ready_event=ready,
+                                topology=topology, mesh=mesh, rules=rules)
+        try:
+            ctx = httpd.RequestHandlerClass.server_ctx
+            limit = time.monotonic() + READY_SECONDS
+            while not ready.wait(0.5):
+                if ctx["warmup_error"] or time.monotonic() > limit:
+                    raise RuntimeError(
+                        f"the in-process engine did not warm up: "
+                        f"{ctx['warmup_error'] or 'deadline'}")
+            engine = httpd.engine
+            handles = [engine.submit(r["prompt"], NEW_TOKENS, seed=sd)
+                       for sd, r in list(seeds.items())[:-1]]
+            tokens = [h.result(timeout=REQUEST_SECONDS) for h in handles]
+            # The repeated prompt, once the first copy has finished.
+            again = engine.submit(reqs[-1]["prompt"], NEW_TOKENS,
+                                  seed=max(seeds))
+            tokens.append(again.result(timeout=REQUEST_SECONDS))
+            jax.effects_barrier()
+            paged = bool(engine.kv_config()["paged"])
+        finally:
+            httpd.engine.shutdown()
+            httpd.server_close()
+    vocab = len(next(iter(rows.values())))
+    logits = np.zeros((len(reqs), NEW_TOKENS, vocab), np.float32)
+    for i, (sd, r) in enumerate(seeds.items()):
+        for j in range(NEW_TOKENS):
+            logits[i, j] = rows.pop((sd, len(r["prompt"]) + j))
+    if rows:
+        raise RuntimeError(f"the engine sampled at positions nobody "
+                           f"asked for: {sorted(rows)[:8]}")
+    return {"tokens": np.asarray(tokens, np.int32), "logits": logits,
+            "paged": paged,
+            "prefix_cached_tokens": again.cached_prompt_tokens}
+
+
 def child_serve_ref(args) -> int:
     """The plain reference for the serve phase's requests, on the same
-    parameters (same seed, same sharding when ``--tp`` > 1)."""
+    parameters (same seed, same sharding when ``--tp`` > 1): the token
+    rule for what came back over HTTP, and the logits rule for the
+    engine driven in this process."""
     t0 = time.monotonic()
     import jax
     import jax.numpy as jnp
@@ -510,10 +603,10 @@ def child_serve_ref(args) -> int:
     for i, r in enumerate(reqs):
         prompts[i, :len(r["prompt"])] = r["prompt"]
     true_len = np.asarray([len(r["prompt"]) for r in reqs], np.int32)
-    engine = np.asarray([r["tokens"] for r in reqs], np.int32)
+    served = np.asarray([r["tokens"] for r in reqs], np.int32)
 
     @jax.jit
-    def reference(params, prompts, true_len, engine):
+    def reference(params, prompts, true_len, tokens):
         # 1. The reference's own greedy continuation.
         own = api.decode(cfg, params, prompts, true_len, NEW_TOKENS,
                          max_seq)
@@ -524,42 +617,99 @@ def child_serve_ref(args) -> int:
             cfg, params, prompts, cache, jnp.int32(0),
             valid_len=true_len, logits_at=true_len - 1)
         rest, _ = api.forward_with_cache(
-            cfg, params, engine, cache, true_len,
+            cfg, params, tokens, cache, true_len,
             valid_len=true_len + NEW_TOKENS)
-        logits = jnp.concatenate([first, rest[:, :-1]], axis=1)
-        top2 = jax.lax.top_k(logits, 2)[0]
-        return (own, jnp.argmax(logits, axis=-1),
-                top2[..., 0] - top2[..., 1])
+        # 3. The control: the same continuation over the NEXT request's
+        # prompt cache and positions — what a crossed slot or a wrong
+        # block table would compute. Rows 1.. only: they feed the same
+        # tokens and differ by the cache alone (row 0 would be another
+        # prompt's last token, which any rule tells apart).
+        crossed_len = jnp.roll(true_len, -1)
+        crossed, _ = api.forward_with_cache(
+            cfg, params, tokens,
+            jax.tree.map(lambda a: jnp.roll(a, -1, axis=1), cache),
+            crossed_len, valid_len=crossed_len + NEW_TOKENS)
+        return (own, jnp.concatenate([first, rest[:, :-1]], axis=1),
+                crossed[:, :-1])
 
-    own, top1, margin = jax.device_get(
-        reference(params, prompts, true_len, engine))
-    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    def distance(a, ref):
+        """Per row: the largest logit difference over the vocabulary,
+        relative to the reference row's range (max - min)."""
+        return (np.abs(a - ref).max(-1) /
+                (ref.max(-1) - ref.min(-1)))
+
+    # ---- tokens: what the server returned over HTTP.
+    own, logits, crossed = jax.device_get(
+        reference(params, prompts, true_len, served))
+    top2 = np.partition(logits, -2, axis=-1)[..., -2:]
+    top1, margin = logits.argmax(-1), top2[..., 1] - top2[..., 0]
     gated = margin > MARGIN
-    wrong = gated & (top1 != engine)
+    wrong = gated & (top1 != served)
     diverged_over_margin = []
     for i, r in enumerate(reqs):
-        differs = np.nonzero(own[i] != engine[i])[0]
+        differs = np.nonzero(own[i] != served[i])[0]
         if differs.size and margin[i, differs[0]] > MARGIN:
             diverged_over_margin.append(
                 {"request": r["name"], "position": int(differs[0]),
                  "margin": float(margin[i, differs[0]])})
+
+    # ---- logits: the engine driven here, on its own tokens.
+    eng = engine_logits(cfg, params, mesh, rules, topology, reqs)
+    same_tokens = bool((eng["tokens"] == served).all())
+    if not same_tokens:
+        _, logits, crossed = jax.device_get(
+            reference(params, prompts, true_len, eng["tokens"]))
+    error = distance(eng["logits"], logits)
+    control = distance(eng["logits"][:, 1:], crossed)
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    failures = [what for failed, what in [
+        (wrong.any(), "a served token is not the reference's top-1 "
+                      "at a position over the margin"),
+        (diverged_over_margin, "decode() leaves the served tokens at "
+                               "a position over the margin"),
+        (gated.sum() < MIN_COMPARED,
+         f"fewer than {MIN_COMPARED} positions clear the margin"),
+        (not eng["paged"], "the engine driven here is not paged"),
+        (eng["prefix_cached_tokens"] <= 0,
+         "the repeated prompt was not served from the prefix cache"),
+        (error.max() > LOGIT_BOUND,
+         f"the engine's logits leave the reference's by more than "
+         f"{LOGIT_BOUND:g} of the logit range"),
+        (np.median(control, axis=1).min() < 2 * LOGIT_BOUND,
+         f"the control is blind: over another request's cache half a "
+         f"request's rows stay within {2 * LOGIT_BOUND:g} of the "
+         f"logit range"),
+    ] if failed]
     result = {
-        "ok": bool(not wrong.any() and not diverged_over_margin and
-                   int(gated.sum()) >= MIN_COMPARED),
-        "positions": int(engine.size),
+        "ok": not failures,
+        "failures": failures,
+        "positions": int(served.size),
         "compared": int(gated.sum()),
-        "agree_where_compared": int((gated & (top1 == engine)).sum()),
-        "agree_anywhere": int((top1 == engine).sum()),
+        "agree_where_compared": int((gated & (top1 == served)).sum()),
+        "agree_anywhere": int((top1 == served).sum()),
         "decode_identical_requests": int(
-            (own == engine).all(axis=1).sum()),
+            (own == served).all(axis=1).sum()),
         "margin_min": float(margin.min()),
         "margin_median": float(np.median(margin)),
         "wrong": [{"request": reqs[i]["name"], "position": int(j),
                    "margin": float(margin[i, j]),
-                   "engine": int(engine[i, j]),
+                   "engine": int(served[i, j]),
                    "reference": int(top1[i, j])}
                   for i, j in zip(*np.nonzero(wrong))][:8],
         "decode_diverged_over_margin": diverged_over_margin,
+        "engine_paged": eng["paged"],
+        "engine_prefix_cached_tokens": eng["prefix_cached_tokens"],
+        "engine_tokens_as_served": same_tokens,
+        "logit_rows": int(error.size),
+        "logit_range_median": float(np.median(
+            logits.max(-1) - logits.min(-1))),
+        "logit_error_max": float(error.max()),
+        "logit_error_median": float(np.median(error)),
+        "logit_error_max_per_request": [float(e)
+                                        for e in error.max(axis=1)],
+        "control_median_per_request": [
+            float(c) for c in np.median(control, axis=1)],
+        "control_min": float(control.min()),
         "device": mesh_lib.device_info(),
         "peak_bytes_per_device": [s.get("peak_bytes_in_use")
                                   for s in stats],
@@ -613,11 +763,28 @@ def ref_phase(model: str, tp: int, serve: dict, out: pathlib.Path,
         f"for {r['decode_identical_requests']} requests; allocator "
         f"peak {[gib(p) for p in r['peak_bytes_per_device']]}; "
         f"{r['seconds']} s")
+    say(f"serve-ref: engine driven in the child (paged "
+        f"{r['engine_paged']}, {r['engine_prefix_cached_tokens']} "
+        f"prompt tokens of the repeated request from the prefix cache, "
+        f"tokens as served {r['engine_tokens_as_served']}): "
+        f"{r['logit_rows']} rows of logits leave the reference's by at "
+        f"most {r['logit_error_max']:.5f} of the row's range (median "
+        f"{r['logit_error_median']:.5f}, median range "
+        f"{r['logit_range_median']:.2f} logits; bound {LOGIT_BOUND:g}); "
+        f"the control — the same tokens over another request's cache "
+        f"— leaves it by {min(r['control_median_per_request']):.3f} at "
+        f"the median row of the request it shows least in (its least "
+        f"row {r['control_min']:.3f}; it has to show "
+        f"{2 * LOGIT_BOUND:g})")
     if not r["ok"]:
         raise Failed(f"serve-ref: the engine left the reference: "
+                     f"{'; '.join(r['failures'])}. "
                      f"wrong={r['wrong']} "
                      f"decode={r['decode_diverged_over_margin']} "
-                     f"compared={r['compared']} (need {MIN_COMPARED})")
+                     f"compared={r['compared']} "
+                     f"error per request={r['logit_error_max_per_request']} "
+                     f"control per request="
+                     f"{r['control_median_per_request']}")
     return {"device": r["device"]}
 
 
@@ -644,6 +811,17 @@ def train4_phase(out: pathlib.Path, deadline: float, need_tpu: bool,
     return {"device": sharded["device"]}
 
 
+def verdict_refused(tiny: bool, device: dict) -> str:
+    """Why phases that all passed still earn no "ok" line ('' = they
+    do): the verdict belongs to a full-width run on a TPU."""
+    if tiny:
+        return (f"a --tiny rehearsal on {device} says nothing about "
+                f"the published widths")
+    if device["platform"] != "tpu":
+        return f"the phases ran on {device}, not on a TPU"
+    return ""
+
+
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--chips", type=int, choices=(1, 4), default=1,
@@ -651,7 +829,7 @@ def main() -> int:
                         "what they are compared with")
     p.add_argument("--tiny", action="store_true",
                    help="rehearse the control flow at tiny size (never "
-                        "ok: not a TPU run)")
+                        "ok, whatever it runs on)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--out", type=pathlib.Path,
                    default=REPO / "chip_smoke_out",
@@ -709,9 +887,10 @@ def main() -> int:
     finally:
         stop_all()
     say(f"all phases passed in {time.monotonic() - t0:.0f} s")
-    if devices[0]["platform"] != "tpu":
-        print(f"chip_smoke: not ok: the phases ran on "
-              f"{devices[0]}, not on a TPU", file=sys.stderr, flush=True)
+    refused = verdict_refused(args.tiny, devices[0])
+    if refused:
+        print(f"chip_smoke: not ok: {refused}", file=sys.stderr,
+              flush=True)
         return 1
     print(json.dumps({"ok": True, "device": devices[0]}), flush=True)
     return 0

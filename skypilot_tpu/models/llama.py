@@ -19,8 +19,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from skypilot_tpu.ops import attention as attention_ops
-
 Params = Dict[str, Any]
 
 
@@ -325,8 +323,9 @@ def attention_block(cfg, x: jax.Array, lp: Params, positions: jax.Array,
         from skypilot_tpu.parallel import ring_attention
         attn = ring_attention.ring_attention_from_context(q, kk, vv)
     else:
-        attn = attention_ops.attention(q, kk, vv, causal=True,
-                                       impl=cfg.attention_impl)
+        from skypilot_tpu.parallel import mesh_attention
+        attn = mesh_attention.attention_from_context(
+            q, kk, vv, causal=True, impl=cfg.attention_impl)
     attn = attn.reshape(b, s, h * hd)
     return x + constrain(lora_dense(attn, lp, "wo"),
                          ("batch", "act_seq", "act_embed"))
@@ -1277,7 +1276,10 @@ def forward_pipelined(cfg: LlamaConfig, params: Params, tokens: jax.Array,
         out, _ = jax.lax.scan(layer_fn, x_in, lp)
         return out
 
-    x = pipeline_lib.gpipe(stage_fn, stage_params, x_mb, pos_mb,
-                           mesh=mesh, num_microbatches=m)
+    # Ambient for the ops that need the mesh inside a stage (the
+    # attention kernel's shard_map), whoever calls this.
+    with mesh_lib.use_mesh(mesh, rules):
+        x = pipeline_lib.gpipe(stage_fn, stage_params, x_mb, pos_mb,
+                               mesh=mesh, num_microbatches=m)
     x = x.reshape(b, s, d)
     return lm_head(cfg, params, x, constrain)

@@ -12,27 +12,31 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from skypilot_tpu.observability import metrics
-from skypilot_tpu.parallel import mesh as mesh_lib
 
 _NEG_INF = -1e30
 
-# Which implementation each attention call site was traced into. The
-# shape fallback in flash_attention is a correctness path; this is what
-# keeps it visible (chip_smoke's train phase and the tests read it). A
-# trace served from jit's cache is not counted again.
+# Which implementation each attention call site was traced into. Two
+# fallbacks cost speed and not correctness; this is what keeps them
+# visible (chip_smoke's train phase and the tests read it): the shape
+# fallback in flash_attention ('reference'), and a kernel under a mesh
+# one of whose axes does not divide the batch or the heads, so that
+# every device of that axis computes the whole dimension
+# ('kernel_replicated', counted beside 'kernel' by
+# parallel/mesh_attention.py). A trace served from jit's cache is not
+# counted again.
 TRACES = metrics.counter(
     "stpu_attention_traces_total",
     "Attention call sites traced, by the implementation compiled in: "
     "'kernel' is the Pallas flash kernel, 'reference' the O(S^2) XLA "
-    "path.", ("impl",))
+    "path, 'kernel_replicated' a kernel (also counted as 'kernel') "
+    "that a mesh axis computes redundantly.", ("impl",))
 
 
 def trace_counts() -> dict:
     return {impl: int(TRACES.labels(impl=impl).get())
-            for impl in ("kernel", "reference")}
+            for impl in ("kernel", "reference", "kernel_replicated")}
 
 
 def _reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -55,44 +59,28 @@ def _reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
-def _kernel_partition(mesh, rules, q_shape, kv_heads: int):
-    """(q_spec, kv_spec) that split the kernel's work over ``mesh``.
-
-    A Mosaic kernel is not partitioned by the compiler, so under a mesh
-    it runs inside a shard_map: batch over the rule table's batch axes,
-    heads over its heads axis, sequence and head_dim whole. An axis
-    that does not divide its dimension is left out (that dimension is
-    then computed in full on each device of the axis). KV heads follow
-    the query heads when they divide too; a single KV head (MQA) is
-    shared by every shard; any other ratio would break the group
-    mapping, so heads then stay whole."""
-    b, _, h, _ = q_shape
-    batch = rules.resolve_axis("batch", mesh)
-    if b % rules.axis_size("batch", mesh):
-        batch = None
-    heads = rules.resolve_axis("heads", mesh)
-    tp = rules.axis_size("heads", mesh)
-    if h % tp or (kv_heads % tp and kv_heads != 1):
-        heads = None
-    kv = heads if kv_heads % tp == 0 else None
-    return P(batch, None, heads, None), P(batch, None, kv, None)
+def resolve_impl(impl: str) -> str:
+    """'auto' by platform only: the kernel on 'tpu', the reference on
+    'cpu' (the tests); any other platform has to say which."""
+    if impl != "auto":
+        return impl
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise ValueError(
+            f"attention impl='auto' knows the platforms 'tpu' "
+            f"(kernel) and 'cpu' (reference), not {platform!r}; "
+            f"pass impl explicitly")
+    return "pallas" if platform == "tpu" else "reference"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "impl", "scale",
-                                             "mesh", "specs"))
-def _attention(q, k, v, *, causal, scale, impl, mesh, specs):
-    if impl == "reference":
-        TRACES.labels(impl="reference").inc()
-        return _reference_attention(q, k, v, causal=causal, scale=scale)
-    from skypilot_tpu.ops.pallas import flash_attention
-    kernel = functools.partial(flash_attention.flash_attention,
-                               causal=causal, scale=scale)
-    if mesh is not None:
-        q_spec, kv_spec = specs
-        kernel = jax.shard_map(kernel, mesh=mesh,
-                               in_specs=(q_spec, kv_spec, kv_spec),
-                               out_specs=q_spec, check_vma=False)
-    return kernel(q, k, v)
+@functools.partial(jax.jit, static_argnames=("causal", "impl", "scale"))
+def _attention(q, k, v, *, causal, scale, impl):
+    if impl == "pallas":
+        from skypilot_tpu.ops.pallas import flash_attention
+        return flash_attention.flash_attention(
+            q, k, v, causal=causal, scale=scale)
+    TRACES.labels(impl="reference").inc()
+    return _reference_attention(q, k, v, causal=causal, scale=scale)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -105,26 +93,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
       q: (batch, q_seq, n_heads, head_dim)
       k, v: (batch, kv_seq, n_kv_heads, head_dim)
       causal: apply causal mask (offset so q is the trailing window of kv).
-      impl: 'auto' | 'pallas' | 'reference'. 'auto' is the kernel on
-        platform 'tpu' and the reference on 'cpu' (the tests).
+      impl: 'auto' | 'pallas' | 'reference' (see :func:`resolve_impl`).
 
-    Under an ambient multi-device mesh (``mesh_lib.use_mesh``) the
-    kernel runs inside a shard_map (see :func:`_kernel_partition`).
-    The mesh is resolved here, outside the jit, so that it is part of
-    the trace's cache key.
+    The op itself knows no mesh. A Mosaic kernel is not partitioned by
+    the compiler, so a model that runs under a multi-device mesh calls
+    ``parallel.mesh_attention.attention_from_context`` instead.
     """
-    if impl == "auto":
-        platform = jax.default_backend()
-        if platform not in ("tpu", "cpu"):
-            raise ValueError(
-                f"attention impl='auto' knows the platforms 'tpu' "
-                f"(kernel) and 'cpu' (reference), not {platform!r}; "
-                f"pass impl explicitly")
-        impl = "pallas" if platform == "tpu" else "reference"
-    mesh = specs = None
-    pair = mesh_lib.current_mesh_rules()
-    if impl == "pallas" and pair is not None and pair[0].size > 1:
-        mesh, rules = pair
-        specs = _kernel_partition(mesh, rules, q.shape, k.shape[2])
-    return _attention(q, k, v, causal=causal, scale=scale, impl=impl,
-                      mesh=mesh, specs=specs)
+    return _attention(q, k, v, causal=causal, scale=scale,
+                      impl=resolve_impl(impl))

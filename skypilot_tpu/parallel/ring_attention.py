@@ -23,6 +23,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from skypilot_tpu.parallel import mesh as mesh_lib
+from skypilot_tpu.parallel import mesh_attention
 
 _NEG_INF = -1e30
 
@@ -149,8 +150,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     if sp_axis not in mesh.axis_names or mesh.shape[sp_axis] == 1:
-        from skypilot_tpu.ops import attention as attention_ops
-        return attention_ops.attention(q, k, v, causal=causal, scale=scale)
+        return mesh_attention.attention_from_context(
+            q, k, v, causal=causal, scale=scale)
     axis_size = mesh.shape[sp_axis]
     spec = P(None, sp_axis, None, None)
     inner = jax.shard_map(

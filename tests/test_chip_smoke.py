@@ -11,7 +11,10 @@ import pathlib
 import subprocess
 import sys
 
+import pytest
+
 REPO = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))   # chip_smoke.py is a script at the root
 
 
 def _smoke(tmp_path, *args):
@@ -35,11 +38,26 @@ def test_chip_smoke_tiny_rehearsal_runs_every_phase_and_is_not_ok(tmp_path):
     assert "all phases passed" in proc.stdout, (
         proc.stdout[-3000:] + proc.stderr[-3000:])
     for phase in ("serve: 5 requests through the LB", "1 prefix hit",
-                  "serve-ref:", "checkpoint of step 24 read back"):
+                  "positions clear the margin",
+                  "serve-ref: engine driven in the child (paged True, "
+                  "960 prompt tokens of the repeated request from the "
+                  "prefix cache", "checkpoint of step 24 read back"):
         assert phase in proc.stdout
     assert proc.returncode != 0
     assert '"ok": true' not in proc.stdout
-    assert "not on a TPU" in proc.stderr
+    assert "not ok: a --tiny rehearsal" in proc.stderr
+
+
+@pytest.mark.parametrize("tiny,platform,ok", [
+    (False, "tpu", True),
+    (False, "cpu", False),
+    (True, "cpu", False),
+    (True, "tpu", False),   # tiny on a chip is still a toy-width run
+])
+def test_only_a_full_width_tpu_run_earns_the_ok_line(tiny, platform, ok):
+    import chip_smoke
+    device = {"platform": platform, "kind": "x", "count": 1}
+    assert (chip_smoke.verdict_refused(tiny, device) == "") == ok
 
 
 _PRINT_CACHE = (

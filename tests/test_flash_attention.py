@@ -147,29 +147,38 @@ def test_irregular_shape_fallback_is_counted():
     assert after["reference"] - before["reference"] == 1
 
 
-@pytest.mark.parametrize("axes,h,kvh", [
-    ({"fsdp": 2, "tp": 2}, 4, 2),    # batch and heads both split
-    ({"fsdp": 4}, 4, 1),             # the LoRA recipe's mesh, MQA
-    ({"dp": 1, "tp": 4}, 4, 1),      # MQA: one KV head shared by shards
-    ({"dp": 1, "tp": 4}, 8, 2),      # ratio breaks groups: heads whole
+@pytest.mark.parametrize("axes,b,h,kvh,replicated", [
+    ({"fsdp": 2, "tp": 2}, 4, 4, 2, False),  # batch and heads both split
+    ({"fsdp": 4}, 4, 4, 1, False),           # the LoRA recipe's mesh, MQA
+    ({"dp": 1, "tp": 4}, 4, 4, 1, False),    # MQA: one KV head, shared
+    ({"dp": 1, "tp": 4}, 4, 8, 2, True),     # ratio breaks groups: whole
+    ({"fsdp": 4}, 2, 4, 1, True),            # batch 2 over 4: whole
 ])
-def test_kernel_under_mesh_matches_reference(axes, h, kvh):
+def test_kernel_under_mesh_matches_reference(axes, b, h, kvh, replicated):
     """A Mosaic kernel is not partitioned by the compiler: under an
-    ambient mesh attention() wraps it in a shard_map over the batch and
-    heads axes, forward and backward."""
+    ambient mesh the model's entry point wraps it in a shard_map over
+    the batch and heads axes, forward and backward. An axis that does
+    not divide is left out, and counted: every device of it then
+    computes the whole dimension."""
     from skypilot_tpu.parallel import mesh as mesh_lib
+    from skypilot_tpu.parallel import mesh_attention
     mesh = mesh_lib.make_mesh(axes, devices=jax.devices()[:4])
     rules = mesh_lib.DEFAULT_RULES
-    q, k, v = _make_qkv(jax.random.key(5), b=4, s=128, h=h, kvh=kvh)
+    q, k, v = _make_qkv(jax.random.key(5), b=b, s=128, h=h, kvh=kvh)
 
     def loss(impl):
         def f(q, k, v):
             with mesh_lib.use_mesh(mesh, rules):
-                return jnp.sum(attention_ops.attention(
+                return jnp.sum(mesh_attention.attention_from_context(
                     q, k, v, causal=True, impl=impl) ** 2)
         return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
 
+    before = attention_ops.trace_counts()
     (lk, gk), (lr, gr) = loss("pallas")(q, k, v), loss("reference")(q, k, v)
+    after = attention_ops.trace_counts()
+    assert after["kernel"] > before["kernel"]
+    assert (after["kernel_replicated"] >
+            before["kernel_replicated"]) == replicated
     np.testing.assert_allclose(float(lk), float(lr), rtol=2e-3)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

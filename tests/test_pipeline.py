@@ -4,8 +4,10 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.parallel import mesh as mesh_lib, pipeline
 from skypilot_tpu.train import trainer
 
@@ -65,8 +67,14 @@ def test_llama_pipelined_matches_plain_forward():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_llama_pipelined_trains():
-    cfg = llama.LlamaConfig.tiny(vocab_size=64)
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_llama_pipelined_trains(impl):
+    """'pallas': a stage is already manual over 'pp', so the kernel's
+    shard_map has to nest inside it over dp and tp (on a TPU 'auto' is
+    the kernel)."""
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=64),
+                              attention_impl=impl)
+    before = attention_ops.trace_counts()
     mesh = mesh_lib.make_mesh({"dp": 2, "pp": 2, "tp": 2})
     rules = mesh_lib.PIPELINE_RULES
     params = llama.init(cfg, jax.random.key(0))
@@ -82,3 +90,6 @@ def test_llama_pipelined_trains():
     for _ in range(8):
         state, m = step(state, {"tokens": tokens})
     assert float(m["loss"]) < float(m0["loss"])
+    after = attention_ops.trace_counts()
+    assert (after["kernel"] > before["kernel"]) == (impl == "pallas")
+    assert after["kernel_replicated"] == before["kernel_replicated"]

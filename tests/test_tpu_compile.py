@@ -14,15 +14,19 @@ a module-scoped fixture (only one process at a time may load the TPU
 library, and xdist gives a file to one worker), and the kernels'
 interpret choice is steered from here, not by an option of the program.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from skypilot_tpu.models import llama
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops.pallas import flash_attention as fa
 from skypilot_tpu.parallel import mesh as mesh_lib
+from skypilot_tpu.parallel import mesh_attention
 
 
 @pytest.fixture(scope="module")
@@ -92,17 +96,57 @@ def test_flash_kernels_compile_for_v5e(topo, for_the_chip, b, s, h, kvh,
 
 def test_kernel_partitions_over_a_described_mesh(topo, for_the_chip):
     """The LoRA recipe's {"fsdp": 4} mesh at Gemma-2B's shapes: the
-    compiler does not partition a Mosaic kernel, attention() has to
-    hand it over inside a shard_map."""
+    compiler does not partition a Mosaic kernel, the model's entry
+    point has to hand it over inside a shard_map."""
     mesh = mesh_lib.make_mesh({"fsdp": 4}, devices=topo.devices)
     rules = mesh_lib.DEFAULT_RULES
 
     def attn(q, k, v):
         with mesh_lib.use_mesh(mesh, rules):
-            return attention_ops.attention(q, k, v, causal=True,
-                                           impl="pallas")
+            return mesh_attention.attention_from_context(
+                q, k, v, causal=True, impl="pallas")
 
     text = _compiled_text(_fwd_bwd(attn),
                           NamedSharding(mesh, P("fsdp")),
                           4, 2048, 8, 1, 256)
     assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("axes", [
+    {"pp": 4},              # every axis manual already: no nesting
+    {"pp": 2, "tp": 2},     # heads split inside a stage
+    {"dp": 2, "pp": 2},     # batch split inside a stage
+])
+def test_kernel_compiles_inside_the_pipeline(topo, for_the_chip, axes):
+    """A pipeline stage is already manual over 'pp'; the kernel's
+    shard_map nests inside it over the axes still automatic. Forward
+    and backward of the pipelined model, kernel forced."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(vocab_size=512), dim=512, n_layers=4,
+        n_heads=4, n_kv_heads=2, mlp_dim=1024, attention_impl="pallas")
+    assert cfg.head_dim == 128
+    mesh = mesh_lib.make_mesh(axes, devices=topo.devices)
+    rules = mesh_lib.PIPELINE_RULES
+
+    def loss(params, tokens):
+        logits = llama.forward_pipelined(cfg, params, tokens, mesh=mesh,
+                                         rules=rules, num_microbatches=2)
+        return logits.astype(jnp.float32).mean()
+
+    shardings = mesh_lib.tree_shardings(mesh, rules,
+                                        llama.param_specs(cfg))
+    params = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(lambda: llama.init(cfg, jax.random.key(0))),
+        shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (4, 512), jnp.int32,
+        sharding=rules.sharding(("batch", None), mesh))
+    before = attention_ops.trace_counts()
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, tokens).compile().as_text()
+    assert "tpu_custom_call" in text
+    after = attention_ops.trace_counts()
+    assert after["kernel"] > before["kernel"]
+    assert after["reference"] == before["reference"]
+    assert after["kernel_replicated"] == before["kernel_replicated"]
