@@ -123,11 +123,15 @@ def device_profile(log_dir: Optional[str] = None,
     loop writes a TensorBoard-loadable trace (xplane) via
     ``jax.profiler`` when ``STPU_PROFILE_DIR`` (or ``log_dir``) is set,
     and is a zero-cost no-op otherwise — recipes can leave it on
-    unconditionally. View: tensorboard --logdir <dir> (Profile tab).
+    unconditionally. The Python tracer is off, as in every trace this
+    package starts (``stepstats.profile_options``). View: tensorboard
+    --logdir <dir> (Profile tab).
     """
     import contextlib
     target = log_dir or os.environ.get(env_var)
     if not target:
         return contextlib.nullcontext()
     import jax
-    return jax.profiler.trace(target)
+    from skypilot_tpu.observability import stepstats
+    return jax.profiler.trace(
+        target, profiler_options=stepstats.profile_options())

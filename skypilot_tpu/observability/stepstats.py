@@ -574,11 +574,27 @@ def begin_profile() -> bool:
         return True
 
 
+def profile_options():
+    """The options every ``jax.profiler`` trace this package starts
+    is taken with: the Python tracer off. JAX's default puts a
+    ``sys.setprofile`` hook on every thread, which slows the engine
+    and train loops the trace is meant to show and buries the device
+    planes under Python frames (a training trace: 24.8 MB against
+    2.2 MB, PERF.md PR 24). The host plane is not empty without it:
+    the engine loop names its own phases (``stpu.engine.*``,
+    serve/decode_engine.py)."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return options
+
+
 def capture_profile(seconds: float, out_dir: Optional[str] = None,
                     claimed: bool = False) -> Dict[str, Any]:
     """On-demand ``jax.profiler`` trace capture (the replica's ``POST
-    /profile`` seam). Starts the trace, sleeps ``seconds`` (clamped to
-    [0.05, 120]), stops it. One capture at a time per process —
+    /profile`` seam). Starts the trace (:func:`profile_options`),
+    sleeps ``seconds`` (clamped to [0.05, 120]), stops it. One capture
+    at a time per process —
     ``claimed=True`` means the caller already holds the slot via
     :func:`begin_profile`; otherwise it is claimed here and a
     concurrent capture raises cleanly. Blocking: callers run it on
@@ -591,7 +607,8 @@ def capture_profile(seconds: float, out_dir: Optional[str] = None,
                                time.strftime("%Y%m%d-%H%M%S"))
     try:
         import jax
-        jax.profiler.start_trace(str(out_dir))
+        jax.profiler.start_trace(str(out_dir),
+                                 profiler_options=profile_options())
         try:
             time.sleep(seconds)
         finally:

@@ -177,6 +177,7 @@ def _flash_fwd_streamed(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="stpu_flash_fwd",
     )(qt, kt, vt)
     # keep_lse_pad: the (B,H,S,LSE_PAD) layout feeds the bwd kernels
     # directly (already lane-tileable); [..., 0] is the logical value.
@@ -337,6 +338,7 @@ def _flash_bwd_streamed(res, do, *, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="stpu_flash_dq",
     )(qt, kt, vt, ot, dot_, lse_pad)
 
     # Grid (batch, kv_block, head, q_block): head × q_block innermost so
@@ -374,6 +376,7 @@ def _flash_bwd_streamed(res, do, *, causal: bool, scale: float,
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name="stpu_flash_dkv",
     )(qt, kt, vt, ot, dot_, lse_pad)
 
     dq = dqt.transpose(0, 2, 1, 3)
@@ -544,6 +547,7 @@ def _flash_fwd_tri(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="stpu_flash_fwd_tri",
     )(jnp.asarray(qmap), jnp.asarray(kmap), qt, kt, vt)
     return out.transpose(0, 2, 1, 3), (lse if keep_lse_pad
                                        else lse[..., 0])
@@ -717,6 +721,7 @@ def _flash_bwd_tri(res, do, *, scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="stpu_flash_dq_tri",
     )(jnp.asarray(qmap), jnp.asarray(kmap), qt, kt, vt, ot, dot_,
       lse_pad)
 
@@ -749,6 +754,7 @@ def _flash_bwd_tri(res, do, *, scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="stpu_flash_dkv_tri",
     )(jnp.asarray(kmap3), jnp.asarray(hmap3), jnp.asarray(qmap3),
       qt, kt, vt, ot, dot_, lse_pad)
 
@@ -869,6 +875,7 @@ def _flash_fwd_resident(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
         interpret=_interpret(),
+        name="stpu_flash_fwd_resident",
     )(qt, kt, vt)
     # keep_lse_pad: the (B,H,S,LSE_PAD) layout feeds the bwd kernels
     # directly (already lane-tileable); [..., 0] is the logical value.
@@ -1021,6 +1028,7 @@ def _flash_bwd_resident(res, do, *, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
         interpret=interpret,
+        name="stpu_flash_dq_resident",
     )(qt, kt, vt, ot, dot_, lse_pad)
 
     # Grid (batch, kv_block, head), head fastest: the group's heads
@@ -1045,6 +1053,7 @@ def _flash_bwd_resident(res, do, *, causal: bool, scale: float,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
         interpret=interpret,
+        name="stpu_flash_dkv_resident",
     )(qt, kt, vt, ot, dot_, lse_pad)
 
     dq = dqt.transpose(0, 2, 1, 3)

@@ -227,6 +227,79 @@ _ENGINE_UP = metrics.gauge(
     "1 while the decode engine accepts work; 0 while it is failed, "
     "restarting, or permanently down.")
 
+_QUEUE_WAIT = metrics.histogram(
+    "stpu_engine_queue_wait_seconds",
+    "Submit-to-slot-assigned wait per admitted request; with "
+    "stpu_engine_prefill_seconds it splits stpu_engine_ttft_seconds.",
+    buckets=metrics.LATENCY_BUCKETS)
+_PREFILL_SECONDS = metrics.histogram(
+    "stpu_engine_prefill_seconds",
+    "Slot-assigned-to-first-token latency per request (chunked "
+    "prefill, interleaved with other slots' chunks and decode steps).",
+    buckets=metrics.LATENCY_BUCKETS)
+# Edges 15 % apart from 5 ms to 500 ms: an iteration without a prefill
+# chunk and one with (46 and 90 ms in PERF.md's cell 1, 40 and 75 in
+# cell 2) are two modes a p95 must tell apart, and LATENCY_BUCKETS has
+# 25, 50 and 100 ms there.
+_ITL = metrics.histogram(
+    "stpu_engine_itl_seconds",
+    "Gap between two token emissions of one slot, from its second "
+    "token on (tokens one verify step accepts together are 0 apart).",
+    buckets=tuple(round(0.005 * 100 ** (i / 33), 5) for i in range(34))
+    + (1.0, 2.5))
+_STEPS = metrics.counter(
+    "stpu_engine_steps_total",
+    "Device programs the engine loop dispatched, by kind: decode "
+    "(1-token step), verify (speculative window), prefill (one "
+    "chunk), restore (one host-tier block H2D).", ("kind",))
+_STEP_KIND = {k: _STEPS.labels(kind=k)
+              for k in ("decode", "verify", "prefill", "restore")}
+_LOOP_SECONDS = metrics.counter(
+    "stpu_engine_loop_seconds_total",
+    "Engine-thread seconds by loop phase; the phases partition an "
+    "iteration, so their sum is the thread's time.", ("phase",))
+_PHASE_SECONDS = {p: _LOOP_SECONDS.labels(phase=p) for p in (
+    "schedule.admit", "schedule.prefill", "schedule.decode", "fetch",
+    "emit", "wait")}
+
+
+class _PhaseClock:
+    """Which phase of its iteration the engine loop is in, for the
+    engine thread alone: :meth:`enter` closes the phase before and
+    opens the named one at the same instant, as a
+    ``stpu.engine.<phase>`` span on the host plane of whatever
+    ``jax.profiler`` trace is running (an inactive-tracer test
+    otherwise) and as seconds on ``stpu_engine_loop_seconds_total``.
+    A switch and not a ``with`` block, so that the phases partition
+    the thread's time by construction: what Python does between two
+    blocks (a returning frame drops the step's device arrays and the
+    handler threads take the GIL: 1-2 ms a step on the chip, PERF.md
+    PR 26) belongs to the phase it ends.
+    ``schedule.*`` ends where its program is dispatched, ``fetch`` is
+    every blocking read of a device value, ``emit`` what the host does
+    with the tokens, ``wait`` the idle condition wait."""
+
+    __slots__ = ("_seconds", "_span", "_t0")
+
+    def __init__(self):
+        self._span = None
+
+    def enter(self, phase: Optional[str]) -> float:
+        """End the open phase and begin ``phase`` (None: the loop is
+        over, begin none); returns the instant of the switch."""
+        now = time.perf_counter()
+        if self._span is not None:
+            self._seconds.inc(now - self._t0)
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if phase is not None:
+            self._seconds, self._t0 = _PHASE_SECONDS[phase], now
+            # A TraceMe starts when it is built.
+            self._span = jax.profiler.TraceAnnotation(
+                "stpu.engine." + phase)
+        return now
+
+
 _DONE = object()          # end-of-stream sentinel on a request's queue
 
 
@@ -320,10 +393,11 @@ class Request:
         return list(self.stream(timeout=timeout))
 
     # engine-side
-    def _emit(self, token: int) -> None:
+    def _emit(self, token: int, now: float) -> None:
         if self.first_token_at is None:
-            self.first_token_at = time.perf_counter()
-            _TTFT.observe(self.first_token_at - self.submitted_at)
+            self.first_token_at = now
+            _TTFT.observe(now - self.submitted_at)
+            _PREFILL_SECONDS.observe(now - self.admitted_at)
         self._out.put(int(token))
 
     def _finish(self, error: Optional[str] = None) -> None:
@@ -337,7 +411,7 @@ class _Slot:
     __slots__ = ("request", "pos", "generated", "prefilled", "tok",
                  "held", "cached", "blocks", "reserved", "pending",
                  "history", "ngram_index", "drafted", "accepted",
-                 "spec_off")
+                 "spec_off", "emitted_at")
 
     def __init__(self):
         self.request: Optional[Request] = None
@@ -345,6 +419,7 @@ class _Slot:
         self.generated = 0
         self.prefilled = 0    # prompt tokens already prefilled
         self.tok = 0          # last emitted token (next step's input)
+        self.emitted_at = 0.0  # perf_counter() of the last emission
         self.held: List[Any] = []           # pinned prefix-pool nodes
         self.cached = 0       # prompt tokens restored from the pool
         self.blocks = 0       # paged: valid block-table entries
@@ -864,6 +939,7 @@ class DecodeEngine:
         _WEIGHT_QUANT_ENABLED.set(int(self._weight_quant))
         self._waiting: "collections.deque[Request]" = collections.deque()
         self._cond = threading.Condition()
+        self._phase = _PhaseClock()     # engine thread only
         self._stop = False
         self._draining = False
         self._thread: Optional[threading.Thread] = None
@@ -1214,7 +1290,15 @@ class DecodeEngine:
         stepstats.record_admission(
             slot=i, prompt_tokens=len(req.prompt),
             max_tokens=req.max_tokens, cached_tokens=slot.cached,
-            queue_wait_s=time.perf_counter() - req.submitted_at)
+            queue_wait_s=req.admitted_at - req.submitted_at)
+
+    @staticmethod
+    def _stamp_admitted(req: Request) -> None:
+        """The request has its slot: ONE clock read that the queue-wait
+        histogram, the request record, the step ring and the traced
+        spans all share."""
+        req.admitted_at = time.perf_counter()
+        _QUEUE_WAIT.observe(req.admitted_at - req.submitted_at)
 
     def _try_admit_paged(self, i: int, req: Request) -> bool:
         """Reservation-based paged admission (compute thread): alias
@@ -1314,20 +1398,15 @@ class DecodeEngine:
                 free.pop()
                 self._waiting.popleft()
                 slot = self._slots[i]
+                self._stamp_admitted(req)
                 if stepstats.ENABLED:
                     self._record_admission(i, req, slot)
-                if reqlog.ENABLED:
-                    # Queue-wait stamp for the request record; the
-                    # traced path below overwrites it with the same
-                    # clock read.
-                    req.admitted_at = time.perf_counter()
                 if traced:
-                    req.admitted_at = time.perf_counter()
                     emits.append(("engine.queue", req.trace,
                                   req.submitted_at, req.admitted_at,
                                   {"slot": i}))
                     emits.append(("engine.prefix_lookup", req.trace,
-                                  t0, time.perf_counter(),
+                                  t0, req.admitted_at,
                                   {"hit": bool(slot.held),
                                    "cached_tokens": slot.cached,
                                    "zero_copy": True}))
@@ -1392,10 +1471,8 @@ class DecodeEngine:
                     slot.pos = slot.generated = slot.prefilled = 0
                     traced = (tracing.ENABLED and req.trace is not None
                               and req.trace.sampled)
-                    if reqlog.ENABLED:
-                        req.admitted_at = time.perf_counter()
+                    self._stamp_admitted(req)
                     if traced:
-                        req.admitted_at = time.perf_counter()
                         # Queue-wait child span, retroactive from the
                         # submit/admission monotonic stamps.
                         emits.append((
@@ -1413,16 +1490,21 @@ class DecodeEngine:
                                 start_mono=t0, end_mono=t1,
                                 attrs=attrs)
 
-    def _emit_token(self, slot: "_Slot", tok: int) -> None:
+    def _emit_token(self, slot: "_Slot", tok: int, now: float) -> None:
         """ONE emission seam for all three token producers (final
         prefill chunk, plain decode step, speculative verify step):
-        last-token state, the draft history index, the client queue
-        and the token counter advance together and can never drift."""
+        last-token state, the draft history index, the client queue,
+        the token counter and the inter-token gap advance together
+        and can never drift. ``now`` is the instant the producer's
+        fetch returned, shared by every slot of the step."""
+        if slot.generated:
+            _ITL.observe(now - slot.emitted_at)
+        slot.emitted_at = now
         slot.tok = tok
         slot.generated += 1
         if self._spec_k:
             self._spec_track(slot, tok)
-        slot.request._emit(tok)
+        slot.request._emit(tok, now)
         _TOKENS.inc()
 
     def _prefill_one(self) -> int:
@@ -1431,6 +1513,7 @@ class DecodeEngine:
         Returns the number of prompt tokens prefilled (0 = no prefill
         work) — truthy exactly when work happened, and the per-step
         telemetry's prefill-token count when stepstats is armed."""
+        self._phase.enter("schedule.prefill")
         for i, slot in enumerate(self._slots):
             req = slot.request
             if req is None or slot.prefilled >= len(req.prompt):
@@ -1466,6 +1549,7 @@ class DecodeEngine:
             if fault_injection.ENABLED:
                 fault_injection.fire("engine.prefill", slot=i,
                                      start=start)
+            _STEP_KIND["prefill"].inc()
             if self._paged:
                 wb = self._ensure_block(i, start // self._chunk)
                 logits, self._cache = _paged_prefill_chunk(
@@ -1481,11 +1565,12 @@ class DecodeEngine:
             slot.prefilled = valid
             slot.pos = valid
             if slot.prefilled >= len(req.prompt):
+                self._phase.enter("fetch")
                 tok = int(_sample(
                     logits[None], jnp.asarray([req.seed], jnp.uint32),
                     jnp.asarray([valid], jnp.int32),
                     jnp.asarray([req.temperature], jnp.float32))[0])
-                self._emit_token(slot, tok)
+                self._emit_token(slot, tok, self._phase.enter("emit"))
                 if self.prefix_cache is not None:
                     _PREFIX_TTFT.labels(
                         cache="hit" if slot.cached else "miss").observe(
@@ -1526,6 +1611,7 @@ class DecodeEngine:
             slot.reserved -= 1
             self.prefix_cache.promote(node, block)
             parts = {k: jnp.asarray(v) for k, v in payload.items()}
+            _STEP_KIND["restore"].inc()
             self._cache = _host_restore_block(
                 self._cache, jnp.int32(block), parts)
             self._readmitted_blocks += 1
@@ -1659,6 +1745,7 @@ class DecodeEngine:
         if fault_injection.ENABLED:
             fault_injection.fire("engine.verify", live=len(live),
                                  drafted=int(spec_np.sum()))
+        _STEP_KIND["verify"].inc()
         if self._paged:
             # Back every position the window may write from the slots'
             # admission reservations (the remaining-1 draft clamp keeps
@@ -1680,9 +1767,11 @@ class DecodeEngine:
                 temps, seeds, self._block)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, accepts)
+        self._phase.enter("fetch")
         targets = jax.device_get(targets)
         accepts = jax.device_get(accepts)
-        dt = max(time.perf_counter() - t0, 1e-9)
+        now = self._phase.enter("emit")
+        dt = max(now - t0, 1e-9)
         if reqlog.ENABLED:
             # Device-time share for cost attribution: the step's wall
             # duration split evenly across the slots that rode it —
@@ -1699,7 +1788,7 @@ class DecodeEngine:
             a = int(accepts[i])
             base_pos = slot.pos
             for j in range(a + 1):
-                self._emit_token(slot, int(targets[i, j]))
+                self._emit_token(slot, int(targets[i, j]), now)
             slot.pos = base_pos + a + 1
             emitted += a + 1
             if k_i:
@@ -1750,6 +1839,7 @@ class DecodeEngine:
         step when drafting is on and any slot found a draft, else the
         plain 1-token step. Returns the number of tokens emitted
         (0 = no decode work)."""
+        self._phase.enter("schedule.decode")
         live = [i for i in self._live()
                 if self._slots[i].prefilled >=
                 len(self._slots[i].request.prompt)]
@@ -1764,6 +1854,7 @@ class DecodeEngine:
         t0 = time.perf_counter()
         if fault_injection.ENABLED:
             fault_injection.fire("engine.step", live=len(live))
+        _STEP_KIND["decode"].inc()
         if self._paged:
             # Lazy growth BEFORE the step: each live slot's write
             # position must be backed (reservation guarantees a block
@@ -1779,8 +1870,10 @@ class DecodeEngine:
                 seeds, self._block)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
+        self._phase.enter("fetch")
         nxt = jax.device_get(nxt)
-        dt = max(time.perf_counter() - t0, 1e-9)
+        now = self._phase.enter("emit")
+        dt = max(now - t0, 1e-9)
         if reqlog.ENABLED:
             # Per-request device-time share (see _verify_decode_step).
             share = dt / len(live)
@@ -1790,7 +1883,7 @@ class DecodeEngine:
         for i in live:
             slot = self._slots[i]
             slot.pos += 1
-            self._emit_token(slot, int(nxt[i]))
+            self._emit_token(slot, int(nxt[i]), now)
             self._maybe_finish(i)
         _SLOTS_OCCUPIED.set(len(self._live()))
         return len(live)
@@ -1833,13 +1926,16 @@ class DecodeEngine:
                 # (pinned by the monkeypatch-bomb test).
                 armed = stepstats.ENABLED
                 t0 = time.perf_counter() if armed else 0.0
+                self._phase.enter("schedule.admit")
                 self._admit()
                 pf = self._prefill_one()
                 dc = self._decode_step()
                 did = bool(pf or dc)
                 if armed and did:
+                    self._phase.enter("emit")
                     self._record_step(t0, pf, dc)
                 if not did:
+                    self._phase.enter("wait")
                     with self._cond:
                         if not self._waiting and not self._stop:
                             self._cond.wait(timeout=0.05)
@@ -1854,6 +1950,7 @@ class DecodeEngine:
             with self._cond:
                 self._failed = msg
                 self._stop = True
+        self._phase.enter(None)
         # Drain: finish anything still attached.
         err = self._failed or "engine shut down"
         outcome = "error" if self._failed else "shutdown"

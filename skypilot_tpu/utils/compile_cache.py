@@ -19,9 +19,48 @@ from __future__ import annotations
 
 import os
 import pathlib
+import threading
+
+from skypilot_tpu.observability import metrics
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
 _IN_CHECKOUT = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+# Every program the process had to build, counted where JAX says so
+# (jax.monitoring). A serving window or a steady training loop should
+# add none: each one is a stall of seconds on the thread that hit it.
+_COMPILES = metrics.counter(
+    "stpu_xla_compiles_total",
+    "XLA programs built for this process: compiled = the backend "
+    "compiled it, cache = read back from the persistent compilation "
+    "cache (a re-trace of an already cached program counts here).",
+    ("source",))
+_COMPILE_SECONDS = metrics.counter(
+    "stpu_xla_compile_seconds_total",
+    "Seconds spent building XLA programs (backend compile, or the "
+    "cache lookup and load), by the same sources.", ("source",))
+_BY_SOURCE = {s: (_COMPILES.labels(source=s),
+                  _COMPILE_SECONDS.labels(source=s))
+              for s in ("compiled", "cache")}
+# JAX 0.9.0 wraps compile_or_get_cached() in ONE duration event whether
+# the backend compiled or the cache answered; on a hit, and only then,
+# the retrieval-time event fires first, inside it and on the same
+# thread.
+_BUILT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_hit = threading.local()
+_listening = False
+
+
+def _on_duration(event: str, seconds: float, **_kwargs) -> None:
+    if event == _CACHE_HIT:
+        _hit.seen = True
+    elif event == _BUILT:
+        source = "cache" if getattr(_hit, "seen", False) else "compiled"
+        _hit.seen = False
+        count, total = _BY_SOURCE[source]
+        count.inc()
+        total.inc(seconds)
 
 
 def cache_dir() -> str:
@@ -32,10 +71,15 @@ def cache_dir() -> str:
 
 
 def enable() -> str:
-    """Turn the persistent cache on by the rule above; returns the
-    directory. Programs that compile in under half a second are not
-    worth a file each; any size is."""
+    """Turn the persistent cache on by the rule above and start
+    counting compilations (once per process); returns the directory.
+    Programs that compile in under half a second are not worth a file
+    each; any size is."""
     import jax
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
     if ENV not in os.environ:
         jax.config.update("jax_compilation_cache_dir", str(_IN_CHECKOUT))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

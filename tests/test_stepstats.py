@@ -300,17 +300,20 @@ def test_begin_profile_atomic_claim(armed):
     with pytest.raises(RuntimeError):
         stepstats.capture_profile(0.05)
     # The claimed path releases the slot on completion.
+    import jax
+    orig = jax.profiler
+
     class _P:
+        ProfileOptions = orig.ProfileOptions
+
         @staticmethod
-        def start_trace(path):
+        def start_trace(path, profiler_options):
             pass
 
         @staticmethod
         def stop_trace():
             pass
 
-    import jax
-    orig = jax.profiler
     jax.profiler = _P
     try:
         stepstats.capture_profile(0.05, claimed=True)
@@ -457,18 +460,22 @@ def test_profile_endpoint_capture(armed, monkeypatch):
 
     from skypilot_tpu.recipes import serve_llm
 
-    calls = {"start": None, "stop": 0}
+    import jax
+    calls = {"start": None, "stop": 0, "python_tracer_level": None}
 
     class _FakeProfiler:
+        ProfileOptions = jax.profiler.ProfileOptions
+
         @staticmethod
-        def start_trace(path):
+        def start_trace(path, profiler_options):
             calls["start"] = path
+            calls["python_tracer_level"] = \
+                profiler_options.python_tracer_level
 
         @staticmethod
         def stop_trace():
             calls["stop"] += 1
 
-    import jax
     monkeypatch.setattr(jax, "profiler", _FakeProfiler)
 
     def free_port():
@@ -494,6 +501,8 @@ def test_profile_endpoint_capture(armed, monkeypatch):
             time.sleep(0.02)
         assert calls["start"] == doc["profile_dir"]
         assert calls["stop"] == 1
+        # An operator's trace never carries the Python tracer.
+        assert calls["python_tracer_level"] == 0
         # Malformed seconds -> clean 400, not a crash.
         bad = urllib.request.Request(
             f"http://127.0.0.1:{port}/profile?seconds=abc",

@@ -90,6 +90,11 @@ def test_flash_kernels_compile_for_v5e(topo, for_the_chip, b, s, h, kvh,
         SingleDeviceSharding(topo.devices[0]), b, s, h, kvh, d)
     # Forward, dq and dk/dv: three kernels, and no reference fallback.
     assert text.count("tpu_custom_call") >= 3
+    # Each under the name a device trace will show it by.
+    suffix = {"resident": "_resident", "triangular": "_tri",
+              "streamed": ""}[family]
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"(stpu_flash_{kernel}{suffix})" in text
     after = attention_ops.trace_counts()
     assert after["reference"] == before["reference"]
 
