@@ -522,10 +522,14 @@ def init_paged_cache(cfg: LlamaConfig, num_blocks: int,
     """ONE device-resident paged KV pool shared by every engine slot
     AND the shared-prefix cache: ``num_blocks`` blocks of
     ``block_tokens`` token rows each, stacked on the layer axis like
-    the dense cache (the decode step scans layers and pool together).
-    Slots map logical positions to blocks through per-slot block
-    tables (serve/kv_pool.py owns the accounting); block 0 is the
-    scratch block free slots write into.
+    the dense cache. The paged forward scans the layer parameters and
+    the layer index and CARRIES this stack whole: a layer writes rows
+    at ``[li, block, offset]`` and gathers blocks at ``[li, block]``,
+    so the pool is never sliced per layer and stays one buffer under
+    the callers' donation (held by the temp-size test in
+    tests/test_paged_kv.py). Slots map logical positions to blocks
+    through per-slot block tables (serve/kv_pool.py owns the
+    accounting); block 0 is the scratch block free slots write into.
 
     ``quantized`` stores the pool as int8 codes plus parallel
     per-(layer, block, kv_head) f32 scale arrays — sized off the same
@@ -646,8 +650,9 @@ def _split_kv_attention(qg: jax.Array, ck: jax.Array, cv: jax.Array,
     return _attn_normalize(el, acc)
 
 
-def _paged_split_kv_attention(qg: jax.Array, pk: jax.Array,
-                              pv: jax.Array, table: jax.Array,
+def _paged_split_kv_attention(qg: jax.Array, li: jax.Array,
+                              pk: jax.Array, pv: jax.Array,
+                              table: jax.Array,
                               positions: jax.Array,
                               valid_len: jax.Array,
                               window: int,
@@ -662,26 +667,29 @@ def _paged_split_kv_attention(qg: jax.Array, pk: jax.Array,
     physical block holding slot ``b``'s logical chunk ``j``. Each
     ``lax.while_loop`` iteration gathers ``window // block_tokens``
     blocks per slot (a batched dynamic-slice of the table + one gather
-    into the pool), reassembles the same (B, W, KVH, D) tile the dense
+    into the pool at ``[li, phys]``: the layer is an index of the
+    gather, never a slice taken first), reassembles the same
+    (B, W, KVH, D) tile the dense
     loop slices out, and runs the IDENTICAL online-softmax tile
     (:func:`_attn_tile`) — so when ``window`` matches the dense path's
     block and tile boundaries align (window | max_seq, true for every
     shipped config), paged output is bit-identical to dense.
 
-    pk/pv: (num_blocks, block_tokens, KVH, D) — ONE layer's pool.
+    pk/pv: (layers, num_blocks, block_tokens, KVH, D) — the WHOLE
+    stacked pool; ``li`` (scalar int32) is the layer read.
     table: (B, table_len) int32; entries past a slot's frontier may be
     stale/zero (the scratch block) — their rows are masked to exact 0
     like any invalid dense row, so garbage never contributes.
 
-    ``k_scale``/``v_scale`` ((num_blocks, KVH) f32, one layer's slice)
-    arm the int8 pool: the SAME ``phys`` gather that pulls a tile's
+    ``k_scale``/``v_scale`` ((layers, num_blocks, KVH) f32) arm the
+    int8 pool: the SAME ``[li, phys]`` gather that pulls a tile's
     code blocks pulls their per-(block, head) scales, and the dequant
     multiply folds into the tile's existing f32 upcast — so
     :func:`_attn_tile` below stays the ONE online-softmax kernel
     shared with the dense loop, fed f32 tiles either way.
     """
     b, t, kvh, g, d = qg.shape
-    bt = pk.shape[1]
+    bt = pk.shape[2]
     nb_win = window // bt
     if nb_win * bt != window:
         raise ValueError(f"window {window} must be a multiple of the "
@@ -695,11 +703,11 @@ def _paged_split_kv_attention(qg: jax.Array, pk: jax.Array,
         s0, m, el, acc = carry
         phys = jax.lax.dynamic_slice(
             table, (jnp.int32(0), s0 // bt), (b, nb_win))  # (B, nbw)
-        kb = pk[phys].astype(jnp.float32)       # (B, nbw, bt, KVH, D)
-        vb = pv[phys].astype(jnp.float32)
+        kb = pk[li, phys].astype(jnp.float32)   # (B, nbw, bt, KVH, D)
+        vb = pv[li, phys].astype(jnp.float32)
         if k_scale is not None:
-            kb = kb * k_scale[phys][:, :, None, :, None]
-            vb = vb * v_scale[phys][:, :, None, :, None]
+            kb = kb * k_scale[li, phys][:, :, None, :, None]
+            vb = vb * v_scale[li, phys][:, :, None, :, None]
         kb = kb.reshape(b, window, kvh, d)
         vb = vb.reshape(b, window, kvh, d)
         kpos = s0 + jnp.arange(window)
@@ -834,8 +842,9 @@ def forward_with_cache(cfg, params: Params,
     return logits, {"k": new_k, "v": new_v}
 
 
-def _quant_scatter_row(pk: jax.Array, ks: jax.Array, blk: jax.Array,
-                       off: jax.Array, row: jax.Array):
+def _quant_scatter_row(pk: jax.Array, ks: jax.Array, li: jax.Array,
+                       blk: jax.Array, off: jax.Array,
+                       row: jax.Array):
     """Scatter one new K/V row per slot into the int8 pool, keeping
     the one-scale-per-(block, head) invariant.
 
@@ -851,12 +860,14 @@ def _quant_scatter_row(pk: jax.Array, ks: jax.Array, blk: jax.Array,
     scratch block (possibly many per batch — last write wins, scratch
     contents are never attendable).
 
-    pk: (NB, BT, KVH, D) int8; ks: (NB, KVH) f32; blk/off: (B,) int32;
+    pk: (L, NB, BT, KVH, D) int8; ks: (L, NB, KVH) f32 — the stacked
+    pool, touched at layer ``li`` only; blk/off: (B,) int32;
     row: (B, KVH, D). Returns (pk, ks) updated.
     """
     b = blk.shape[0]
-    cur = pk[blk].astype(jnp.float32)               # (B, BT, KVH, D)
-    old_s = jnp.where((off == 0)[:, None], 0.0, ks[blk])     # (B, KVH)
+    cur = pk[li, blk].astype(jnp.float32)           # (B, BT, KVH, D)
+    old_s = jnp.where((off == 0)[:, None], 0.0,
+                      ks[li, blk])                           # (B, KVH)
     row_s = jnp.max(jnp.abs(row.astype(jnp.float32)),
                     axis=-1) / 127.0
     new_s = jnp.maximum(jnp.maximum(old_s, row_s), 1e-8)
@@ -865,10 +876,10 @@ def _quant_scatter_row(pk: jax.Array, ks: jax.Array, blk: jax.Array,
     q_row = jnp.round(row.astype(jnp.float32) / new_s[..., None])
     scaled = scaled.at[jnp.arange(b), off].set(q_row)
     q = jnp.clip(scaled, -127, 127).astype(jnp.int8)
-    return pk.at[blk].set(q), ks.at[blk].set(new_s)
+    return pk.at[li, blk].set(q), ks.at[li, blk].set(new_s)
 
 
-def _quant_block_write(pk: jax.Array, ks: jax.Array,
+def _quant_block_write(pk: jax.Array, ks: jax.Array, li: jax.Array,
                        write_block: jax.Array, rows: jax.Array,
                        valid_rows: jax.Array):
     """Whole-block int8 overwrite (single-slot chunk prefill): a fresh
@@ -876,26 +887,39 @@ def _quant_block_write(pk: jax.Array, ks: jax.Array,
     right-padded final chunk's junk rows are excluded so padding can
     never inflate the quantization step — then every row quantized
     under it (junk rows too; they are masked at read like any invalid
-    row). rows: (BT, KVH, D); valid_rows: (BT,) bool."""
+    row). pk/ks are the stacked pool, written at ``[li, write_block]``
+    — the codes as a row scatter like every other pool write (see
+    :func:`paged_attention_block` for what a whole-block
+    dynamic-update-slice costs on the TPU);
+    rows: (BT, KVH, D); valid_rows: (BT,) bool."""
     rf = rows.astype(jnp.float32)
     masked = jnp.where(valid_rows[:, None, None], jnp.abs(rf), 0.0)
     s = jnp.maximum(jnp.max(masked, axis=(0, 2)) / 127.0, 1e-8)
     q = jnp.clip(jnp.round(rf / s[None, :, None]),
                  -127, 127).astype(jnp.int8)
-    return pk.at[write_block].set(q), ks.at[write_block].set(s)
+    return (pk.at[li, write_block, jnp.arange(rows.shape[0])].set(q),
+            ks.at[li, write_block].set(s))
 
 
 def paged_attention_block(cfg, x: jax.Array, lp: Params,
+                          li: jax.Array,
                           pk: jax.Array, pv: jax.Array,
+                          ks: Optional[jax.Array],
+                          vs: Optional[jax.Array],
                           table: jax.Array, positions: jax.Array,
                           start_pos: jax.Array, valid_len: jax.Array,
                           window: int,
                           write_block: Optional[jax.Array],
-                          write_pos: Optional[jax.Array] = None,
-                          ks: Optional[jax.Array] = None,
-                          vs: Optional[jax.Array] = None):
+                          write_pos: Optional[jax.Array] = None):
     """One pre-norm GQA attention residual block against the PAGED KV
     pool (the block-table twin of :func:`cached_attention_block`).
+
+    ``pk``/``pv`` are the WHOLE stacked pool
+    (layers, num_blocks, block_tokens, KVH, D) and ``li`` the layer
+    this block is: every write and every read names ``[li, ...]``, so
+    no layer's pool is ever sliced out of the stack or written back
+    into it (the layer scan carries the pool; see
+    :func:`forward_with_paged_cache`).
 
     Writes route through the table: T == 1 (batched decode step)
     scatters each slot's new K/V row into block ``table[b, pos//bt]``
@@ -906,20 +930,22 @@ def paged_attention_block(cfg, x: jax.Array, lp: Params,
     ``write_block``. Aliased (shared-prefix) blocks are never write
     targets: admission aligns the cached prefix to whole blocks and
     prefill/decode only ever write from the first non-cached block on.
-    ``ks``/``vs`` ((num_blocks, KVH) f32 per-layer scale slices) arm
-    the int8 pool: every write path quantizes against the target
-    block's one-scale-per-(block, head) entry (fresh scale on
-    whole-block prefill, grow-only code-space rescale on row
+    ``ks``/``vs`` ((layers, num_blocks, KVH) f32 scales, None for the
+    bf16 pool) arm the int8 pool: every write path quantizes against
+    the target block's one-scale-per-(block, head) entry (fresh scale
+    on whole-block prefill, grow-only code-space rescale on row
     scatters) and the attention gather dequantizes with the same
-    scales. Returns (x + attn_out, pk, pv, ks, vs) with the pool
-    updated in place under donation."""
+    scales. Returns (x + attn_out, pk, pv, ks, vs)."""
     b, t = x.shape[0], x.shape[1]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    bt = pk.shape[1]
+    bt = pk.shape[2]
     quant = ks is not None
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps,
                  getattr(cfg, "norm_offset", 0.0))
     q, k_new, v_new = qkv_proj(cfg, y, lp, positions)
+    # Every write to the bf16 pool is ONE row scatter
+    # pk.at[li, blk, off] with (B, T) targets; the three callers differ
+    # only in how they name them (the int8 pool re-scales per block).
     if write_pos is not None:
         # Speculative verify: per-(slot, token) scatter THROUGH the
         # table. Junk columns (a slot's draft tail shorter than the
@@ -938,41 +964,46 @@ def paged_attention_block(cfg, x: jax.Array, lp: Params,
             # scale reset) is always crossed BEFORE that block's
             # later offsets are written.
             for j in range(t):
-                pk, ks = _quant_scatter_row(pk, ks, blk[:, j],
+                pk, ks = _quant_scatter_row(pk, ks, li, blk[:, j],
                                             off[:, j], k_new[:, j])
-                pv, vs = _quant_scatter_row(pv, vs, blk[:, j],
+                pv, vs = _quant_scatter_row(pv, vs, li, blk[:, j],
                                             off[:, j], v_new[:, j])
-        else:
-            pk = pk.at[blk, off].set(k_new.astype(pk.dtype))
-            pv = pv.at[blk, off].set(v_new.astype(pv.dtype))
     elif t == 1:
         blk = jnp.take_along_axis(table, (start_pos // bt)[:, None],
-                                  axis=1)[:, 0]
-        off = start_pos % bt
+                                  axis=1)
+        off = (start_pos % bt)[:, None]
         if quant:
-            pk, ks = _quant_scatter_row(pk, ks, blk, off, k_new[:, 0])
-            pv, vs = _quant_scatter_row(pv, vs, blk, off, v_new[:, 0])
-        else:
-            pk = pk.at[blk, off].set(k_new[:, 0].astype(pk.dtype))
-            pv = pv.at[blk, off].set(v_new[:, 0].astype(pv.dtype))
+            pk, ks = _quant_scatter_row(pk, ks, li, blk[:, 0],
+                                        off[:, 0], k_new[:, 0])
+            pv, vs = _quant_scatter_row(pv, vs, li, blk[:, 0],
+                                        off[:, 0], v_new[:, 0])
     else:
         if b != 1 or t != bt or write_block is None:
             raise ValueError(
                 "paged chunk prefill needs B == 1, T == block_tokens "
                 "and a write_block (chunk-aligned whole-block write); "
                 f"got B={b}, T={t}, block_tokens={bt}")
+        # The chunk's rows land at offsets 0..bt-1 of write_block. A
+        # whole-block .at[li, write_block].set() is a
+        # dynamic-update-slice, and the TPU compiler then gives the
+        # WHOLE carried pool the layout of its update operand (the
+        # projection's output) and converts it back for the gather,
+        # layer by layer (PERF.md, PR 27); the row scatter keeps the
+        # pool in the layout it arrived in.
+        blk = jnp.broadcast_to(write_block, (1, t))
+        off = jnp.arange(t)[None, :]
         if quant:
             valid_rows = positions[0] < valid_len[0]
-            pk, ks = _quant_block_write(pk, ks, write_block,
+            pk, ks = _quant_block_write(pk, ks, li, write_block,
                                         k_new[0], valid_rows)
-            pv, vs = _quant_block_write(pv, vs, write_block,
+            pv, vs = _quant_block_write(pv, vs, li, write_block,
                                         v_new[0], valid_rows)
-        else:
-            pk = pk.at[write_block].set(k_new[0].astype(pk.dtype))
-            pv = pv.at[write_block].set(v_new[0].astype(pv.dtype))
+    if not quant:
+        pk = pk.at[li, blk, off].set(k_new.astype(pk.dtype))
+        pv = pv.at[li, blk, off].set(v_new.astype(pv.dtype))
     groups = h // kvh
     qg = q.reshape(b, t, kvh, groups, hd)
-    attn = _paged_split_kv_attention(qg, pk, pv, table, positions,
+    attn = _paged_split_kv_attention(qg, li, pk, pv, table, positions,
                                      valid_len, window,
                                      k_scale=ks, v_scale=vs)
     attn = attn.astype(x.dtype).reshape(b, t, h * hd)
@@ -998,7 +1029,19 @@ def forward_with_paged_cache(cfg, params: Params, tokens: jax.Array,
     is the attention tile width; match it to the dense path's
     ``min(SPLIT_KV_BLOCK, max_seq)`` for bit-parity. ``write_block``
     is the single-slot prefill write target (see
-    :func:`paged_attention_block`)."""
+    :func:`paged_attention_block`).
+
+    The layer scan SCANS the layer parameters and the layer index and
+    CARRIES the stacked pool (codes, and the int8 pool's scales beside
+    them) next to the activations: each layer scatters its rows into
+    ``[li, ...]`` of the carried buffer and gathers from it, so under
+    the callers' donation the pool is one buffer from entry to exit.
+    Handing the pool to the scan as a scanned input and collecting it
+    as a stacked output instead makes XLA slice a layer's pool out,
+    write it back into a second stack and copy that over the donated
+    one — three reads and writes of the whole pool a program (PERF.md,
+    PR 27). tests/test_paged_kv.py holds the compiled temporaries
+    under a quarter of the pool."""
     b, t = tokens.shape
     start_pos = jnp.asarray(start_pos, jnp.int32)
     if start_pos.ndim == 0:
@@ -1015,33 +1058,25 @@ def forward_with_paged_cache(cfg, params: Params, tokens: jax.Array,
     # (mixtral swaps in its dense-routed MoE).
     mlp_fn = mlp_fn or (lambda cfg, x2, lp: mlp_block(cfg, x2, lp))
 
-    quantized = "k_scale" in cache
-
-    def layer_fn(x, scanned):
-        if quantized:
-            lp, pk, pv, ks, vs = scanned                   # per-layer
-        else:
-            (lp, pk, pv), ks, vs = scanned, None, None
+    def layer_fn(carry, scanned):
+        x, pk, pv, ks, vs = carry
+        lp, li = scanned
         x2, pk, pv, ks, vs = paged_attention_block(
-            cfg, x, lp, pk, pv, table, positions, start_pos,
-            valid_len, window, write_block, write_pos=write_pos,
-            ks=ks, vs=vs)
-        return mlp_fn(cfg, x2, lp), ((pk, pv, ks, vs) if quantized
-                                     else (pk, pv))
+            cfg, x, lp, li, pk, pv, ks, vs, table, positions,
+            start_pos, valid_len, window, write_block,
+            write_pos=write_pos)
+        return (mlp_fn(cfg, x2, lp), pk, pv, ks, vs), None
 
-    if quantized:
-        # Scales ride the layer scan beside the code pools so the
-        # whole cache tree stays donate-aliasable through the jitted
-        # serving entry points (scales update in place like codes).
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"],
-                          cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": new_k, "v": new_v,
-                     "k_scale": new_ks, "v_scale": new_vs}
-    else:
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": new_k, "v": new_v}
+    # The bf16 pool has no scales: None is an empty pytree and rides
+    # the carry as nothing, so one layer_fn serves both pools.
+    (x, *pool), _ = jax.lax.scan(
+        layer_fn,
+        (x, cache["k"], cache["v"],
+         cache.get("k_scale"), cache.get("v_scale")),
+        (params["layers"], jnp.arange(cache["k"].shape[0])))
+    new_cache = {name: leaf for name, leaf in
+                 zip(("k", "v", "k_scale", "v_scale"), pool)
+                 if leaf is not None}
     if logits_at is not None:
         logits_at = jnp.asarray(logits_at, jnp.int32)
         if logits_at.ndim == 0:

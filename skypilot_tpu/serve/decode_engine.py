@@ -19,8 +19,18 @@ jitted decode step over all of them every iteration:
     the row over — stale K/V left behind is never attendable (masked
     until overwritten), the invariant the ragged-parity tests pin;
   * the cache is DONATED through both jitted entry points (prefill
-    chunk and decode step), so the O(layers * slots * max_seq) buffer
-    updates in place instead of double-buffering HBM every token.
+    chunk and decode step), so the engine keeps one
+    O(layers * slots * max_seq) buffer from step to step. Donation
+    names the buffer the result lands in, not what the program builds
+    on the way: in the paged programs the layer scan SCANS the layer
+    parameters and the layer index and CARRIES the stacked pool, each
+    layer scattering rows into and gathering blocks from [layer, ...]
+    of that one buffer (models/llama.forward_with_paged_cache). That
+    no second pool exists inside a program is held by the compiled
+    temporaries (tests/test_paged_kv.py::
+    test_paged_entry_points_hold_one_pool_buffer), not by the
+    donation. The dense programs still scan their row cache in and
+    out, layer by layer.
 
 Sampling is reproducible per request: the key for the token at
 position p is fold_in(fold_in(root, seed), p), independent of which

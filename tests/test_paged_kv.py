@@ -386,8 +386,14 @@ def test_paged_more_live_slots_than_dense_same_budget():
 def test_paged_entry_points_keep_donation_sharded_and_single():
     """The pool stays donated through BOTH paged jitted entry points —
     single-device and TP-sharded (cache_shardings applies unchanged to
-    the pool layout) — so the O(layers * blocks) buffer updates in
-    place instead of double-buffering HBM. Pinned per family."""
+    the pool layout): the buffer handed in is consumed. That is all a
+    consumed donation shows. A program can take the donated buffer and
+    still build a second pool beside it (up to PR 26 both programs
+    did: one layer's pool sliced out, written back into a second
+    stack, copied over the donated one); that the pool is ONE buffer
+    inside the program is held by
+    test_paged_entry_points_hold_one_pool_buffer below. Pinned per
+    family."""
     from skypilot_tpu.parallel import mesh as mesh_lib
     mesh = mesh_lib.make_mesh({"tp": 2}, devices=jax.devices()[:2])
     rules = mesh_lib.DEFAULT_RULES
@@ -424,6 +430,101 @@ def test_paged_entry_points_keep_donation_sharded_and_single():
                 jnp.zeros((2,), jnp.uint32))
             assert old_k.is_deleted() and old_v.is_deleted(), \
                 f"{family} shard={shard}: step dropped donation"
+
+
+# A pool far larger than everything else a program touches, so that a
+# second copy of it (or of one layer of it) cannot hide among the
+# temporaries: 16384 blocks of 64 rows, never allocated — the entry
+# points are lowered from shapes.
+_ONE_BUFFER_BLOCKS, _ONE_BUFFER_BT, _ONE_BUFFER_WINDOW = 16384, 64, 256
+
+
+def _compile_paged_entry(entry, family, quantized, tp):
+    """Compile one paged entry point from shapes alone (float32; the
+    CPU backend widens a bf16 pool for its scatter, which says nothing
+    about the program). Returns (compiled, pool bytes on one device,
+    parameter bytes, the K pool's shape on one device)."""
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    mdl, cfg = _tiny(family)
+    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    params = jax.eval_shape(lambda: mdl.init(cfg, jax.random.key(0)))
+    pool = jax.eval_shape(lambda: mdl.init_paged_cache(
+        cfg, _ONE_BUFFER_BLOCKS, _ONE_BUFFER_BT, quantized=quantized))
+    shard_shape = pool["k"].shape
+    if tp > 1:
+        mesh = mesh_lib.make_mesh({"tp": tp},
+                                  devices=jax.devices()[:tp])
+        rules = mesh_lib.DEFAULT_RULES
+        params = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            params,
+            mesh_lib.tree_shardings(mesh, rules, mdl.param_specs(cfg)))
+        shardings = gang_replica.cache_shardings(cfg, mesh, rules)
+        pool = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                        sharding=shardings[k])
+                for k, v in pool.items()}
+        shard_shape = shardings["k"].shard_shape(pool["k"].shape)
+    slots, table_len = 2, 8
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    seeds = jax.ShapeDtypeStruct((slots,), jnp.uint32)
+    if entry == "_paged_step":
+        args = (i32(slots), i32(slots), i32(slots, table_len),
+                _ONE_BUFFER_WINDOW, temps, seeds)
+    elif entry == "_paged_prefill_chunk":
+        args = (i32(_ONE_BUFFER_BT), i32(table_len), i32(), i32(),
+                i32(), _ONE_BUFFER_WINDOW)
+    else:
+        args = (i32(slots, 4), i32(slots), i32(slots),
+                i32(slots, table_len), _ONE_BUFFER_WINDOW, temps, seeds)
+    compiled = getattr(decode_engine, entry).lower(
+        cfg, params, pool, *args).compile()
+    nbytes = lambda tree: sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    return compiled, nbytes(pool) // tp, nbytes(params), shard_shape
+
+
+@pytest.mark.parametrize("family,entry,quantized,tp", [
+    (family, entry, quantized, 1)
+    for family in ("llama", "mixtral", "gemma")
+    for entry in ("_paged_step", "_paged_prefill_chunk",
+                  "_paged_spec_step")
+    for quantized in (False, True)
+] + [
+    # The sharded pool (kv_heads over tp; head_dim for gemma's single
+    # KV head): the form a tp=4 replica runs.
+    ("llama", "_paged_step", False, 2),
+    ("llama", "_paged_prefill_chunk", False, 2),
+    ("llama", "_paged_spec_step", False, 2),
+    ("llama", "_paged_step", True, 2),
+    ("gemma", "_paged_step", False, 2),
+    ("mixtral", "_paged_prefill_chunk", False, 2),
+])
+def test_paged_entry_points_hold_one_pool_buffer(family, entry,
+                                                 quantized, tp):
+    """The pool is ONE buffer from entry to exit of every paged
+    program: the layer scan carries the stack and each layer scatters
+    into and gathers from ``[li, ...]`` of it. What shows it is the
+    compiled program, not the donation: its temporaries stay under a
+    quarter of the pool (of one shard's pool under tp), and its text
+    holds no copy, dynamic-slice or dynamic-update-slice whose result
+    has the pool's or one layer's pool's shape. The scanned-in,
+    stacked-out form this replaced read 1.25-1.78 times the pool in
+    temporaries here (PERF.md, PR 27)."""
+    import re
+    compiled, pool_bytes, param_bytes, kshape = _compile_paged_entry(
+        entry, family, quantized, tp)
+    assert pool_bytes >= 50 * param_bytes
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.25 * pool_bytes, (
+        f"temporaries {temp} of a {pool_bytes}-byte pool")
+    dims = {",".join(map(str, kshape)), ",".join(map(str, kshape[1:]))}
+    moved = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?(\S+ = \w+\[([\d,]*)\]\S* "
+        r"(?:copy|dynamic-slice|dynamic-update-slice))\(",
+        compiled.as_text(), re.M) if m.group(2) in dims]
+    assert not moved, moved
 
 
 def test_paged_tp_engine_bit_identical_to_dense_single():

@@ -7,7 +7,10 @@ compile the real lowering, forward and backward, at the widths the main
 path uses — head 256 (Gemma-2B) and 128 (Llama), S 2048 (resident
 family) and 8192 (triangular when causal, streamed when not) — and look
 for the kernel in the compiled program. Nothing runs: no results, no
-times.
+times. The paged serving programs are compiled here too, at Mistral-7B's
+widths: whether the KV pool stays one buffer is the TPU compiler's
+choice of layouts, which the CPU's compile of the same program does not
+make.
 
 All in this one file and one process: the topology is described inside
 a module-scoped fixture (only one process at a time may load the TPU
@@ -27,6 +30,7 @@ from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops.pallas import flash_attention as fa
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.parallel import mesh_attention
+from skypilot_tpu.serve import decode_engine
 
 
 @pytest.fixture(scope="module")
@@ -155,3 +159,62 @@ def test_kernel_compiles_inside_the_pipeline(topo, for_the_chip, axes):
     assert after["kernel"] > before["kernel"]
     assert after["reference"] == before["reference"]
     assert after["kernel_replicated"] == before["kernel_replicated"]
+
+
+@pytest.mark.parametrize("entry,quantized", [
+    ("_paged_step", False),
+    ("_paged_prefill_chunk", False),
+    ("_paged_prefill_chunk", True),
+    ("_paged_spec_step", False),
+])
+def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
+                                                      entry, quantized):
+    """Mistral-7B's widths (two layers of them), 32 slots, 641 blocks
+    of 64 rows: temporaries under a quarter of the pool and no
+    pool-shaped copy. tests/test_paged_kv.py holds the same on the CPU
+    for every family; this holds what only the TPU compiler decides. A
+    prefill chunk written as one whole-block dynamic-update-slice
+    passed there and read 1.5 times the pool in temporaries here: the
+    compiler gave the carried pool the layout of the update operand
+    ({4,2,3,1,0}: the projection's output, bitcast) and converted the
+    whole pool back for the attention's gather in every layer. Written
+    as a row scatter, like the decode step's, the pool keeps the
+    layout it arrived in."""
+    import re
+    cfg = llama.LlamaConfig(vocab_size=32768, dim=4096, n_layers=2,
+                            n_heads=32, n_kv_heads=8, mlp_dim=14336,
+                            rope_theta=1e6, max_seq_len=32768)
+    slots, bt, max_seq, window = 32, 64, 1280, 256
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init(cfg, jax.random.key(0))))
+    pool = on_chip(jax.eval_shape(lambda: llama.init_paged_cache(
+        cfg, slots * max_seq // bt + 1, bt, quantized=quantized)))
+    i32, table_len = jnp.int32, max_seq // bt
+    sampling = (arg(jnp.float32, slots), arg(jnp.uint32, slots))
+    args = {
+        "_paged_step": (arg(i32, slots), arg(i32, slots),
+                        arg(i32, slots, table_len), window, *sampling),
+        "_paged_prefill_chunk": (arg(i32, bt), arg(i32, table_len),
+                                 arg(i32), arg(i32), arg(i32), window),
+        "_paged_spec_step": (arg(i32, slots, 5), arg(i32, slots),
+                             arg(i32, slots),
+                             arg(i32, slots, table_len), window,
+                             *sampling),
+    }[entry]
+    compiled = getattr(decode_engine, entry).lower(
+        cfg, params, pool, *args).compile()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.25 * pool_bytes, (temp, pool_bytes)
+    dims = ",".join(map(str, pool["k"].shape))
+    copies = re.findall(
+        rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(",
+        compiled.as_text(), re.M)
+    assert not copies, copies
