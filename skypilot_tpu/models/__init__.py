@@ -1,5 +1,7 @@
-"""Model families (llama / mixtral / gemma) sharing one attention,
-KV-cache, and serving-decode stack (models/llama.py)."""
+"""Model families: llama / mixtral / gemma share one attention,
+KV-cache, and serving-decode stack (models/llama.py); deepseek brings
+its own attention and latent paged pool (models/deepseek.py) to the
+same engine."""
 from __future__ import annotations
 
 
@@ -10,16 +12,19 @@ def model_api(cfg):
     the serving recipe, the decode engine, and the benches so a fourth
     family plugs in at exactly one place.
     """
-    from skypilot_tpu.models import gemma, llama, mixtral
+    from skypilot_tpu.models import deepseek, gemma, llama, mixtral
     if isinstance(cfg, mixtral.MixtralConfig):
         return mixtral
+    if isinstance(cfg, deepseek.DeepseekV3Config):
+        return deepseek
     if isinstance(cfg, gemma.GemmaConfig):
         return gemma
     return llama
 
 
 def family_name(cfg) -> str:
-    """Config-type -> family string ("llama" / "mixtral" / "gemma").
+    """Config-type -> family string ("llama" / "mixtral" / "gemma" /
+    "deepseek").
 
     The stable identifier the tuning manifest keys engine constants
     by (skypilot_tpu/tune/) — the same dispatch as model_api, reduced

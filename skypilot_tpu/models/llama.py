@@ -767,6 +767,35 @@ def cached_attention_block(cfg, x: jax.Array, lp: Params,
     return x + lora_dense(attn, lp, "wo"), ck, cv
 
 
+def slot_positions(b: int, t: int, start_pos, valid_len):
+    """The per-slot (B,) ``start_pos`` and ``valid_len`` (default
+    start_pos + T) of an incremental forward, each given as a scalar or
+    a vector, and the (B, T) absolute positions of its tokens."""
+    start_pos = jnp.asarray(start_pos, jnp.int32)
+    if start_pos.ndim == 0:
+        start_pos = jnp.broadcast_to(start_pos, (b,))
+    if valid_len is None:
+        valid_len = start_pos + t
+    valid_len = jnp.asarray(valid_len, jnp.int32)
+    if valid_len.ndim == 0:
+        valid_len = jnp.broadcast_to(valid_len, (b,))
+    positions = start_pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
+    return start_pos, valid_len, positions
+
+
+def read_out(x: jax.Array, logits_at) -> jax.Array:
+    """The rows of (B, T, D) the head is computed at: all of them, or
+    the one chunk-relative ``logits_at`` names (a scalar, or one per
+    slot for ragged prompts). Serving prefill reads exactly one
+    position and skips the O(T x vocab) head on the padded chunk."""
+    if logits_at is None:
+        return x
+    logits_at = jnp.asarray(logits_at, jnp.int32)
+    if logits_at.ndim == 0:
+        return jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
+    return x[jnp.arange(x.shape[0]), logits_at][:, None]
+
+
 def forward_with_cache(cfg, params: Params,
                        tokens: jax.Array, cache: Dict[str, jax.Array],
                        start_pos: jax.Array,
@@ -803,15 +832,8 @@ def forward_with_cache(cfg, params: Params,
     parity gate proves it per winner anyway.
     """
     b, t = tokens.shape
-    start_pos = jnp.asarray(start_pos, jnp.int32)
-    if start_pos.ndim == 0:
-        start_pos = jnp.broadcast_to(start_pos, (b,))
-    if valid_len is None:
-        valid_len = start_pos + t
-    valid_len = jnp.asarray(valid_len, jnp.int32)
-    if valid_len.ndim == 0:
-        valid_len = jnp.broadcast_to(valid_len, (b,))
-    positions = start_pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
+    start_pos, valid_len, positions = slot_positions(b, t, start_pos,
+                                                     valid_len)
     x = _decode_embed(cfg, params, tokens)
 
     # Pluggable residual MLP half — mixtral swaps in its dense-routed
@@ -830,15 +852,8 @@ def forward_with_cache(cfg, params: Params,
 
     x, (new_k, new_v) = jax.lax.scan(
         layer_fn, x, (params["layers"], cache["k"], cache["v"]))
-    if logits_at is not None:
-        # Serving prefill reads exactly one position — skip the
-        # O(T x vocab) head on the padded chunk.
-        logits_at = jnp.asarray(logits_at, jnp.int32)
-        if logits_at.ndim == 0:
-            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
-        else:  # per-slot read-out (ragged prompt lengths)
-            x = x[jnp.arange(b), logits_at][:, None]
-    logits = lm_head(cfg, params, x, lambda a, _spec: a)
+    logits = lm_head(cfg, params, read_out(x, logits_at),
+                     lambda a, _spec: a)
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -901,6 +916,43 @@ def _quant_block_write(pk: jax.Array, ks: jax.Array, li: jax.Array,
             ks.at[li, write_block].set(s))
 
 
+def paged_write_targets(table: jax.Array, bt: int, b: int, t: int,
+                        start_pos: jax.Array,
+                        write_block: Optional[jax.Array],
+                        write_pos: Optional[jax.Array]):
+    """(blk, off), each (B, T): the physical block and the row in it
+    that each token of a paged forward writes, by the three callers'
+    ways of naming them (see :func:`paged_attention_block`)."""
+    if write_pos is not None:
+        # Speculative verify: per-(slot, token) scatter THROUGH the
+        # table. Junk columns (a slot's draft tail shorter than the
+        # batch's static T) carry a sentinel >= the table span and
+        # route to the scratch block — like free slots' rides, their
+        # garbage is masked to exact 0 by valid_len, never attendable.
+        ok = write_pos < table.shape[1] * bt
+        blk_idx = jnp.clip(write_pos // bt, 0, table.shape[1] - 1)
+        blk = jnp.where(ok, jnp.take_along_axis(table, blk_idx,
+                                                axis=1), 0)
+        return blk, jnp.where(ok, write_pos % bt, 0)
+    if t == 1:
+        blk = jnp.take_along_axis(table, (start_pos // bt)[:, None],
+                                  axis=1)
+        return blk, (start_pos % bt)[:, None]
+    if b != 1 or t != bt or write_block is None:
+        raise ValueError(
+            "paged chunk prefill needs B == 1, T == block_tokens "
+            "and a write_block (chunk-aligned whole-block write); "
+            f"got B={b}, T={t}, block_tokens={bt}")
+    # The chunk's rows land at offsets 0..bt-1 of write_block. A
+    # whole-block .at[li, write_block].set() is a dynamic-update-slice,
+    # and the TPU compiler then gives the WHOLE carried pool the layout
+    # of its update operand (the projection's output) and converts it
+    # back for the gather, layer by layer (PERF.md, PR 27); the row
+    # scatter keeps the pool in the layout it arrived in.
+    return (jnp.broadcast_to(write_block, (1, t)),
+            jnp.arange(t)[None, :])
+
+
 def paged_attention_block(cfg, x: jax.Array, lp: Params,
                           li: jax.Array,
                           pk: jax.Array, pv: jax.Array,
@@ -944,63 +996,29 @@ def paged_attention_block(cfg, x: jax.Array, lp: Params,
                  getattr(cfg, "norm_offset", 0.0))
     q, k_new, v_new = qkv_proj(cfg, y, lp, positions)
     # Every write to the bf16 pool is ONE row scatter
-    # pk.at[li, blk, off] with (B, T) targets; the three callers differ
-    # only in how they name them (the int8 pool re-scales per block).
-    if write_pos is not None:
-        # Speculative verify: per-(slot, token) scatter THROUGH the
-        # table. Junk columns (a slot's draft tail shorter than the
-        # batch's static T) carry a sentinel >= the table span and
-        # route to the scratch block — like free slots' rides, their
-        # garbage is masked to exact 0 by valid_len, never attendable.
-        span = table.shape[1] * bt
-        ok = write_pos < span
-        blk_idx = jnp.clip(write_pos // bt, 0, table.shape[1] - 1)
-        blk = jnp.where(ok, jnp.take_along_axis(table, blk_idx,
-                                                axis=1), 0)
-        off = jnp.where(ok, write_pos % bt, 0)
-        if quant:
-            # Columns in order: the verify window's positions are
-            # consecutive per slot, so a block boundary (off == 0,
-            # scale reset) is always crossed BEFORE that block's
-            # later offsets are written.
-            for j in range(t):
-                pk, ks = _quant_scatter_row(pk, ks, li, blk[:, j],
-                                            off[:, j], k_new[:, j])
-                pv, vs = _quant_scatter_row(pv, vs, li, blk[:, j],
-                                            off[:, j], v_new[:, j])
-    elif t == 1:
-        blk = jnp.take_along_axis(table, (start_pos // bt)[:, None],
-                                  axis=1)
-        off = (start_pos % bt)[:, None]
-        if quant:
-            pk, ks = _quant_scatter_row(pk, ks, li, blk[:, 0],
-                                        off[:, 0], k_new[:, 0])
-            pv, vs = _quant_scatter_row(pv, vs, li, blk[:, 0],
-                                        off[:, 0], v_new[:, 0])
-    else:
-        if b != 1 or t != bt or write_block is None:
-            raise ValueError(
-                "paged chunk prefill needs B == 1, T == block_tokens "
-                "and a write_block (chunk-aligned whole-block write); "
-                f"got B={b}, T={t}, block_tokens={bt}")
-        # The chunk's rows land at offsets 0..bt-1 of write_block. A
-        # whole-block .at[li, write_block].set() is a
-        # dynamic-update-slice, and the TPU compiler then gives the
-        # WHOLE carried pool the layout of its update operand (the
-        # projection's output) and converts it back for the gather,
-        # layer by layer (PERF.md, PR 27); the row scatter keeps the
-        # pool in the layout it arrived in.
-        blk = jnp.broadcast_to(write_block, (1, t))
-        off = jnp.arange(t)[None, :]
-        if quant:
-            valid_rows = positions[0] < valid_len[0]
-            pk, ks = _quant_block_write(pk, ks, li, write_block,
-                                        k_new[0], valid_rows)
-            pv, vs = _quant_block_write(pv, vs, li, write_block,
-                                        v_new[0], valid_rows)
+    # pk.at[li, blk, off] with (B, T) targets (the int8 pool re-scales
+    # per block).
+    blk, off = paged_write_targets(table, bt, b, t, start_pos,
+                                   write_block, write_pos)
     if not quant:
         pk = pk.at[li, blk, off].set(k_new.astype(pk.dtype))
         pv = pv.at[li, blk, off].set(v_new.astype(pv.dtype))
+    elif write_pos is not None or t == 1:
+        # Columns in order: the verify window's positions are
+        # consecutive per slot, so a block boundary (off == 0, scale
+        # reset) is always crossed BEFORE that block's later offsets
+        # are written.
+        for j in range(t):
+            pk, ks = _quant_scatter_row(pk, ks, li, blk[:, j],
+                                        off[:, j], k_new[:, j])
+            pv, vs = _quant_scatter_row(pv, vs, li, blk[:, j],
+                                        off[:, j], v_new[:, j])
+    else:
+        valid_rows = positions[0] < valid_len[0]
+        pk, ks = _quant_block_write(pk, ks, li, write_block,
+                                    k_new[0], valid_rows)
+        pv, vs = _quant_block_write(pv, vs, li, write_block,
+                                    v_new[0], valid_rows)
     groups = h // kvh
     qg = q.reshape(b, t, kvh, groups, hd)
     attn = _paged_split_kv_attention(qg, li, pk, pv, table, positions,
@@ -1043,15 +1061,8 @@ def forward_with_paged_cache(cfg, params: Params, tokens: jax.Array,
     PR 27). tests/test_paged_kv.py holds the compiled temporaries
     under a quarter of the pool."""
     b, t = tokens.shape
-    start_pos = jnp.asarray(start_pos, jnp.int32)
-    if start_pos.ndim == 0:
-        start_pos = jnp.broadcast_to(start_pos, (b,))
-    if valid_len is None:
-        valid_len = start_pos + t
-    valid_len = jnp.asarray(valid_len, jnp.int32)
-    if valid_len.ndim == 0:
-        valid_len = jnp.broadcast_to(valid_len, (b,))
-    positions = start_pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
+    start_pos, valid_len, positions = slot_positions(b, t, start_pos,
+                                                     valid_len)
     x = _decode_embed(cfg, params, tokens)
 
     # Pluggable residual MLP half, exactly as in forward_with_cache
@@ -1077,13 +1088,8 @@ def forward_with_paged_cache(cfg, params: Params, tokens: jax.Array,
     new_cache = {name: leaf for name, leaf in
                  zip(("k", "v", "k_scale", "v_scale"), pool)
                  if leaf is not None}
-    if logits_at is not None:
-        logits_at = jnp.asarray(logits_at, jnp.int32)
-        if logits_at.ndim == 0:
-            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
-        else:  # per-slot read-out (ragged prompt lengths)
-            x = x[jnp.arange(b), logits_at][:, None]
-    logits = lm_head(cfg, params, x, lambda a, _spec: a)
+    logits = lm_head(cfg, params, read_out(x, logits_at),
+                     lambda a, _spec: a)
     return logits, new_cache
 
 

@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.agent import constants as agent_constants
-from skypilot_tpu.models import family_name, gemma, llama, mixtral, model_api
+from skypilot_tpu.models import (deepseek, family_name, gemma, llama,
+                                 mixtral, model_api)
 from skypilot_tpu.observability import metrics
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
@@ -969,6 +970,8 @@ def model_config(model: str, dtype: str = None):
         "gemma-tiny": gemma.GemmaConfig.tiny,
         "gemma-2b": gemma.GemmaConfig.gemma_2b,
         "gemma-7b": gemma.GemmaConfig.gemma_7b,
+        "deepseek-tiny": deepseek.DeepseekV3Config.tiny,
+        "deepseek-v3-5l-ep16": deepseek.DeepseekV3Config.v3_5l_ep16,
     }[model]()
     if dtype:
         cfg = dataclasses.replace(
@@ -1035,7 +1038,8 @@ def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--model",
                    choices=["tiny", "8b", "mixtral-tiny", "mixtral-8x7b",
-                            "gemma-tiny", "gemma-2b", "gemma-7b"],
+                            "gemma-tiny", "gemma-2b", "gemma-7b",
+                            "deepseek-tiny", "deepseek-v3-5l-ep16"],
                    default="tiny")
     p.add_argument("--port", type=int, default=8080)
     p.add_argument("--seed", type=int, default=0)

@@ -257,6 +257,15 @@ _ITL = metrics.histogram(
     "token on (tokens one verify step accepts together are 0 apart).",
     buckets=tuple(round(0.005 * 100 ** (i / 33), 5) for i in range(34))
     + (1.0, 2.5))
+_MOE_ROUTED = metrics.counter(
+    "stpu_moe_tokens_routed_total",
+    "Token-expert pairs of decode steps that landed on an expert this "
+    "rank holds (live slots only; families whose expert layer holds a "
+    "share of the routed experts).")
+_MOE_HIT = metrics.counter(
+    "stpu_moe_experts_hit_total",
+    "Held experts, summed over sparse layers, that at least one live "
+    "slot's token of a decode step chose.")
 _STEPS = metrics.counter(
     "stpu_engine_steps_total",
     "Device programs the engine loop dispatched, by kind: decode "
@@ -490,7 +499,7 @@ def _paged_prefill_chunk(cfg, params, cache, buf, table_row, start,
     donated: the write happens in place. Returns (last-real-token
     logits (vocab,), pool)."""
     api = model_api(cfg)
-    logits, cache = api.forward_with_paged_cache(
+    logits, cache, *_ = api.forward_with_paged_cache(
         cfg, params, buf[None, :], cache, table_row[None, :], start,
         valid_len=valid, logits_at=jnp.maximum(valid - start - 1, 0),
         window=window, write_block=wb)
@@ -532,12 +541,19 @@ def _paged_step(cfg, params, cache, toks, pos, table, window, temps,
     slot's new K/V row scatters into block ``table[b, pos//bt]``, and
     attention gathers every slot's valid prefix through its table.
     Free slots ride along with table row 0 (the scratch block) and are
-    ignored host-side. The pool is donated (in-place update)."""
+    ignored host-side. The pool is donated (in-place update).
+
+    A family whose forward reports which held experts each token chose
+    (deepseek: a third result, (B, T, sparse layers, held) bool) gets
+    them back beside the tokens, ``((nxt, chosen), pool)``, so that the
+    step's one blocking fetch brings both."""
     api = model_api(cfg)
-    logits, cache = api.forward_with_paged_cache(
+    logits, cache, *chosen = api.forward_with_paged_cache(
         cfg, params, toks[:, None], cache, table, pos, window=window)
     logits = logits[:, -1]
     nxt = _sample(logits, seeds, pos + 1, temps)
+    if chosen:
+        nxt = (nxt, chosen[0][:, 0])
     return nxt, cache
 
 
@@ -943,8 +959,8 @@ class DecodeEngine:
         # not be read from another thread; shapes and shardings stay.
         self._cache_device_bytes = mesh_lib.bytes_per_device(self._cache)
         if self._paged:
-            _KV_POOL_BLOCK_BYTES.set(sum(
-                v.nbytes for v in self._cache.values()) // total)
+            _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
+                cfg, chunk, quantized=self._kv_quant))
         _KV_QUANT_ENABLED.set(int(self._kv_quant))
         _WEIGHT_QUANT_ENABLED.set(int(self._weight_quant))
         self._waiting: "collections.deque[Request]" = collections.deque()
@@ -1883,6 +1899,11 @@ class DecodeEngine:
         self._phase.enter("fetch")
         nxt = jax.device_get(nxt)
         now = self._phase.enter("emit")
+        if isinstance(nxt, tuple):
+            nxt, chosen = nxt
+            chosen = chosen[live]              # (live, layers, held)
+            _MOE_ROUTED.inc(int(chosen.sum()))
+            _MOE_HIT.inc(int(chosen.any(axis=0).sum()))
         dt = max(now - t0, 1e-9)
         if reqlog.ENABLED:
             # Per-request device-time share (see _verify_decode_step).

@@ -86,6 +86,20 @@ def block_bytes(block_tokens: int, n_layers: int, n_kv_heads: int,
     return rows * per_elem + scales
 
 
+def block_bytes_for(cfg, block_tokens: int, *,
+                    quantized: bool = False) -> int:
+    """Device bytes ONE pool block of a model configuration costs
+    across all layers. What a cached token costs is the family's to
+    say, and its ``init_paged_cache`` says it (shapes only): keys and
+    values for every KV head (:func:`block_bytes`), or one latent row
+    shared by all heads (deepseek)."""
+    import jax
+    from skypilot_tpu.models import model_api
+    pool = jax.eval_shape(lambda: model_api(cfg).init_paged_cache(
+        cfg, 1, block_tokens, quantized=quantized))
+    return sum(a.size * a.dtype.itemsize for a in pool.values())
+
+
 def blocks_for_budget(budget_bytes: int, block_tokens: int,
                       n_layers: int, n_kv_heads: int, head_dim: int, *,
                       quantized: bool = False,

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from skypilot_tpu.models import gemma, llama, mixtral
+from skypilot_tpu.models import deepseek, gemma, llama, mixtral
 from skypilot_tpu.serve import decode_engine
 from skypilot_tpu.serve import gang_replica
 from skypilot_tpu.serve import kv_pool
@@ -43,6 +43,8 @@ def _tiny(family="llama"):
         return mixtral, mixtral.MixtralConfig.tiny()
     if family == "gemma":
         return gemma, gemma.GemmaConfig.tiny(vocab_size=128)
+    if family == "deepseek":
+        return deepseek, deepseek.DeepseekV3Config.tiny(vocab_size=128)
     return llama, llama.LlamaConfig.tiny(vocab_size=128)
 
 
@@ -443,14 +445,15 @@ def _compile_paged_entry(entry, family, quantized, tp):
     """Compile one paged entry point from shapes alone (float32; the
     CPU backend widens a bf16 pool for its scatter, which says nothing
     about the program). Returns (compiled, pool bytes on one device,
-    parameter bytes, the K pool's shape on one device)."""
+    parameter bytes, the first pool leaf's shape on one device)."""
     from skypilot_tpu.parallel import mesh as mesh_lib
     mdl, cfg = _tiny(family)
     cfg = dataclasses.replace(cfg, dtype=jnp.float32)
     params = jax.eval_shape(lambda: mdl.init(cfg, jax.random.key(0)))
     pool = jax.eval_shape(lambda: mdl.init_paged_cache(
         cfg, _ONE_BUFFER_BLOCKS, _ONE_BUFFER_BT, quantized=quantized))
-    shard_shape = pool["k"].shape
+    first = next(iter(pool))
+    shard_shape = pool[first].shape
     if tp > 1:
         mesh = mesh_lib.make_mesh({"tp": tp},
                                   devices=jax.devices()[:tp])
@@ -464,7 +467,7 @@ def _compile_paged_entry(entry, family, quantized, tp):
         pool = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
                                         sharding=shardings[k])
                 for k, v in pool.items()}
-        shard_shape = shardings["k"].shard_shape(pool["k"].shape)
+        shard_shape = shardings[first].shard_shape(pool[first].shape)
     slots, table_len = 2, 8
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     temps = jax.ShapeDtypeStruct((slots,), jnp.float32)
@@ -491,6 +494,12 @@ def _compile_paged_entry(entry, family, quantized, tp):
     for entry in ("_paged_step", "_paged_prefill_chunk",
                   "_paged_spec_step")
     for quantized in (False, True)
+] + [
+    # The latent pool (two leaves, two scans carrying them; no int8
+    # form, no tp).
+    ("deepseek", entry, False, 1)
+    for entry in ("_paged_step", "_paged_prefill_chunk",
+                  "_paged_spec_step")
 ] + [
     # The sharded pool (kv_heads over tp; head_dim for gemma's single
     # KV head): the form a tp=4 replica runs.
