@@ -505,11 +505,14 @@ def engine_logits(cfg, params, mesh, rules, topology, reqs) -> dict:
     trie — and keep every row of logits it samples from.
 
     The engine hands out tokens only. ``decode_engine._sample`` is the
-    one place where both the first token (host side, after the last
-    prefill chunk) and every decode step (inside the jitted step) turn
-    logits into a token, and it is called with the request's seed and
-    the token's absolute position; so a tap there, keyed by distinct
-    seeds, names each row whatever slot the request landed in."""
+    one place where both the first token (inside the jitted prefill
+    chunk) and every decode step (inside the jitted step) turn logits
+    into a token, and it is called with the request's seed and the
+    token's absolute position; so a tap there, keyed by distinct
+    seeds, names each row whatever slot the request landed in. Every
+    chunk samples (one program); one that does not end its prompt
+    samples at a prompt position and the engine drops the token, so
+    the tap leaves those rows out."""
     from unittest import mock
 
     import jax
@@ -524,7 +527,8 @@ def engine_logits(cfg, params, mesh, rules, topology, reqs) -> dict:
     def keep(logits, seed, position):
         for row, sd, pos in zip(np.asarray(logits), np.asarray(seed),
                                 np.asarray(position)):
-            if int(sd) in seeds:
+            if int(sd) in seeds and \
+                    int(pos) >= len(seeds[int(sd)]["prompt"]):
                 rows[(int(sd), int(pos))] = np.array(row, np.float32)
 
     sample = decode_engine._sample

@@ -273,6 +273,12 @@ _STEPS = metrics.counter(
     "chunk), restore (one host-tier block H2D).", ("kind",))
 _STEP_KIND = {k: _STEPS.labels(kind=k)
               for k in ("decode", "verify", "prefill", "restore")}
+_LOOKAHEAD = metrics.counter(
+    "stpu_engine_lookahead_steps_total",
+    "Decode steps dispatched while the step before was still unread "
+    "on the device, so that the host's work between two steps ran "
+    "under a step; over stpu_engine_steps_total{kind=decode,verify} "
+    "it says how often the loop runs ahead.")
 _LOOP_SECONDS = metrics.counter(
     "stpu_engine_loop_seconds_total",
     "Engine-thread seconds by loop phase; the phases partition an "
@@ -354,6 +360,13 @@ class Request:
         self.spec_accepted = 0
         self.submitted_at = time.perf_counter()
         self.first_token_at: Optional[float] = None
+        # Tokens handed to the client so far, and when the last one
+        # was: the engine reads a token's value an iteration after it
+        # dispatched the program that samples it, when the slot may
+        # already serve another request, so what emission advances
+        # lives here and not on the slot.
+        self.emitted = 0
+        self.emitted_at = 0.0
         self.error: Optional[str] = None
         self.cancelled = False
         # Distributed-tracing parent context (tracing.SpanContext from
@@ -375,7 +388,7 @@ class Request:
         # ever written under ``reqlog.ENABLED`` guards: the request's
         # device-time share (step_dur/live_slots summed per decode
         # step), the KV tier its prefix matched, and the finished
-        # engine-half record _free_slot attaches for the serve layer
+        # engine-half record _finish_request attaches for the serve layer
         # to read after _DONE.
         self.device_time_s = 0.0
         self.kv_tier: Optional[str] = None
@@ -413,11 +426,22 @@ class Request:
 
     # engine-side
     def _emit(self, token: int, now: float) -> None:
+        """ONE emission seam for all three token producers (final
+        prefill chunk, plain decode step, speculative verify step):
+        the client queue, the token counter, the first-token and the
+        inter-token histograms advance together. ``now`` is the
+        instant the producer's fetch returned, shared by every token
+        it brought."""
         if self.first_token_at is None:
             self.first_token_at = now
             _TTFT.observe(now - self.submitted_at)
             _PREFILL_SECONDS.observe(now - self.admitted_at)
+        else:
+            _ITL.observe(now - self.emitted_at)
+        self.emitted_at = now
+        self.emitted += 1
         self._out.put(int(token))
+        _TOKENS.inc()
 
     def _finish(self, error: Optional[str] = None) -> None:
         self.error = error
@@ -425,20 +449,21 @@ class Request:
 
 
 class _Slot:
-    """Host-side state of one cache row (or, paged, one block table)."""
+    """Host-side state of one cache row (or, paged, one block table):
+    what the engine knows when it DISPATCHES a program. Nothing here
+    depends on a token's value; what arrives with the values is on the
+    :class:`Request` and in the slot's draft history."""
 
-    __slots__ = ("request", "pos", "generated", "prefilled", "tok",
+    __slots__ = ("request", "pos", "generated", "prefilled",
                  "held", "cached", "blocks", "reserved", "pending",
                  "history", "ngram_index", "drafted", "accepted",
-                 "spec_off", "emitted_at")
+                 "spec_off")
 
     def __init__(self):
         self.request: Optional[Request] = None
         self.pos = 0          # valid length of the row (= next write)
-        self.generated = 0
+        self.generated = 0    # tokens whose sampling is dispatched
         self.prefilled = 0    # prompt tokens already prefilled
-        self.tok = 0          # last emitted token (next step's input)
-        self.emitted_at = 0.0  # perf_counter() of the last emission
         self.held: List[Any] = []           # pinned prefix-pool nodes
         self.cached = 0       # prompt tokens restored from the pool
         self.blocks = 0       # paged: valid block-table entries
@@ -459,11 +484,41 @@ class _Slot:
         self.spec_off = False
 
 
+class _Unread:
+    """A token vector some dispatched program leaves on the device,
+    and whom its rows are for: ``rows`` holds (slot index or None,
+    request, outcome or None) in emission order — row ``i`` of the
+    vector is the request's next token, an outcome says that it is
+    its last (the slot was retired when the program was dispatched),
+    and no index means no token, only the end (a cancel). ``chosen``
+    is a step's held-expert array beside the tokens (deepseek), ``t0``
+    a decode step's dispatch instant (None for a prefill chunk)."""
+
+    __slots__ = ("toks", "chosen", "rows", "t0")
+
+    def __init__(self, toks, rows, chosen=None, t0=None):
+        self.toks, self.chosen, self.t0 = toks, chosen, t0
+        self.rows = collections.deque(rows)
+
+
 # ------------------------------------------------------- jitted entry points
+def _first_token(logits, toks, tok_row, seed, pos, temp):
+    """What a prefill chunk does for the decode step that follows it:
+    sample the token its last real position predicts (the request's
+    own key, ``pos`` = the prompt's length) and write it into row
+    ``tok_row`` of ``toks``, the step programs' input vector, ON THE
+    DEVICE. The host reads the value one iteration later, with the
+    step's. ``tok_row`` is the slot's on a prompt's final chunk and
+    one past the last slot otherwise: the write is dropped, the
+    program the same."""
+    tok = _sample(logits, seed[None], pos[None], temp[None])
+    return toks.at[tok_row].set(tok[0], mode="drop")
+
+
 @functools.partial(jax.jit, static_argnums=(0, 7),
                    donate_argnums=(2,))
 def _prefill_chunk(cfg, params, cache, buf, slot, start, valid,
-                   block):
+                   block, toks, tok_row, seed, temp):
     """Prefill ONE chunk of ONE slot's prompt into the shared cache.
 
     buf: (P,) tokens for positions [start, start+P) of row ``slot``
@@ -471,7 +526,8 @@ def _prefill_chunk(cfg, params, cache, buf, slot, start, valid,
     absolute count of real tokens after this chunk — padding K/V
     written past it stays masked until decode steps overwrite it. The
     cache is donated: the row splice happens in place. Returns
-    (last-real-token logits (vocab,), cache).
+    (``toks`` with the first token in ``tok_row``
+    (:func:`_first_token`), cache).
     """
     api = model_api(cfg)
     row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
@@ -482,13 +538,15 @@ def _prefill_chunk(cfg, params, cache, buf, slot, start, valid,
     cache = {k: jax.lax.dynamic_update_slice_in_dim(cache[k], row[k],
                                                     slot, axis=1)
              for k in cache}
-    return logits[0, 0], cache
+    return _first_token(logits[:, 0], toks, tok_row, seed, valid,
+                        temp), cache
 
 
 @functools.partial(jax.jit, static_argnums=(0, 8),
                    donate_argnums=(2,))
 def _paged_prefill_chunk(cfg, params, cache, buf, table_row, start,
-                         valid, wb, window):
+                         valid, wb, window, toks, tok_row, seed,
+                         temp):
     """Prefill ONE chunk of ONE slot's prompt into the paged pool.
 
     The block-table twin of :func:`_prefill_chunk`: ``table_row`` is
@@ -496,14 +554,15 @@ def _paged_prefill_chunk(cfg, params, cache, buf, table_row, start,
     physical block the chunk lands in (a whole-block write — chunks
     and blocks are the same granularity, which is what lets prefix
     hits alias whole blocks instead of splicing rows). The pool is
-    donated: the write happens in place. Returns (last-real-token
-    logits (vocab,), pool)."""
+    donated: the write happens in place. Returns (``toks`` with the
+    first token in ``tok_row`` (:func:`_first_token`), pool)."""
     api = model_api(cfg)
     logits, cache, *_ = api.forward_with_paged_cache(
         cfg, params, buf[None, :], cache, table_row[None, :], start,
         valid_len=valid, logits_at=jnp.maximum(valid - start - 1, 0),
         window=window, write_block=wb)
-    return logits[0, 0], cache
+    return _first_token(logits[:, 0], toks, tok_row, seed, valid,
+                        temp), cache
 
 
 @jax.jit
@@ -600,42 +659,61 @@ def _accept_counts(toks, targets, spec_len):
                    axis=1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 8),
+def _verify_window(last, drafts):
+    """(B, K+1) verify window: each slot's last sampled token, which
+    never left the device, then its drafts (padding past spec_len)."""
+    return jnp.concatenate([last[:, None], drafts], axis=1)
+
+
+def _verified(toks, logits, pos, spec_len, temps, seeds):
+    """Targets, accepted counts and each slot's new last token (the
+    correction token after its accepted drafts: the next step's
+    input, kept on the device like a plain step's result)."""
+    targets = _sample_multi(logits, seeds, pos, temps)
+    accepts = _accept_counts(toks, targets, spec_len)
+    last = jnp.take_along_axis(targets, accepts[:, None], axis=1)[:, 0]
+    return targets, accepts, last
+
+
+@functools.partial(jax.jit, static_argnums=(0, 9),
                    donate_argnums=(2,))
-def _spec_step(cfg, params, cache, toks, pos, spec_len, temps, seeds,
-               block):
+def _spec_step(cfg, params, cache, last, drafts, pos, spec_len, temps,
+               seeds, block):
     """One speculative verify step over ALL slots (dense cache): each
     slot's window [last token, draft_1..draft_k, padding] forwards in
     one pass (models verify_step), targets are sampled per position
     with the engine's fold_in(seed, pos) keys, and drafts are accepted
     up to the first mismatch. Returns (targets (B, T), accepts (B,),
-    cache) — the engine emits targets[b, :accepts[b] + 1] per live
-    slot, so the device->host transfer is two small int arrays, never
-    the (B, T, vocab) logits. The cache is donated (in-place update);
-    rejected-suffix rows beyond each slot's accepted frontier stay
-    masked exactly like any stale slot-reuse row."""
+    last (B,), cache) — the engine emits targets[b, :accepts[b] + 1]
+    per live slot, so the device->host transfer is two small int
+    arrays, never the (B, T, vocab) logits. The cache is donated
+    (in-place update); rejected-suffix rows beyond each slot's
+    accepted frontier stay masked exactly like any stale slot-reuse
+    row."""
     api = model_api(cfg)
+    toks = _verify_window(last, drafts)
     logits, cache = api.verify_step(cfg, params, toks, cache, pos,
                                     spec_len, block=block)
-    targets = _sample_multi(logits, seeds, pos, temps)
-    return targets, _accept_counts(toks, targets, spec_len), cache
+    return (*_verified(toks, logits, pos, spec_len, temps, seeds),
+            cache)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7),
+@functools.partial(jax.jit, static_argnums=(0, 8),
                    donate_argnums=(2,))
-def _paged_spec_step(cfg, params, cache, toks, pos, spec_len, table,
-                     window, temps, seeds):
+def _paged_spec_step(cfg, params, cache, last, drafts, pos, spec_len,
+                     table, window, temps, seeds):
     """The paged twin of :func:`_spec_step`: the verify window writes
     and gathers through each slot's block table (models
     verify_step_paged); the pool is donated. The engine truncates the
     rejected suffix's blocks back afterwards (block-table truncate +
     reservation return)."""
     api = model_api(cfg)
+    toks = _verify_window(last, drafts)
     logits, cache = api.verify_step_paged(cfg, params, toks, cache,
                                           table, pos, spec_len,
                                           window=window)
-    targets = _sample_multi(logits, seeds, pos, temps)
-    return targets, _accept_counts(toks, targets, spec_len), cache
+    return (*_verified(toks, logits, pos, spec_len, temps, seeds),
+            cache)
 
 
 @jax.jit
@@ -789,9 +867,17 @@ class DecodeEngine:
 
     One background thread owns all device compute: each iteration it
     (1) admits queued requests into free slots, (2) advances at most
-    one pending prefill by one chunk, (3) runs one batched decode step
-    for every live slot — so prefill of a joining request interleaves
-    with, instead of blocking, in-flight decode.
+    one pending prefill by one chunk, (3) dispatches one batched decode
+    step for every live slot — so prefill of a joining request
+    interleaves with, instead of blocking, in-flight decode — and only
+    then (4) reads the tokens of the iteration BEFORE and hands them
+    to the clients. Sampled tokens go from one program to the next on
+    the device (``_toks``), so the host runs one step behind the
+    device and its work between two steps is hidden under a step
+    (:meth:`_decode_step`, PERF.md PR 29). A slot's life has two
+    halves with one owner each: what is known at dispatch
+    (:meth:`_retire`) and what arrives with the values
+    (:meth:`_land`, :meth:`_finish_request`).
     """
 
     def __init__(self, cfg, params, *, slots: int = 4,
@@ -958,6 +1044,29 @@ class DecodeEngine:
         # Taken once: every step donates the cache, so its leaves may
         # not be read from another thread; shapes and shardings stay.
         self._cache_device_bytes = mesh_lib.bytes_per_device(self._cache)
+        # Every slot's last sampled token, ON THE DEVICE from one
+        # program to the next: a step's result is the next step's
+        # input as it stands, a final prefill chunk writes its first
+        # token into its slot's row (_first_token). The host reads
+        # the values to hand them to the clients, never to feed them
+        # back — which is what lets it dispatch iteration k before it
+        # has read iteration k-1 (_decode_step). Free rows hold
+        # whatever was sampled there last and are ignored.
+        self._toks = jax.device_put(
+            np.zeros((slots,), np.int32),
+            None if mesh is None else jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()))
+        # Dispatched and not yet read: by the iteration under way,
+        # and by the one before it (due at this one's end).
+        self._fresh: List[_Unread] = []
+        self._behind: List[_Unread] = []
+        self._landed_at = 0.0           # perf_counter() of the last read
+        # Requests between the two halves of their end: slot retired,
+        # last token still unread (in_flight() counts them).
+        self._retiring = 0
+        # A slot whose next write would be the last position ends.
+        self._limit = (self._table_len * chunk if self._paged
+                       else self._max_seq)
         if self._paged:
             _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
                 cfg, chunk, quantized=self._kv_quant))
@@ -1083,7 +1192,8 @@ class DecodeEngine:
     def in_flight(self) -> int:
         """Requests admitted or queued and not yet finished."""
         with self._cond:
-            return len(self._waiting) + len(self._live())
+            return (len(self._waiting) + len(self._live()) +
+                    self._retiring)
 
     def failed(self) -> Optional[str]:
         """The error that killed the compute loop, if it died."""
@@ -1194,7 +1304,7 @@ class DecodeEngine:
     def _publish_paged(self, i: int) -> None:
         """Paged publish-on-free: ADOPT the slot's full prompt blocks
         into the trie — a refcount transfer (kv_pool.publish retains,
-        the slot's own reference drops right after in _free_slot), not
+        the slot's own reference drops right after in _retire), not
         a gather. Zero device work, zero host copies. The final
         partial prompt block (prompt tail + generated tokens share it)
         is never published, exactly like the dense path's full-chunk
@@ -1234,71 +1344,26 @@ class DecodeEngine:
             slot.reserved = 0
         _KV_POOL_FREE.set(self._pool.free_blocks())
 
-    def _free_slot(self, i: int, error: Optional[str] = None,
-                   outcome: str = "ok") -> None:
+    def _retire(self, i: int, error: Optional[str] = None) -> None:
+        """The DISPATCH half of a slot's end: the program that samples
+        the request's last token has been dispatched (or the request
+        was cancelled, or the engine is going down), so the slot and
+        its blocks are free for the next admission. Every program
+        dispatched from here on is queued behind that one on the
+        device, so a block handed out again is overwritten only after
+        it was last read. The request itself ends when its last token
+        has been read: :meth:`_finish_request`, an iteration later."""
         slot = self._slots[i]
-        if slot.request is not None:
-            if self._paged and error is None:
+        if self._paged:
+            if error is None:
                 # Refcount transfer into the trie BEFORE the slot's
                 # own references drop; skipped on engine failure/
                 # shutdown (device state not trustworthy).
                 self._publish_paged(i)
-            req = slot.request
-            if tracing.ENABLED and req.trace is not None \
-                    and req.trace.sampled:
-                # Decode child span: first token → slot free. A request
-                # that died before its first token anchors at submit so
-                # the failure still shows on the timeline.
-                tracing.record_span(
-                    "engine.decode", "engine", req.trace,
-                    start_mono=(req.first_token_at
-                                or req.submitted_at),
-                    status="error" if error else "ok",
-                    attrs={"tokens": slot.generated,
-                           "outcome": outcome})
-                if slot.drafted:
-                    # Speculative-verify child span: one retroactive
-                    # summary per request (a span per verify STEP
-                    # would be token-granular spam), so a trace shows
-                    # how much of the stream speculation paid for.
-                    tracing.record_span(
-                        "engine.verify", "engine", req.trace,
-                        start_mono=(req.first_token_at
-                                    or req.submitted_at),
-                        attrs={"drafted": slot.drafted,
-                               "accepted": slot.accepted,
-                               "accept_rate": round(
-                                   slot.accepted / slot.drafted, 4)})
-            if reqlog.ENABLED:
-                # Engine half of the wide-event request record: every
-                # field is something the slot/request already tracks.
-                # Attached to the request BEFORE _finish puts _DONE,
-                # so the serve handler's stream loop can read it once
-                # the iterator exhausts and ship it to the LB as the
-                # trailing stats frame.
-                req.reqlog_record = {
-                    "queue_wait_s": (
-                        round(req.admitted_at - req.submitted_at, 6)
-                        if req.admitted_at is not None else None),
-                    "prompt_tokens": len(req.prompt),
-                    "cached_prompt_tokens": req.cached_prompt_tokens,
-                    "generated_tokens": slot.generated,
-                    "kv_tier": req.kv_tier,
-                    "spec_drafted": slot.drafted,
-                    "spec_accepted": slot.accepted,
-                    "ttft_s": (
-                        round(req.first_token_at - req.submitted_at, 6)
-                        if req.first_token_at is not None else None),
-                    "device_time_s": round(req.device_time_s, 6),
-                    "outcome": outcome,
-                    "error": error,
-                }
-            slot.request._finish(error)
-            _REQUESTS.labels(outcome=outcome).inc()
-        if self._paged:
             self._release_paged(i)
+        self._retiring += 1      # before the slot stops counting
         slot.request = None
-        slot.pos = slot.generated = slot.prefilled = slot.tok = 0
+        slot.pos = slot.generated = slot.prefilled = 0
         slot.cached = 0
         slot.history = []
         slot.ngram_index = {}
@@ -1307,6 +1372,62 @@ class DecodeEngine:
         # Gauge updated HERE so every free path (finish, cancel during
         # prefill, cache-full) is reflected even while the loop idles.
         _SLOTS_OCCUPIED.set(len(self._live()))
+
+    def _finish_request(self, req: Request, outcome: str,
+                        error: Optional[str] = None) -> None:
+        """The FETCH half: every token the request will get has been
+        handed over, so close its spans, attach its record and end its
+        stream."""
+        if tracing.ENABLED and req.trace is not None \
+                and req.trace.sampled:
+            # Decode child span: first token → last. A request that
+            # died before its first token anchors at submit so the
+            # failure still shows on the timeline.
+            tracing.record_span(
+                "engine.decode", "engine", req.trace,
+                start_mono=(req.first_token_at or req.submitted_at),
+                status="error" if error else "ok",
+                attrs={"tokens": req.emitted, "outcome": outcome})
+            if req.spec_drafted:
+                # Speculative-verify child span: one retroactive
+                # summary per request (a span per verify STEP would
+                # be token-granular spam), so a trace shows how much
+                # of the stream speculation paid for.
+                tracing.record_span(
+                    "engine.verify", "engine", req.trace,
+                    start_mono=(req.first_token_at
+                                or req.submitted_at),
+                    attrs={"drafted": req.spec_drafted,
+                           "accepted": req.spec_accepted,
+                           "accept_rate": round(
+                               req.spec_accepted / req.spec_drafted,
+                               4)})
+        if reqlog.ENABLED:
+            # Engine half of the wide-event request record: every
+            # field is something the request already tracks. Attached
+            # BEFORE _finish puts _DONE, so the serve handler's stream
+            # loop can read it once the iterator exhausts and ship it
+            # to the LB as the trailing stats frame.
+            req.reqlog_record = {
+                "queue_wait_s": (
+                    round(req.admitted_at - req.submitted_at, 6)
+                    if req.admitted_at is not None else None),
+                "prompt_tokens": len(req.prompt),
+                "cached_prompt_tokens": req.cached_prompt_tokens,
+                "generated_tokens": req.emitted,
+                "kv_tier": req.kv_tier,
+                "spec_drafted": req.spec_drafted,
+                "spec_accepted": req.spec_accepted,
+                "ttft_s": (
+                    round(req.first_token_at - req.submitted_at, 6)
+                    if req.first_token_at is not None else None),
+                "device_time_s": round(req.device_time_s, 6),
+                "outcome": outcome,
+                "error": error,
+            }
+        req._finish(error)
+        self._retiring -= 1
+        _REQUESTS.labels(outcome=outcome).inc()
 
     def _record_admission(self, i: int, req: Request,
                           slot: "_Slot") -> None:
@@ -1385,7 +1506,6 @@ class DecodeEngine:
         # after the last cached token either way.
         slot.prefilled = slot.pos = slot.cached
         slot.generated = 0
-        slot.tok = 0
         req.cached_prompt_tokens = slot.cached
         self.prefix_cache.note_result(len(dev_nodes) + len(pending))
         if dev_nodes or pending:
@@ -1454,6 +1574,14 @@ class DecodeEngine:
                 pinned.update(int(b) for b in self._table[i, :s.blocks])
         _KV_POOL_PINNED.set(len(pinned))
 
+    def _table_upload(self, i=slice(None)):
+        """The block table (or slot ``i``'s row) as a program's input:
+        a COPY, because the upload may read the host's memory after
+        the call returns (on the CPU the device array IS that memory)
+        and the engine writes the table again — retires a slot, grows
+        a block — before it waits for anything."""
+        return jnp.asarray(self._table[i].copy())
+
     def _ensure_block(self, i: int, j: int) -> int:
         """Back slot ``i``'s logical block ``j``, allocating from the
         slot's admission reservation on first touch (lazy growth —
@@ -1516,36 +1644,24 @@ class DecodeEngine:
                                 start_mono=t0, end_mono=t1,
                                 attrs=attrs)
 
-    def _emit_token(self, slot: "_Slot", tok: int, now: float) -> None:
-        """ONE emission seam for all three token producers (final
-        prefill chunk, plain decode step, speculative verify step):
-        last-token state, the draft history index, the client queue,
-        the token counter and the inter-token gap advance together
-        and can never drift. ``now`` is the instant the producer's
-        fetch returned, shared by every slot of the step."""
-        if slot.generated:
-            _ITL.observe(now - slot.emitted_at)
-        slot.emitted_at = now
-        slot.tok = tok
-        slot.generated += 1
-        if self._spec_k:
-            self._spec_track(slot, tok)
-        slot.request._emit(tok, now)
-        _TOKENS.inc()
-
     def _prefill_one(self) -> int:
         """Advance the first slot with un-prefilled prompt by ONE
-        chunk; on the final chunk, sample and emit the first token.
-        Returns the number of prompt tokens prefilled (0 = no prefill
-        work) — truthy exactly when work happened, and the per-step
-        telemetry's prefill-token count when stepstats is armed."""
+        chunk; the final chunk samples the first token on the device
+        (into the slot's row of ``_toks``, where this iteration's
+        decode step finds it) and leaves it to be read with that
+        step's tokens. Returns the number of prompt tokens prefilled
+        (0 = no prefill work) — truthy exactly when work happened, and
+        the per-step telemetry's prefill-token count when stepstats is
+        armed."""
         self._phase.enter("schedule.prefill")
         for i, slot in enumerate(self._slots):
             req = slot.request
             if req is None or slot.prefilled >= len(req.prompt):
                 continue
             if req.cancelled:
-                self._free_slot(i, outcome="cancelled")
+                # No token of it is dispatched yet: it ends here.
+                self._retire(i)
+                self._finish_request(req, "cancelled")
                 continue
             if self._spec_k and not slot.history:
                 # Every request passes through here at least once (the
@@ -1572,51 +1688,34 @@ class DecodeEngine:
             buf_np[:len(piece)] = piece
             buf = jnp.asarray(buf_np)
             valid = start + len(piece)
+            final = valid >= len(req.prompt)
+            # Where the first token goes: the slot's row, or nowhere.
+            first = (self._toks,
+                     np.int32(i if final else len(self._slots)),
+                     np.uint32(req.seed), np.float32(req.temperature))
             if fault_injection.ENABLED:
                 fault_injection.fire("engine.prefill", slot=i,
                                      start=start)
             _STEP_KIND["prefill"].inc()
             if self._paged:
                 wb = self._ensure_block(i, start // self._chunk)
-                logits, self._cache = _paged_prefill_chunk(
+                self._toks, self._cache = _paged_prefill_chunk(
                     self._cfg, self._params, self._cache, buf,
-                    jnp.asarray(self._table[i]), jnp.int32(start),
-                    jnp.int32(valid), jnp.int32(wb), self._window)
+                    self._table_upload(i), jnp.int32(start),
+                    jnp.int32(valid), jnp.int32(wb), self._window,
+                    *first)
             else:
-                logits, self._cache = _prefill_chunk(
+                self._toks, self._cache = _prefill_chunk(
                     self._cfg, self._params, self._cache, buf,
                     jnp.int32(i), jnp.int32(start), jnp.int32(valid),
-                    self._block)
+                    self._block, *first)
             req.prefill_chunks += 1
             slot.prefilled = valid
             slot.pos = valid
-            if slot.prefilled >= len(req.prompt):
-                self._phase.enter("fetch")
-                tok = int(_sample(
-                    logits[None], jnp.asarray([req.seed], jnp.uint32),
-                    jnp.asarray([valid], jnp.int32),
-                    jnp.asarray([req.temperature], jnp.float32))[0])
-                self._emit_token(slot, tok, self._phase.enter("emit"))
-                if self.prefix_cache is not None:
-                    _PREFIX_TTFT.labels(
-                        cache="hit" if slot.cached else "miss").observe(
-                        req.first_token_at - req.submitted_at)
-                if tracing.ENABLED and req.trace is not None \
-                        and req.trace.sampled:
-                    # Chunked-prefill child span, closing at the first
-                    # token: steps_to_first_token is the chunk-prefill
-                    # count (the first token is sampled from the final
-                    # chunk's logits in this engine).
-                    tracing.record_span(
-                        "engine.prefill", "engine", req.trace,
-                        start_mono=(req.prefill_start
-                                    or req.submitted_at),
-                        attrs={"prompt_tokens": len(req.prompt),
-                               "cached_tokens":
-                                   req.cached_prompt_tokens,
-                               "steps_to_first_token":
-                                   req.prefill_chunks})
-                self._maybe_finish(i)
+            if final:
+                slot.generated = 1
+                self._fresh.append(_Unread(
+                    self._toks, [(i, req, self._maybe_retire(i))]))
             return len(piece)
         return 0
 
@@ -1656,18 +1755,91 @@ class DecodeEngine:
         req.cached_prompt_tokens = slot.cached
         return self._chunk
 
-    def _maybe_finish(self, i: int) -> None:
+    def _maybe_retire(self, i: int) -> Optional[str]:
+        """After a dispatch: if the program just dispatched samples
+        the last token slot ``i``'s request is owed, retire the slot
+        and return the request's outcome (the caller hands it on with
+        the token's row); None while it owes more. Counts, never a
+        token's value: the engine has no stop-token rule."""
         slot = self._slots[i]
-        req = slot.request
-        if req is None:
+        if slot.generated >= slot.request.max_tokens:
+            outcome = "ok"
+        elif slot.pos + 1 >= self._limit:
+            outcome = "cache_full"
+        else:
+            return None
+        self._retire(i)
+        return outcome
+
+    def _first_token_out(self, req: Request) -> None:
+        """What the engine records once per request, when its first
+        token (a final prefill chunk's) has been read."""
+        if self.prefix_cache is not None:
+            _PREFIX_TTFT.labels(
+                cache="hit" if req.cached_prompt_tokens else "miss"
+            ).observe(req.first_token_at - req.submitted_at)
+        if tracing.ENABLED and req.trace is not None \
+                and req.trace.sampled:
+            # Chunked-prefill child span, closing at the first token:
+            # steps_to_first_token is the chunk-prefill count (the
+            # first token is sampled from the final chunk's logits in
+            # this engine).
+            tracing.record_span(
+                "engine.prefill", "engine", req.trace,
+                start_mono=(req.prefill_start or req.submitted_at),
+                attrs={"prompt_tokens": len(req.prompt),
+                       "cached_tokens": req.cached_prompt_tokens,
+                       "steps_to_first_token": req.prefill_chunks})
+
+    def _land(self, everything: bool = False) -> None:
+        """Read what the iteration before this one left on the device
+        (``everything``: this one's dispatches too) in ONE blocking
+        fetch, and hand the tokens on: the FETCH half of a slot's
+        life — client queues, the latency histograms, deepseek's
+        routing counters, the end of every request whose last token
+        this brings. It touches no slot but to keep a drafting slot's
+        history."""
+        due = self._behind + self._fresh if everything else self._behind
+        if not due:
             return
-        if req.cancelled:
-            self._free_slot(i, outcome="cancelled")
-        elif slot.generated >= req.max_tokens:
-            self._free_slot(i, outcome="ok")
-        elif slot.pos + 1 >= (self._table_len * self._chunk
-                              if self._paged else self._max_seq):
-            self._free_slot(i, outcome="cache_full")
+        self._phase.enter("fetch")
+        fetched = jax.device_get([(e.toks, e.chosen) for e in due])
+        now = self._phase.enter("emit")
+        for entry, (toks, chosen) in zip(due, fetched):
+            rows = entry.rows
+            if entry.t0 is not None:
+                live = [i for i, _, _ in rows if i is not None]
+                if chosen is not None:
+                    chosen = chosen[live]      # (live, layers, held)
+                    _MOE_ROUTED.inc(int(chosen.sum()))
+                    _MOE_HIT.inc(int(chosen.any(axis=0).sum()))
+                # A step's time is the interval between two reads:
+                # with the loop a step ahead, dispatch to read spans
+                # two.
+                dt = max(now - max(entry.t0, self._landed_at), 1e-9)
+                if reqlog.ENABLED:
+                    # Device-time share for cost attribution: the
+                    # step's wall duration split evenly across the
+                    # slots that rode it — host-side bookkeeping only.
+                    for i, req, _ in rows:
+                        if i is not None:
+                            req.device_time_s += dt / len(live)
+                _TOK_RATE.observe(len(live) / dt)
+            while rows:
+                i, req, outcome = rows.popleft()
+                if i is not None:
+                    tok = int(toks[i])
+                    req._emit(tok, now)
+                    if req.emitted == 1:
+                        self._first_token_out(req)
+                    if self._spec_k and self._slots[i].request is req:
+                        self._spec_track(self._slots[i], tok)
+                if outcome is not None:
+                    self._finish_request(req, outcome)
+        self._landed_at = now
+        self._behind = []
+        if everything:
+            self._fresh = []
 
     # -------------------------------------------- speculative decoding
     def _spec_init(self, slot: "_Slot", req: Request) -> None:
@@ -1752,19 +1924,20 @@ class DecodeEngine:
         in a single batched pass, targets are re-sampled with the
         engine's own per-position keys, and each slot emits its
         accepted prefix plus the correction token — 1..k+1 tokens for
-        one memory-bound pass. Rollback of a rejected suffix is a
-        host-side frontier rewind (dense: rows past the frontier stay
-        masked; paged: the grown block-table tail is truncated and its
-        reservation returned). Returns tokens emitted."""
-        t = self._spec_k + 1
-        toks_np = np.zeros((len(self._slots), t), np.int32)
+        one memory-bound pass. How many a slot accepts decides its
+        next position, so this step is read before anything else is
+        planned: never dispatched ahead, and nothing is unread when
+        it is (the caller landed it all to draft). Rollback of a
+        rejected suffix is a host-side frontier rewind (dense: rows
+        past the frontier stay masked; paged: the grown block-table
+        tail is truncated and its reservation returned). Returns
+        tokens emitted."""
+        drafts_np = np.zeros((len(self._slots), self._spec_k), np.int32)
         spec_np = np.zeros((len(self._slots),), np.int32)
-        for i, slot in enumerate(self._slots):
-            toks_np[i, 0] = slot.tok
         for i in live:
             d = drafts.get(i)
             if d:
-                toks_np[i, 1:1 + len(d)] = d
+                drafts_np[i, :len(d)] = d
                 spec_np[i] = len(d)
         pos, temps, seeds = self._step_inputs(live)
         t0 = time.perf_counter()
@@ -1782,26 +1955,24 @@ class DecodeEngine:
                                (slot.pos + int(spec_np[i]))
                                // self._chunk + 1):
                     self._ensure_block(i, j)
-            targets, accepts, self._cache = _paged_spec_step(
-                self._cfg, self._params, self._cache,
-                jnp.asarray(toks_np), pos, jnp.asarray(spec_np),
-                jnp.asarray(self._table), self._window, temps, seeds)
+            targets, accepts, self._toks, self._cache = \
+                _paged_spec_step(
+                    self._cfg, self._params, self._cache, self._toks,
+                    jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
+                    self._table_upload(), self._window, temps, seeds)
         else:
-            targets, accepts, self._cache = _spec_step(
-                self._cfg, self._params, self._cache,
-                jnp.asarray(toks_np), pos, jnp.asarray(spec_np),
+            targets, accepts, self._toks, self._cache = _spec_step(
+                self._cfg, self._params, self._cache, self._toks,
+                jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
                 temps, seeds, self._block)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, accepts)
         self._phase.enter("fetch")
-        targets = jax.device_get(targets)
-        accepts = jax.device_get(accepts)
-        now = self._phase.enter("emit")
+        targets, accepts = jax.device_get((targets, accepts))
+        now = self._landed_at = self._phase.enter("emit")
         dt = max(now - t0, 1e-9)
         if reqlog.ENABLED:
-            # Device-time share for cost attribution: the step's wall
-            # duration split evenly across the slots that rode it —
-            # host-side bookkeeping only, the jitted step is untouched.
+            # Per-request device-time share (see _land).
             share = dt / len(live)
             for i in live:
                 self._slots[i].request.device_time_s += share
@@ -1814,8 +1985,11 @@ class DecodeEngine:
             a = int(accepts[i])
             base_pos = slot.pos
             for j in range(a + 1):
-                self._emit_token(slot, int(targets[i, j]), now)
+                tok = int(targets[i, j])
+                req._emit(tok, now)
+                self._spec_track(slot, tok)
             slot.pos = base_pos + a + 1
+            slot.generated += a + 1
             emitted += a + 1
             if k_i:
                 slot.drafted += k_i
@@ -1847,7 +2021,10 @@ class DecodeEngine:
                     self._table[i, j] = 0
                     slot.blocks = j
                     slot.reserved += 1
-            self._maybe_finish(i)
+            # Its tokens are out already: both halves of its end.
+            outcome = self._maybe_retire(i)
+            if outcome is not None:
+                self._finish_request(req, outcome)
         if drafted_step:
             _SPEC_DRAFTED.inc(drafted_step)
             _SPEC_ACCEPTED.inc(accepted_step)
@@ -1856,31 +2033,65 @@ class DecodeEngine:
             self._step_spec_drafted = drafted_step
             self._step_spec_accepted = accepted_step
         _TOK_RATE.observe(emitted / dt)
-        _SLOTS_OCCUPIED.set(len(self._live()))
         return emitted
 
     def _decode_step(self) -> int:
-        """One batched step over every slot whose prompt is fully
-        prefilled and which still owes tokens — a speculative verify
-        step when drafting is on and any slot found a draft, else the
-        plain 1-token step. Returns the number of tokens emitted
-        (0 = no decode work)."""
+        """Dispatch one batched step over every slot whose prompt is
+        fully prefilled and which still owes tokens, THEN read what
+        the iteration before left on the device (:meth:`_land`): the
+        step's input is the vector the last step left there, so the
+        host's emitting, admitting and planning between two steps run
+        while the device works, not while it waits. Exact, not
+        speculative: what is decided at dispatch (positions, block
+        growth, who is owed a token, whose last this is) depends on
+        counts alone.
+
+        Where a value IS needed to plan — a live slot may draft from
+        its history (``spec_k`` and not ``spec_off``) — everything
+        unread is landed first and the step after is not dispatched
+        ahead: the order the engine had before it looked ahead, as
+        the drained case of the same code. A speculative verify step
+        is read at once (:meth:`_verify_decode_step`).
+
+        Returns the number of rows dispatched; when 0, nothing is
+        left unread either (whoever drives the engine by hand loops
+        until this and ``_prefill_one`` return 0)."""
         self._phase.enter("schedule.decode")
-        live = [i for i in self._live()
-                if self._slots[i].prefilled >=
-                len(self._slots[i].request.prompt)]
+        drafting = self._spec_k and any(
+            s.request is not None and not s.spec_off
+            for s in self._slots)
+        if drafting and (self._behind or self._fresh):
+            self._land(everything=True)
+            self._phase.enter("schedule.decode")
+        live = []
+        for i, slot in enumerate(self._slots):
+            req = slot.request
+            if req is None or slot.prefilled < len(req.prompt):
+                continue
+            if not req.cancelled:
+                live.append(i)
+                continue
+            # Its end goes behind whatever of its tokens is unread.
+            self._retire(i)
+            unread = self._fresh or self._behind
+            if unread:
+                unread[-1].rows.append((None, req, "cancelled"))
+            else:
+                self._finish_request(req, "cancelled")
         if not live:
+            self._land(everything=True)
             return 0
-        if self._spec_k:
+        if drafting:
             drafts = {i: self._draft(self._slots[i]) for i in live}
             if any(drafts.values()):
                 return self._verify_decode_step(live, drafts)
-        toks = jnp.asarray([s.tok for s in self._slots], jnp.int32)
         pos, temps, seeds = self._step_inputs(live)
         t0 = time.perf_counter()
         if fault_injection.ENABLED:
             fault_injection.fire("engine.step", live=len(live))
         _STEP_KIND["decode"].inc()
+        if any(e.t0 is not None for e in self._behind):
+            _LOOKAHEAD.inc()
         if self._paged:
             # Lazy growth BEFORE the step: each live slot's write
             # position must be backed (reservation guarantees a block
@@ -1888,35 +2099,25 @@ class DecodeEngine:
             for i in live:
                 self._ensure_block(i, self._slots[i].pos // self._chunk)
             nxt, self._cache = _paged_step(
-                self._cfg, self._params, self._cache, toks, pos,
-                jnp.asarray(self._table), self._window, temps, seeds)
+                self._cfg, self._params, self._cache, self._toks, pos,
+                self._table_upload(), self._window, temps, seeds)
         else:
             nxt, self._cache = _engine_step(
-                self._cfg, self._params, self._cache, toks, pos, temps,
-                seeds, self._block)
+                self._cfg, self._params, self._cache, self._toks, pos,
+                temps, seeds, self._block)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
-        self._phase.enter("fetch")
-        nxt = jax.device_get(nxt)
-        now = self._phase.enter("emit")
-        if isinstance(nxt, tuple):
-            nxt, chosen = nxt
-            chosen = chosen[live]              # (live, layers, held)
-            _MOE_ROUTED.inc(int(chosen.sum()))
-            _MOE_HIT.inc(int(chosen.any(axis=0).sum()))
-        dt = max(now - t0, 1e-9)
-        if reqlog.ENABLED:
-            # Per-request device-time share (see _verify_decode_step).
-            share = dt / len(live)
-            for i in live:
-                self._slots[i].request.device_time_s += share
-        _TOK_RATE.observe(len(live) / dt)
+        self._toks, chosen = nxt if isinstance(nxt, tuple) \
+            else (nxt, None)
+        rows = []
         for i in live:
             slot = self._slots[i]
             slot.pos += 1
-            self._emit_token(slot, int(nxt[i]), now)
-            self._maybe_finish(i)
-        _SLOTS_OCCUPIED.set(len(self._live()))
+            slot.generated += 1
+            rows.append((i, slot.request, self._maybe_retire(i)))
+        self._fresh.append(_Unread(self._toks, rows, chosen, t0))
+        self._land()
+        self._behind, self._fresh = self._fresh, []
         return len(live)
 
     def _record_step(self, t0: float, pf: int, dc: int) -> None:
@@ -1957,11 +2158,14 @@ class DecodeEngine:
                 # (pinned by the monkeypatch-bomb test).
                 armed = stepstats.ENABLED
                 t0 = time.perf_counter() if armed else 0.0
+                # What the iteration before dispatched is read by this
+                # one whatever else it does: that is work too.
+                owed = bool(self._behind)
                 self._phase.enter("schedule.admit")
                 self._admit()
                 pf = self._prefill_one()
                 dc = self._decode_step()
-                did = bool(pf or dc)
+                did = bool(pf or dc or owed)
                 if armed and did:
                     self._phase.enter("emit")
                     self._record_step(t0, pf, dc)
@@ -1981,13 +2185,27 @@ class DecodeEngine:
             with self._cond:
                 self._failed = msg
                 self._stop = True
-        self._phase.enter(None)
-        # Drain: finish anything still attached.
+        # Drain. First what is dispatched and unread: a request whose
+        # last step went out before the stop (or the fault) gets its
+        # tokens and its own outcome.
         err = self._failed or "engine shut down"
         outcome = "error" if self._failed else "shutdown"
+        try:
+            self._land(everything=True)
+        except Exception:  # noqa: stpu-except — the device is gone and the unread tokens with it; their requests end below
+            pass
+        self._phase.enter(None)
+        for entry in self._behind + self._fresh:
+            for _, req, last in entry.rows:
+                if last is not None:
+                    self._finish_request(req, outcome, err)
+        self._behind, self._fresh = [], []
+        # Then anything still attached.
         for i, slot in enumerate(self._slots):
-            if slot.request is not None:
-                self._free_slot(i, error=err, outcome=outcome)
+            req = slot.request
+            if req is not None:
+                self._retire(i, error=err)
+                self._finish_request(req, outcome, err)
         with self._cond:
             waiting, self._waiting = list(self._waiting), \
                 collections.deque()

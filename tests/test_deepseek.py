@@ -369,7 +369,7 @@ def test_routing_counters_move_by_what_the_batch_chose():
             engine._prefill_one()
         live = engine._live()
         assert len(live) == 2
-        toks = jnp.asarray([s.tok for s in engine._slots], jnp.int32)
+        toks = engine._toks      # the chunks' first tokens, on the device
         pos = jnp.asarray([s.pos for s in engine._slots], jnp.int32)
         for i in live:
             engine._ensure_block(i, engine._slots[i].pos // 8)
@@ -381,6 +381,10 @@ def test_routing_counters_move_by_what_the_batch_chose():
         chosen = np.asarray(chosen)[live, 0]      # (live, layers, held)
         routed0 = _counter("stpu_moe_tokens_routed_total")
         hit0 = _counter("stpu_moe_experts_hit_total")
+        assert engine._decode_step() == 2
+        # The step's tokens and routing are read one iteration later,
+        # and the step dispatched then is not in the counters yet.
+        assert _counter("stpu_moe_tokens_routed_total") == routed0
         assert engine._decode_step() == 2
         assert _counter("stpu_moe_tokens_routed_total") - routed0 == \
             chosen.sum()

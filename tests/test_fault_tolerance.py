@@ -1221,6 +1221,12 @@ def test_gang_sigkill_mid_stream_lb_resume_bit_identical():
         status, body = result["out"]
         assert status == 200
         assert body == refs["seeded"], "post-SIGKILL splice diverged"
+        # The LB counts a resume after it has sent the stream's
+        # terminator (_splice_from), so the client can be here first.
+        deadline = time.time() + 5
+        while time.time() < deadline and lb_lib._RESUMES.labels(
+                outcome="ok").get() < before_ok + 2:
+            time.sleep(0.01)
         assert lb_lib._RESUMES.labels(
             outcome="ok").get() >= before_ok + 2
     finally:

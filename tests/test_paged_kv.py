@@ -419,14 +419,16 @@ def test_paged_entry_points_keep_donation_sharded_and_single():
             old_k, old_v = pool["k"], pool["v"]
             buf = jnp.zeros((8,), jnp.int32).at[:4].set(
                 jnp.asarray([1, 2, 3, 4]))
-            _logits, pool = decode_engine._paged_prefill_chunk(
+            toks, pool = decode_engine._paged_prefill_chunk(
                 cfg, params, pool, buf, table[0], jnp.int32(0),
-                jnp.int32(4), jnp.int32(1), 64)
+                jnp.int32(4), jnp.int32(1), 64,
+                jnp.zeros((2,), jnp.int32), jnp.int32(0),
+                jnp.uint32(0), jnp.float32(0.0))
             assert old_k.is_deleted() and old_v.is_deleted(), \
                 f"{family} shard={shard}: prefill dropped donation"
             old_k, old_v = pool["k"], pool["v"]
             _nxt, pool = decode_engine._paged_step(
-                cfg, params, pool, jnp.zeros((2,), jnp.int32),
+                cfg, params, pool, toks,
                 jnp.asarray([4, 0], jnp.int32), table, 64,
                 jnp.zeros((2,), jnp.float32),
                 jnp.zeros((2,), jnp.uint32))
@@ -477,9 +479,11 @@ def _compile_paged_entry(entry, family, quantized, tp):
                 _ONE_BUFFER_WINDOW, temps, seeds)
     elif entry == "_paged_prefill_chunk":
         args = (i32(_ONE_BUFFER_BT), i32(table_len), i32(), i32(),
-                i32(), _ONE_BUFFER_WINDOW)
+                i32(), _ONE_BUFFER_WINDOW, i32(slots), i32(),
+                jax.ShapeDtypeStruct((), jnp.uint32),
+                jax.ShapeDtypeStruct((), jnp.float32))
     else:
-        args = (i32(slots, 4), i32(slots), i32(slots),
+        args = (i32(slots), i32(slots, 3), i32(slots), i32(slots),
                 i32(slots, table_len), _ONE_BUFFER_WINDOW, temps, seeds)
     compiled = getattr(decode_engine, entry).lower(
         cfg, params, pool, *args).compile()

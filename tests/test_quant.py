@@ -177,21 +177,23 @@ def test_quant_entry_points_keep_donation_sharded_and_single():
             old = dict(pool)
             buf = jnp.zeros((8,), jnp.int32).at[:4].set(
                 jnp.asarray([1, 2, 3, 4]))
-            _logits, pool = decode_engine._paged_prefill_chunk(
+            toks, pool = decode_engine._paged_prefill_chunk(
                 cfg, params, pool, buf, table[0], jnp.int32(0),
-                jnp.int32(4), jnp.int32(1), 64)
+                jnp.int32(4), jnp.int32(1), 64,
+                jnp.zeros((2,), jnp.int32), jnp.int32(0),
+                jnp.uint32(0), jnp.float32(0.0))
             assert_donated(old, "prefill")
             old = dict(pool)
-            _nxt, pool = decode_engine._paged_step(
-                cfg, params, pool, jnp.zeros((2,), jnp.int32),
+            nxt, pool = decode_engine._paged_step(
+                cfg, params, pool, toks,
                 jnp.asarray([4, 0], jnp.int32), table, 64,
                 jnp.zeros((2,), jnp.float32),
                 jnp.zeros((2,), jnp.uint32))
             assert_donated(old, "step")
             old = dict(pool)
-            _t, _a, pool = decode_engine._paged_spec_step(
-                cfg, params, pool,
-                jnp.zeros((2, 3), jnp.int32),
+            _t, _a, _last, pool = decode_engine._paged_spec_step(
+                cfg, params, pool, nxt,
+                jnp.zeros((2, 2), jnp.int32),
                 jnp.asarray([5, 0], jnp.int32),
                 jnp.asarray([2, 0], jnp.int32), table, 64,
                 jnp.zeros((2,), jnp.float32),

@@ -225,15 +225,15 @@ def test_sharded_engine_donation_preserved():
         buf = jnp.zeros((64,), jnp.int32).at[:4].set(
             jnp.asarray([1, 2, 3, 4]))
         block = decode_engine._default_split_kv_block()
-        _logits, cache = decode_engine._prefill_chunk(
+        toks, cache = decode_engine._prefill_chunk(
             cfg, params, cache, buf, jnp.int32(0), jnp.int32(0),
-            jnp.int32(4), block)
+            jnp.int32(4), block, jnp.zeros((2,), jnp.int32),
+            jnp.int32(0), jnp.uint32(0), jnp.float32(0.0))
         assert old_k.is_deleted() and old_v.is_deleted(), \
             f"{name}: prefill chunk dropped the cache donation"
         old_k, old_v = cache["k"], cache["v"]
         _nxt, cache = decode_engine._engine_step(
-            cfg, params, cache,
-            jnp.zeros((2,), jnp.int32),
+            cfg, params, cache, toks,
             jnp.asarray([4, 0], jnp.int32),
             jnp.zeros((2,), jnp.float32),
             jnp.zeros((2,), jnp.uint32), block)

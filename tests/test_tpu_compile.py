@@ -215,9 +215,11 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
         "_paged_step": (arg(i32, slots), arg(i32, slots),
                         arg(i32, slots, table_len), window, *sampling),
         "_paged_prefill_chunk": (arg(i32, bt), arg(i32, table_len),
-                                 arg(i32), arg(i32), arg(i32), window),
-        "_paged_spec_step": (arg(i32, slots, 5), arg(i32, slots),
-                             arg(i32, slots),
+                                 arg(i32), arg(i32), arg(i32), window,
+                                 arg(i32, slots), arg(i32),
+                                 arg(jnp.uint32), arg(jnp.float32)),
+        "_paged_spec_step": (arg(i32, slots), arg(i32, slots, 4),
+                             arg(i32, slots), arg(i32, slots),
                              arg(i32, slots, table_len), window,
                              *sampling),
     }[entry]
