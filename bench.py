@@ -272,8 +272,8 @@ def _serving_leg() -> dict:
     state and measured exactly the way users run the tool. Each
     fixed-batch point now also records the prefill/steady-state split
     (prefill_ms / decode_ms_per_token_steady), and a per-family
-    ``engine_ragged_tok_s`` leg measures the continuous-batching
-    decode engine under a ragged arrival mix — the traffic the
+    ``engine_paged_tok_s`` leg measures the continuous-batching
+    decode engine under a mixed-length arrival mix — the traffic the
     fixed-batch path cannot batch. The run-to-run spread of these
     legs has not been measured on today's code."""
     out: dict = {}
@@ -306,34 +306,13 @@ def _serving_leg() -> dict:
                 # be visible in the json, not sink the whole bench run.
                 out[key] = None
                 out[f"{key}_error"] = str(e)[:200]
-        key = f"{family}_engine_ragged_tok_s"
-        try:
-            r = run_tool(["--family", family, "--mode", "engine"],
-                         timeout=1200)
-            out[key] = r["engine_ragged_tok_s"]
-            # Phase-breakdown detail (stepstats): the measurable
-            # objective the autotuner / disagg-autoscaler items will
-            # consume — carried round-over-round next to the tok/s
-            # headline (details are not bench_compare-gated).
-            out[f"{family}_engine_ragged_detail"] = {
-                k: r.get(k) for k in ("slots", "requests",
-                                      "generated_tokens",
-                                      "wall_seconds",
-                                      "phase_breakdown",
-                                      "busy_fraction",
-                                      "dispatch_ms_mean",
-                                      "device_ms_mean")}
-        except Exception as e:  # noqa: BLE001
-            out[key] = None
-            out[f"{key}_error"] = str(e)[:200]
-        # Paged-KV serving leg: the engine on the block pool (HALF the
-        # dense HBM budget) under a mixed-length mix — throughput per
-        # byte of KV plus the pool's peak utilization, the capacity
-        # lever tracked round-over-round next to the dense ragged leg.
+        # Engine serving leg: the engine on a block pool of HALF of
+        # slots x max_seq tokens under a mixed-length mix — throughput
+        # per byte of KV plus the pool's peak utilization.
         key = f"{family}_engine_paged_tok_s"
         try:
-            # 16 slots over HALF the dense budget — twice the ragged
-            # leg's slot count on the same bytes is the leg's point.
+            # 16 slots over the bytes of 8 whole rows: twice the slot
+            # count on the same bytes is the leg's point.
             r = run_tool(["--family", family, "--mode", "paged",
                           "--slots", "16", "--requests", "48"],
                          timeout=1200)
@@ -384,7 +363,7 @@ def _serving_leg() -> dict:
             out[f"{key}_error"] = str(e)[:200]
         # Speculative-decoding serving leg: n-gram self-drafts + one
         # batched multi-token verify pass per step, on the chat
-        # (shared-prefix) mix at the ragged leg's b8 slot count — the
+        # (shared-prefix) mix at a b8 slot count — the
         # per-request speed lever batching can't reach. The leg
         # bit-asserts speculative streams == non-speculative before
         # reporting, runs the same-mix baseline for the honest
@@ -506,7 +485,7 @@ def _serving_leg() -> dict:
         except Exception as e:  # noqa: BLE001
             out[key] = None
             out[f"{key}_error"] = str(e)[:200]
-        # Tuned-constants serving leg (`stpu tune`): the ragged engine
+        # Tuned-constants serving leg (`stpu tune`): the paged engine
         # leg re-run at the tuning manifest's constants, with the
         # default-constants number beside it. bench_compare gates the
         # tuned tok/s higher-is-better like the other engine legs;

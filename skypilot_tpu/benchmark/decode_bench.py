@@ -7,7 +7,7 @@ never reached the round-over-round record. Reference bar: serving
 throughput is the reference's headline README metric
 (/root/reference/README.md:49).
 
-Two measurements:
+The measurements:
 
   * ``measure_decode`` — fixed-batch incremental decode (prefill +
     KV-cached per-token steps; dense top-2 expert routing for MoE) in
@@ -16,22 +16,20 @@ Two measurements:
     cache is allocated by the caller and DONATED through the jit
     boundary so each step updates it in place (no second full-size
     cache in HBM).
-  * ``measure_engine_ragged`` — the continuous-batching decode engine
-    (serve/decode_engine.py) under a RAGGED arrival mix (heterogeneous
-    prompt lengths and token budgets), the traffic shape the
-    fixed-batch path cannot batch at all.
-  * ``measure_engine_paged`` — the engine in PAGED KV mode (one
-    device-resident block pool + per-slot block tables,
-    serve/kv_pool.py) under the same mixed-length mix, with the pool
-    sized to HALF the dense budget: tok/s, peak pool utilization, and
-    peak concurrent live slots — the capacity-per-byte story.
+  * ``measure_engine_paged`` — the continuous-batching decode engine
+    (serve/decode_engine.py: one device-resident block pool +
+    per-slot block tables, serve/kv_pool.py) under a mixed-length
+    arrival mix (heterogeneous prompt lengths and token budgets),
+    with the pool sized to HALF of slots x max_seq tokens: tok/s,
+    peak pool utilization, and peak concurrent live slots — the
+    capacity-per-byte story.
   * ``measure_engine_q8`` — the paged engine with int8 KV blocks and
     int8 weights (STPU_KV_QUANT / STPU_WEIGHT_QUANT): quantized tok/s
     plus the block-capacity ratio vs bf16 at the SAME HBM byte budget
     (the >= 1.8x floor bench_compare gates).
   * ``measure_engine_spec`` — self-speculative decoding (n-gram
     drafts + one batched multi-token verify pass per step) on the
-    chat shared-prefix mix at the ragged leg's b8 slot count, with
+    chat shared-prefix mix at a b8 slot count, with
     the same-mix non-speculative baseline and the draft acceptance
     rate reported beside the headline tok/s — and the two runs'
     streams bit-asserted identical.
@@ -209,93 +207,6 @@ def measure_decode(family: str, batch: int = 8, prompt_len: int = 128,
     }
 
 
-def measure_engine_ragged(family: str, slots: int = 8,
-                          n_requests: int = 32, max_prompt: int = 192,
-                          max_tokens: int = 64,
-                          engine_kw: Optional[Dict[str, Any]] = None,
-                          **shape_kw) -> Dict[str, Any]:
-    """Continuous-batching engine throughput under ragged arrivals.
-
-    A deterministic (seeded) mix of prompt lengths in [8, max_prompt]
-    and token budgets in [8, max_tokens] is submitted all at once; the
-    engine packs them into ``slots`` cache rows, prefilling joiners in
-    chunks between decode steps. Reported tokens/sec counts GENERATED
-    tokens over the whole wall (including prefill) — the number a
-    heterogeneous traffic mix actually observes, which per-bucket
-    fixed-batch serving cannot reach because it only co-schedules
-    same-length prompts.
-
-    With tracing armed (STPU_TRACE=1 / tracing.arm()) every request
-    carries a trace context, so the run measures the engine's ARMED
-    overhead (per-request queue/prefill/decode span records, not
-    per-token work) — comparing the armed and unarmed tok/s is the
-    tracing-overhead acceptance check; unarmed, the tracing cost is
-    one module-flag check per seam.
-
-    The leg runs with step telemetry (observability/stepstats.py)
-    armed and reports the PHASE BREAKDOWN (prefill vs decode vs mixed
-    seconds, busy fraction, sampled dispatch/device split) as bench
-    detail fields — the objective the attention-constant autotuner and
-    the disagg-autoscaler roadmap items consume via bench_compare.
-    """
-    from skypilot_tpu.observability import stepstats
-    from skypilot_tpu.observability import tracing
-    from skypilot_tpu.serve.decode_engine import DecodeEngine
-
-    mdl, cfg = build(family, **shape_kw)
-    params = mdl.init(cfg, jax.random.key(0))
-    # use_manifest=False: the bench measures EXPLICIT constants — an
-    # ambient tuning manifest must never contaminate a measurement
-    # (the tuner would chase its own prior output). engine_kw lets the
-    # tuner pin candidates (block, prefill_chunk).
-    kw = dict(prefill_chunk=64, use_manifest=False)
-    kw.update(engine_kw or {})
-    engine = DecodeEngine(cfg, params, slots=slots,
-                          max_seq=max_prompt + max_tokens, **kw)
-    engine.start()
-    engine.warmup()
-
-    rng = random.Random(0)
-    specs = [( [rng.randint(1, cfg.vocab_size - 1)
-                for _ in range(rng.randint(8, max_prompt))],
-               rng.randint(8, max_tokens))
-             for _ in range(n_requests)]
-    span = tracing.start_span("bench.engine_ragged", kind="bench",
-                              attrs={"requests": n_requests})
-    trace_ctx = span.context()  # None unless tracing is armed
-    was_armed = stepstats.ENABLED
-    stepstats.arm(ring=8192, sync_every=16)
-    stepstats.reset()
-    try:
-        t0 = time.perf_counter()
-        reqs = [engine.submit(p, max_tokens=mt, trace=trace_ctx)
-                for p, mt in specs]
-        total = sum(len(r.result(timeout=1800.0)) for r in reqs)
-        dt = time.perf_counter() - t0
-        snap = stepstats.snapshot()
-    finally:
-        if not was_armed:
-            stepstats.disarm()
-        span.end()
-        engine.shutdown()
-    return {
-        "model": _model_info(family, cfg, params),
-        "slots": slots,
-        "requests": n_requests,
-        "max_prompt": max_prompt,
-        "max_tokens": max_tokens,
-        "traced": trace_ctx is not None,
-        "generated_tokens": total,
-        "wall_seconds": round(dt, 3),
-        "engine_ragged_tok_s": round(total / dt, 1),
-        "phase_breakdown": snap.get("phases", {}),
-        "busy_fraction": snap.get("busy_fraction"),
-        "dispatch_ms_mean": snap.get("dispatch_ms_mean"),
-        "device_ms_mean": (snap.get("sync") or {}).get(
-            "device_ms_mean"),
-    }
-
-
 def measure_engine_paged(family: str, slots: int = 16,
                          n_requests: int = 48, max_prompt: int = 192,
                          max_tokens: int = 64,
@@ -306,18 +217,17 @@ def measure_engine_paged(family: str, slots: int = 16,
     """Paged-KV engine throughput under a MIXED-LENGTH arrival mix —
     the capacity story of the block pool measured as a bench leg.
 
-    The pool is sized (``pool_tokens``, default = half the dense
-    budget for ``slots`` rows) so a dense engine of the same HBM spend
-    could only configure ``slots/2`` rows; paging runs ``slots`` block
-    tables over it and admission packs by ACTUAL length, so the
-    mixed mix sustains more live slots per byte of KV. Reports
+    A deterministic (seeded) mix of prompt lengths in [8, max_prompt]
+    and token budgets in [8, max_tokens] is submitted all at once.
+    The pool is sized (``pool_tokens``, default = half of ``slots`` x
+    max_seq tokens) so whole rows of the same HBM spend would number
+    ``slots/2``; the engine runs ``slots`` block tables over it and
+    admission packs by ACTUAL length, so the mixed mix sustains more
+    live slots per byte of KV. Reports
     generated tok/s (``engine_paged_tok_s``), the pool high-water
     utilization (``kv_pool_utilization`` — peak blocks in use over
     usable blocks; higher = denser packing of the same HBM), the
-    peak concurrent live slots, and the stepstats phase breakdown
-    (same detail contract as measure_engine_ragged). The request mix
-    is seeded identically to measure_engine_ragged so the two legs
-    stay comparable."""
+    peak concurrent live slots, and the stepstats phase breakdown."""
     from skypilot_tpu.observability import stepstats
     from skypilot_tpu.serve.decode_engine import DecodeEngine
 
@@ -327,7 +237,7 @@ def measure_engine_paged(family: str, slots: int = 16,
     chunk = block_tokens or 64          # tuner-pinnable block size
     max_seq += (-max_seq) % chunk       # keep chunk | max_seq
     budget = pool_tokens or (slots * max_seq) // 2
-    kw = dict(prefill_chunk=chunk, paged=True,
+    kw = dict(prefill_chunk=chunk,
               kv_pool_blocks=budget // chunk + 1, use_manifest=False)
     kw.update(engine_kw or {})
     engine = DecodeEngine(cfg, params, slots=slots, max_seq=max_seq,
@@ -438,7 +348,7 @@ def measure_engine_q8(family: str, slots: int = 16,
             f"({bb_q8} vs {bb_bf16} bytes/block) at the same HBM "
             f"budget — below the 1.8x capacity gate")
 
-    kw = dict(prefill_chunk=chunk, paged=True,
+    kw = dict(prefill_chunk=chunk,
               kv_pool_blocks=q8_blocks,
               kv_quant=True, weight_quant=True, use_manifest=False)
     kw.update(engine_kw or {})
@@ -498,7 +408,7 @@ def measure_engine_spec(family: str, slots: int = 8,
                         **shape_kw) -> Dict[str, Any]:
     """Self-speculative decoding throughput on the chat
     (shared-prefix) mix — the per-request speed lever batching can't
-    reach, measured at the same b8 slot count as the ragged leg.
+    reach, measured at a b8 slot count.
 
     One shared system prompt with deterministic (seeded) unique tails,
     greedy — the production chat shape PR 3's prefix cache targets and
@@ -545,7 +455,7 @@ def measure_engine_spec(family: str, slots: int = 8,
     def run(k):
         engine = DecodeEngine(cfg, params, slots=slots,
                               max_seq=max_seq, prefill_chunk=chunk,
-                              paged=True, spec_k=k,
+                              spec_k=k,
                               spec_ngram=spec_ngram,
                               use_manifest=False)
         engine.start()
@@ -608,7 +518,7 @@ def measure_engine_tp(family: str, tp: int = 2, slots: int = 8,
                       n_requests: int = 24, max_prompt: int = 192,
                       max_tokens: int = 64,
                       **shape_kw) -> Dict[str, Any]:
-    """Tensor-parallel engine throughput under the ragged mix.
+    """Tensor-parallel engine throughput under the mixed-length mix.
 
     The sharded-replica serving path (serve/gang_replica.py): params
     sharded by param_specs, the KV cache by cache_specs, over a
@@ -667,8 +577,7 @@ def measure_engine_prefix(family: str, slots: int = 8,
                           max_unique: int = 32, max_tokens: int = 48,
                           **shape_kw) -> Dict[str, Any]:
     """Engine throughput under shared-prefix traffic through the paged
-    pool's zero-copy prefix cache (the only prefix representation —
-    the dense splice cache is retired).
+    pool's zero-copy prefix cache.
 
     One ``shared_prefix``-token system prompt, a deterministic (seeded)
     unique tail per request. Phase 1 (cold): a single request prefills
@@ -689,7 +598,7 @@ def measure_engine_prefix(family: str, slots: int = 8,
     max_seq = shared_prefix + max_unique + max_tokens
     max_seq += (-max_seq) % chunk       # keep chunk | max_seq
     engine = DecodeEngine(cfg, params, slots=slots, max_seq=max_seq,
-                          prefill_chunk=chunk, paged=True,
+                          prefill_chunk=chunk,
                           use_manifest=False)
     engine.start()
     engine.warmup()
@@ -779,7 +688,7 @@ def measure_engine_tier(family: str, slots: int = 8,
     # live slots' own rows (cold requests run one at a time).
     working_blocks = n_requests * prompt_blocks
     pool_blocks = working_blocks // 2 + 2 * (max_seq // chunk) + 1
-    kw = dict(prefill_chunk=chunk, paged=True,
+    kw = dict(prefill_chunk=chunk,
               kv_pool_blocks=pool_blocks,
               prefix_cache_mb=host_cache_mb, use_manifest=False)
     kw.update(engine_kw or {})
@@ -866,7 +775,7 @@ def measure_engine_slo(family: str, *, slots: int = 8,
     """SLO-graded serving leg: the family's engine behind a REAL
     serve_llm replica and an in-process LB, driven by the open-loop
     load generator (benchmark/loadgen.py) under the shared-prefix chat
-    mix. Unlike measure_engine_ragged (engine in isolation, submit-all
+    mix. Unlike measure_engine_paged (engine in isolation, submit-all
     -at-once), this measures what a USER sees through the whole data
     plane — HTTP parse, LB proxy hop, engine queueing under a Poisson
     arrival process — and grades it against declared TTFT/TPOT SLOs.

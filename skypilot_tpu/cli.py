@@ -546,11 +546,10 @@ def check(paths, rules, as_json, list_rules, env_table, clouds):
               help="Model families to sweep (repeatable; default "
                    "all three).")
 @click.option("--mode", "modes", multiple=True,
-              type=click.Choice(["ragged", "paged", "spec", "q8"]),
+              type=click.Choice(["paged", "spec", "q8"]),
               help="Engine modes to sweep (repeatable; default all). "
-                   "Each mode tunes its own axes: ragged = attention "
-                   "block x prefill chunk, paged/q8 = chunk x gather "
-                   "window, spec = draft depth.")
+                   "Each mode tunes its own axes: paged/q8 = chunk x "
+                   "gather window, spec = draft depth.")
 @click.option("--out", type=click.Path(), default=None,
               help="Manifest output path (default "
                    "~/.stpu/tuning/manifest.json, where the engine "
@@ -568,11 +567,11 @@ def check(paths, rules, as_json, list_rules, env_table, clouds):
 def tune(families, modes, out, quick, tiny, slots):
     """Autotune decode-engine constants into a sha-pinned manifest.
 
-    Sweeps the hand-pinned constants (split-KV attention block,
-    prefill chunk / paged KV block size, paged gather window,
-    speculative draft depth) per (family, batch band, tp, quant
-    mode), measuring each candidate through the same decode_bench
-    legs `stpu bench` records, pruning losers at a small step budget,
+    Sweeps the hand-pinned constants (prefill chunk / KV block
+    size, gather window, speculative draft depth) per (family, batch
+    band, tp, quant mode), measuring each candidate through the same
+    decode_bench legs `stpu bench` records, pruning losers at a small
+    step budget,
     and parity-gating every winner (greedy + seeded engine output
     must be bit-identical to default constants) before persisting.
     Engines pick the manifest up at startup; see STPU_TUNE_MANIFEST

@@ -26,10 +26,12 @@ What differs from llama.py / mixtral.py, and where it lives:
     expert's. Nothing stands in for the absent ranks or their exchange.
 
 Not supported, and refused by name (:func:`refuse`): the int8 pool and
-int8 weights, ``tp > 1`` (a latent row has no head axis to shard), LoRA
-adapters, the dense row cache (``forward_with_cache`` / ``decode``), and
-the multi-token-prediction module (``num_nextn_predict_layers``), which
-takes no part in the next-token forward pass.
+int8 weights, ``tp > 1`` (a latent row has no head axis to shard) and
+LoRA adapters. The multi-token-prediction module
+(``num_nextn_predict_layers``) takes no part in the next-token forward
+pass and is left out. The plain reference the tests hold this module to
+is benchmarks/reference/deepseek_arch.py: there is no row-cache
+``decode`` here.
 """
 from __future__ import annotations
 
@@ -492,11 +494,6 @@ def init_paged_cache(cfg: DeepseekV3Config, num_blocks: int,
             "k_r": jnp.zeros(rows + (key_bytes,), jnp.uint8)}
 
 
-def paged_cache_specs(cfg: DeepseekV3Config) -> Dict[str, tuple]:
-    spec = ("layers", None, None, None)
-    return {"c_kv": spec, "k_r": spec}
-
-
 def _to_bytes(x: jax.Array) -> jax.Array:
     """(..., n) values -> (..., n * itemsize) uint8, PLANAR: every
     value's low byte, then every value's next byte. (Interleaved, as a
@@ -644,16 +641,8 @@ def verify_step_paged(cfg: DeepseekV3Config, params: Params,
     return logits, cache
 
 
-def _no_row_cache(*args, **kwargs):
-    refuse("the dense row cache (forward_with_cache, decode, "
-           "verify_step)", "this family serves through the paged pool "
-           "only (kv_paged=1)")
-
-
 def cache_specs(cfg: DeepseekV3Config):
     """Asked for by gang_replica.cache_shardings alone, to lay a cache
     over a mesh."""
     refuse("tp > 1", "a latent row has no head axis to shard")
 
-
-init_cache = forward_with_cache = verify_step = decode = _no_row_cache

@@ -221,23 +221,20 @@ params_quantized = llama.params_quantized
 # Paged KV block pool (decode-engine paged mode): layout and block-
 # table attention are llama's shared machinery.
 init_paged_cache = llama.init_paged_cache
-paged_cache_specs = llama.paged_cache_specs
 forward_with_paged_cache = llama.forward_with_paged_cache
 
 # Speculative decoding (decode-engine verify path): the multi-token
 # verify window is llama's shared machinery driven by this config's
 # knobs (norm offset, GeGLU, scaled embeddings, MQA cache layout).
-verify_step = llama.verify_step
 verify_step_paged = llama.verify_step_paged
 
 
 def forward_with_cache(cfg: GemmaConfig, params: Params,
                        tokens: jax.Array, cache, start_pos,
-                       valid_len=None, logits_at=None, *,
-                       block: Optional[int] = None):
+                       valid_len=None, logits_at=None):
     return llama.forward_with_cache(cfg, params, tokens, cache,
                                     start_pos, valid_len=valid_len,
-                                    logits_at=logits_at, block=block)
+                                    logits_at=logits_at)
 
 
 def decode(cfg: GemmaConfig, params: Params, prompt: jax.Array,

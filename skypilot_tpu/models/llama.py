@@ -550,16 +550,6 @@ def init_paged_cache(cfg: LlamaConfig, num_blocks: int,
             "v_scale": jnp.zeros(sshape, dtype=jnp.float32)}
 
 
-def paged_cache_specs(cfg: LlamaConfig) -> Dict[str, tuple]:
-    """Logical-axis names for the paged pool. Identical to
-    :func:`cache_specs`: the (layers, num_blocks, block_tokens,
-    kv_heads, head_dim) layout keeps kv_heads at the same axis index
-    as the dense (layers, batch, max_seq, kv_heads, head_dim) cache,
-    so the TP sharding rules — including gang_replica.cache_shardings'
-    head_dim fallback — apply unchanged."""
-    return cache_specs(cfg)
-
-
 def _attn_tile(qf: jax.Array, scale: float, kb: jax.Array,
                vb: jax.Array, msk: jax.Array, m: jax.Array,
                el: jax.Array, acc: jax.Array):
@@ -725,44 +715,26 @@ def _paged_split_kv_attention(qg: jax.Array, li: jax.Array,
 def cached_attention_block(cfg, x: jax.Array, lp: Params,
                            ck: jax.Array, cv: jax.Array,
                            positions: jax.Array, start_pos: jax.Array,
-                           valid_len: jax.Array,
-                           write_pos: Optional[jax.Array] = None,
-                           block: Optional[int] = None):
+                           valid_len: jax.Array):
     """One pre-norm GQA attention residual block against the KV cache
     (shared by llama's and mixtral's decode paths). ``start_pos`` and
     ``valid_len`` are per-slot (B,) vectors — every slot in the batch
-    may sit at a different sequence position (continuous batching).
-    ``write_pos`` (B, T), when given, replaces the contiguous
-    dynamic-update-slice cache write with a per-token row scatter whose
-    out-of-bounds rows are DROPPED — the speculative verify_step write
-    path, where a slot's draft tail may be shorter than the batch's
-    static T (junk columns carry a sentinel >= max_seq and write
-    nothing, so a short-draft slot can never clobber valid rows the
-    way a clamped dynamic_update_slice would).
+    may sit at a different sequence position.
     Returns (x + attn_out, updated ck, updated cv)."""
     b, t = x.shape[0], x.shape[1]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps,
                  getattr(cfg, "norm_offset", 0.0))
     q, k_new, v_new = qkv_proj(cfg, y, lp, positions)
-    if write_pos is None:
-        upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u,
-                                                           (s, 0, 0))
-        ck = jax.vmap(upd)(ck, k_new.astype(ck.dtype), start_pos)
-        cv = jax.vmap(upd)(cv, v_new.astype(cv.dtype), start_pos)
-    else:
-        b_iota = jnp.arange(b)[:, None]
-        ck = ck.at[b_iota, write_pos].set(k_new.astype(ck.dtype),
-                                          mode="drop")
-        cv = cv.at[b_iota, write_pos].set(v_new.astype(cv.dtype),
-                                          mode="drop")
+    upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
+    ck = jax.vmap(upd)(ck, k_new.astype(ck.dtype), start_pos)
+    cv = jax.vmap(upd)(cv, v_new.astype(cv.dtype), start_pos)
     # GQA grouped attention against the UNEXPANDED cache (the head-
     # order convention of ops/attention.py): q regrouped per KV head
     # so no repeat()ed copy of the cache hits HBM on the hot path.
     groups = h // kvh
     qg = q.reshape(b, t, kvh, groups, hd)
-    attn = _split_kv_attention(qg, ck, cv, positions, valid_len,
-                               block)
+    attn = _split_kv_attention(qg, ck, cv, positions, valid_len)
     attn = attn.astype(x.dtype).reshape(b, t, h * hd)
     return x + lora_dense(attn, lp, "wo"), ck, cv
 
@@ -801,8 +773,7 @@ def forward_with_cache(cfg, params: Params,
                        start_pos: jax.Array,
                        valid_len: Optional[jax.Array] = None,
                        logits_at: Optional[jax.Array] = None, *,
-                       write_pos: Optional[jax.Array] = None,
-                       mlp_fn=None, block: Optional[int] = None
+                       mlp_fn=None
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Incremental forward: process a chunk, reading/writing the cache.
 
@@ -824,12 +795,7 @@ def forward_with_cache(cfg, params: Params,
     true length so padding K/V never becomes attendable (padding slots
     are overwritten by later decode steps before valid_len reaches
     them). ``logits_at`` (chunk-relative index) computes the lm_head at
-    just that position, returning (B, 1, vocab). ``block`` (static)
-    overrides the split-KV attention tile width — the autotuner's
-    dense-path knob; None keeps the SPLIT_KV_BLOCK default. Any
-    aligned tile width is bit-identical (the online softmax is
-    exact), so this is a perf knob, not a numerics one — the tuner's
-    parity gate proves it per winner anyway.
+    just that position, returning (B, 1, vocab).
     """
     b, t = tokens.shape
     start_pos, valid_len, positions = slot_positions(b, t, start_pos,
@@ -845,9 +811,7 @@ def forward_with_cache(cfg, params: Params,
         lp, ck, cv = scanned                               # per-layer
         x2, ck, cv = cached_attention_block(cfg, x, lp, ck, cv,
                                             positions, start_pos,
-                                            valid_len,
-                                            write_pos=write_pos,
-                                            block=block)
+                                            valid_len)
         return mlp_fn(cfg, x2, lp), (ck, cv)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -1099,55 +1063,12 @@ def _verify_write_positions(t: int, start_pos: jax.Array,
     """(B, T) cache-write positions for a speculative verify window:
     column j of slot b lands at start_pos[b] + j while j <= spec_len[b]
     (the slot's real token + its drafts) and at the out-of-range
-    sentinel ``span`` past its draft tail — dense scatters DROP those
-    rows, the paged scatter routes them to the scratch block. Either
-    way a short-draft slot's junk columns write nothing attendable."""
+    sentinel ``span`` past its draft tail, which the paged scatter
+    routes to the scratch block: a short-draft slot's junk columns
+    write nothing attendable."""
     offs = jnp.arange(t)[None, :]
     wpos = start_pos[:, None] + offs
     return jnp.where(offs <= spec_len[:, None], wpos, span)
-
-
-def verify_step(cfg, params: Params, tokens: jax.Array,
-                cache: Dict[str, jax.Array], start_pos: jax.Array,
-                spec_len: jax.Array, *, mlp_fn=None,
-                block: Optional[int] = None
-                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Multi-token speculative verification against the dense cache.
-
-    ``tokens`` (B, T) is, per slot, its last emitted token followed by
-    up to T-1 drafted tokens (``spec_len`` (B,) real drafts; the tail
-    is padding). One forward computes logits at ALL T positions —
-    column j is the target distribution for the token at absolute
-    position ``start_pos + j + 1``, conditioned on the draft prefix
-    whose K/V this same pass wrote — which is what lets the engine
-    accept k drafted tokens for the price of one memory-bound pass
-    (the per-slot (B,) start_pos/valid_len contract generalized to a
-    per-slot (B, T) logits-at-positions read-out).
-
-    Writes scatter per token with out-of-bounds DROP semantics
-    (:func:`_verify_write_positions`), so rejected/padded suffixes
-    never land where a clamped dynamic_update_slice would corrupt
-    valid rows; ``valid_len = start_pos + spec_len + 1`` masks each
-    slot's junk columns out of every other query. The engine rolls a
-    rejected suffix back host-side by simply not advancing ``pos``
-    past the accepted frontier — rows beyond it are stale-masked, the
-    exact invariant slot reuse already relies on.
-
-    Returns (logits (B, T, vocab), cache).
-    """
-    b, t = tokens.shape
-    start_pos = jnp.asarray(start_pos, jnp.int32)
-    if start_pos.ndim == 0:
-        start_pos = jnp.broadcast_to(start_pos, (b,))
-    spec_len = jnp.asarray(spec_len, jnp.int32)
-    if spec_len.ndim == 0:
-        spec_len = jnp.broadcast_to(spec_len, (b,))
-    max_seq = cache["k"].shape[2]
-    wpos = _verify_write_positions(t, start_pos, spec_len, max_seq)
-    return forward_with_cache(
-        cfg, params, tokens, cache, start_pos,
-        valid_len=start_pos + spec_len + 1, write_pos=wpos,
-        mlp_fn=mlp_fn, block=block)
 
 
 def verify_step_paged(cfg, params: Params, tokens: jax.Array,
@@ -1155,13 +1076,25 @@ def verify_step_paged(cfg, params: Params, tokens: jax.Array,
                       start_pos: jax.Array, spec_len: jax.Array, *,
                       window: int, mlp_fn=None
                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """:func:`verify_step` against the paged block pool: the same
-    (B, T) verify window with writes scattered THROUGH each slot's
-    block table (junk columns route to the scratch block) and
-    attention gathered by :func:`_paged_split_kv_attention`. The
-    engine backs the window's blocks from the slot's admission
-    reservation before the call and truncates the rejected suffix's
-    blocks back afterwards."""
+    """Multi-token speculative verification against the paged pool.
+
+    ``tokens`` (B, T) is, per slot, its last emitted token followed by
+    up to T-1 drafted tokens (``spec_len`` (B,) real drafts; the tail
+    is padding). One forward computes logits at ALL T positions —
+    column j is the target distribution for the token at absolute
+    position ``start_pos + j + 1``, conditioned on the draft prefix
+    whose K/V this same pass wrote — which is what lets the engine
+    accept k drafted tokens for the price of one memory-bound pass.
+
+    Writes scatter THROUGH each slot's block table
+    (:func:`_verify_write_positions`: junk columns route to the scratch
+    block), attention gathers by :func:`_paged_split_kv_attention`, and
+    ``valid_len = start_pos + spec_len + 1`` masks each slot's junk
+    columns out of every other query. The engine backs the window's
+    blocks from the slot's admission reservation before the call and
+    truncates the rejected suffix's blocks back afterwards.
+
+    Returns (logits (B, T, vocab), pool)."""
     b, t = tokens.shape
     start_pos = jnp.asarray(start_pos, jnp.int32)
     if start_pos.ndim == 0:

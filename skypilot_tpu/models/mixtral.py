@@ -303,10 +303,9 @@ def init_cache(cfg: MixtralConfig, batch: int, max_seq: int):
 
 cache_specs = llama.cache_specs
 
-# Paged KV block pool: llama's layout/specs, experts add no per-token
-# cache state.
+# Paged KV block pool: llama's layout (and, above, its specs), experts
+# add no per-token cache state.
 init_paged_cache = llama.init_paged_cache
-paged_cache_specs = llama.paged_cache_specs
 
 
 def _moe_block(cfg: MixtralConfig, x: jax.Array, lp: Params) -> jax.Array:
@@ -318,8 +317,7 @@ def _moe_block(cfg: MixtralConfig, x: jax.Array, lp: Params) -> jax.Array:
 def forward_with_cache(cfg: MixtralConfig, params: Params,
                        tokens: jax.Array, cache, start_pos: jax.Array,
                        valid_len: Optional[jax.Array] = None,
-                       logits_at: Optional[jax.Array] = None, *,
-                       block: Optional[int] = None):
+                       logits_at: Optional[jax.Array] = None):
     """Incremental MoE forward: llama's cache loop (attention/mask
     contract lives there, in one place) with the dense-routed top-2
     expert MLP swapped in — the serving loop the reference delegates to
@@ -328,7 +326,7 @@ def forward_with_cache(cfg: MixtralConfig, params: Params,
     llama.forward_with_cache."""
     return llama.forward_with_cache(
         cfg, params, tokens, cache, start_pos, valid_len=valid_len,
-        logits_at=logits_at, mlp_fn=_moe_block, block=block)
+        logits_at=logits_at, mlp_fn=_moe_block)
 
 
 def forward_with_paged_cache(cfg: MixtralConfig, params: Params,
@@ -343,17 +341,6 @@ def forward_with_paged_cache(cfg: MixtralConfig, params: Params,
         cfg, params, tokens, cache, table, start_pos,
         valid_len=valid_len, logits_at=logits_at, window=window,
         write_block=write_block, mlp_fn=_moe_block)
-
-
-def verify_step(cfg: MixtralConfig, params: Params, tokens: jax.Array,
-                cache, start_pos, spec_len, *,
-                block: Optional[int] = None):
-    """Multi-token speculative verification for Mixtral: llama's dense
-    verify window with the dense-routed top-2 expert MLP swapped in —
-    per-token dense routing is composition-independent, so a verify
-    column's logits equal the 1-token step's by construction."""
-    return llama.verify_step(cfg, params, tokens, cache, start_pos,
-                             spec_len, mlp_fn=_moe_block, block=block)
 
 
 def verify_step_paged(cfg: MixtralConfig, params: Params,

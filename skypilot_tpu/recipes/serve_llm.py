@@ -12,13 +12,10 @@ endpoints SkyServe probes and balances:
                      "temperature": 0.7, "seed": 1} -> {"tokens": [...]}
 
 Requests are served by the slot-based continuous-batching decode engine
-(serve/decode_engine.py): concurrent requests of ANY prompt length
-share one KV cache batch, joining mid-flight into free slots (chunked
-prefill interleaved with decode) and streaming per slot — no
-model-lock-per-request serialization, no same-bucket-only batching.
-``engine_slots=0`` falls back to the legacy locked fixed-batch path
-(kept for apples-to-apples measurement; both paths donate their KV
-cache through the jit boundary).
+(serve/decode_engine.py), the only path from a request to a model:
+concurrent requests of ANY prompt length share one paged KV pool,
+joining mid-flight into free slots (chunked prefill interleaved with
+decode) and streaming per slot.
 
     python -m skypilot_tpu.recipes.serve_llm --model tiny --port 8080
 """
@@ -56,13 +53,10 @@ from skypilot_tpu.utils import compile_cache
 from skypilot_tpu.utils import fault_injection
 
 
-# Request limits: prompt/decode lengths are padded to buckets so the jit
-# cache stays bounded (≤ len(buckets) × len(mt buckets) compiles) and a
-# hostile request cannot trigger unbounded allocation or a giant scan.
-PROMPT_BUCKET = 64
+# Request limits: a hostile request cannot trigger unbounded allocation,
+# and their sum is the engine's max_seq (what the pool is sized by).
 MAX_PROMPT_TOKENS = 1024
 MAX_GEN_TOKENS = 256
-GEN_BUCKET = 16
 
 # Engine defaults (overridable per serve() call / env). The prefill
 # chunk deliberately has NO constant here: the recipe leaves it at
@@ -71,31 +65,26 @@ GEN_BUCKET = 16
 # a literal here was exactly the three-call-site drift magnet the
 # autotuner PR removed.
 ENGINE_SLOTS = int(os.environ.get("STPU_ENGINE_SLOTS", "4"))
+NO_ENGINE_SLOTS = ("engine slots must be at least 1: every request is "
+                   "served by the decode engine")
 # Host-RAM KV spill tier budget (MiB) under the paged pool's trie:
 # LRU-evicted prefix blocks spill D2H into a bounded host pool and
 # re-admit H2D on a warm match, so the effective prefix cache grows
 # from the HBM pool to host RAM at the cost of one block transfer per
 # re-hit. 0 turns the tier off (evictions drop the leaf); default on
-# at 64 MiB. Ignored by the dense engine (no trie, no tier).
+# at 64 MiB.
 ENGINE_PREFIX_CACHE_MB = float(
     os.environ.get("STPU_PREFIX_CACHE_MB", "64"))
-# Paged KV block pool (decode_engine paged mode): one device-resident
-# pool + per-slot block tables instead of dense per-slot cache rows —
-# admission is free-block based and prefix hits alias blocks
-# zero-copy. ON by default (bit-identical to dense, pinned by
-# tests/test_paged_kv.py); STPU_KV_PAGED=0 keeps the dense path
-# selectable for parity debugging (no prefix cache there).
-ENGINE_KV_PAGED = os.environ.get("STPU_KV_PAGED", "1") == "1"
-# 0 = auto-size the pool to the dense HBM budget
-# (slots * max_seq / block + 1 scratch; doubled under KV_QUANT —
-# int8 blocks are ~half the bytes).
+# The KV block pool's size in blocks. 0 = auto-size: slots * max_seq /
+# block + 1 scratch; doubled under KV_QUANT — int8 blocks are ~half
+# the bytes.
 ENGINE_KV_POOL_BLOCKS = int(os.environ.get("STPU_KV_POOL_BLOCKS", "0"))
 # 0 = block size follows the prefill chunk (64).
 ENGINE_KV_BLOCK_TOKENS = int(
     os.environ.get("STPU_KV_BLOCK_TOKENS", "0"))
 # Quantized serving (decode_engine quant mode): KV_QUANT stores int8
-# KV blocks + per-(layer, block, head) f32 scales in the paged pool
-# (~2x block capacity at the same HBM budget; requires KV_PAGED);
+# KV blocks + per-(layer, block, head) f32 scales in the pool (~2x
+# block capacity at the same HBM budget);
 # WEIGHT_QUANT serves int8 per-channel-scaled params. NOT
 # bit-identical to bf16 — gated by the tests/test_quant.py parity
 # suite (top-1 agreement + perplexity bound per family).
@@ -149,12 +138,8 @@ _PREEMPT_NOTICES = metrics.counter(
     "replace-ahead trigger for the controller.")
 
 
-def _ceil_to(n: int, b: int) -> int:
-    return ((n + b - 1) // b) * b
-
-
 def _device_memory(param_bytes: dict, engine) -> list:
-    kv_bytes = engine.cache_bytes_per_device() if engine else {}
+    kv_bytes = engine.cache_bytes_per_device()
     rows = []
     for d in jax.local_devices():
         stats = d.memory_stats() or {}
@@ -165,76 +150,6 @@ def _device_memory(param_bytes: dict, engine) -> list:
                      "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
                      "bytes_limit": stats.get("bytes_limit")})
     return rows
-
-
-def _pick(logits_row: jax.Array, temperature: float,
-          key: jax.Array) -> jax.Array:
-    if temperature > 0.0:
-        return jax.random.categorical(
-            key, logits_row / temperature, axis=-1).astype(jnp.int32)
-    return jnp.argmax(logits_row, axis=-1).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3, 5))
-def _prefill(cfg: llama.LlamaConfig, params, buf: jax.Array,
-             max_seq: int, start: jax.Array, temperature: float,
-             key: jax.Array):
-    """Legacy streaming path, step 1: one O(S) prefill over the padded
-    prompt; returns (first token (1,), KV cache). Shapes are bucket
-    sizes so all prompts in a bucket share one compile."""
-    api = model_api(cfg)
-    cache = api.init_cache(cfg, 1, max_seq)
-    logits, cache = api.forward_with_cache(
-        cfg, params, buf[None, :], cache, jnp.int32(0), valid_len=start,
-        logits_at=jnp.asarray(start - 1, jnp.int32))
-    return _pick(logits[:, 0], temperature, key), cache
-
-
-@functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=(3,))
-def _gen_step(cfg: llama.LlamaConfig, params, tok: jax.Array, cache,
-              pos: jax.Array, temperature: float, key: jax.Array):
-    """Legacy streaming path, step 2..N: one cached decode step —
-    called per token so the handler can flush each token to the client
-    as it exists (SSE). The KV cache is DONATED: XLA aliases it in
-    place instead of copying the whole O(layers * max_seq) buffer every
-    token."""
-    logits, cache = model_api(cfg).forward_with_cache(
-        cfg, params, tok[:, None], cache, pos)
-    return _pick(logits[:, -1], temperature, key), cache
-
-
-@functools.partial(jax.jit, static_argnums=(0, 4, 5),
-                   donate_argnums=(6,))
-def _decode(cfg: llama.LlamaConfig, params, buf: jax.Array,
-            start: jax.Array, mt_pad: int,
-            temperature: float, cache, seed: jax.Array) -> jax.Array:
-    """Legacy fixed-batch continuation over a padded prompt buffer.
-
-    buf: (s_pad,) int32 with the prompt in [0, start). Shapes are bucket
-    sizes and the true prompt length is a dynamic scalar, so all prompts
-    in a bucket share one compile (plus one per distinct temperature).
-    ``cache`` is allocated by the caller, DONATED, and returned (so XLA
-    can alias it to the output) — the decode scan updates it in place
-    instead of materializing a second full-size cache in HBM each step.
-    Returns (tokens (mt_pad,), cache).
-    """
-    max_seq = buf.shape[0] + mt_pad
-    toks, cache = model_api(cfg).decode(
-        cfg, params, buf[None, :], start, mt_pad, max_seq,
-        temperature=temperature, key=jax.random.key(seed),
-        cache=cache, return_cache=True)
-    return toks[0], cache
-
-
-def _decode_locked(ctx, buf, s, mt_pad, temperature, seed):
-    """Legacy path: allocate + donate a fresh cache under the model
-    lock (the returned cache exists only for donation aliasing)."""
-    cfg = ctx["cfg"]
-    cache = model_api(cfg).init_cache(cfg, 1, buf.shape[0] + mt_pad)
-    with ctx["lock"]:
-        toks, _ = _decode(cfg, ctx["params"], buf, jnp.int32(s),
-                          mt_pad, temperature, cache, jnp.uint32(seed))
-        return toks
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -256,7 +171,7 @@ class _Handler(BaseHTTPRequestHandler):
         if self.path in ("/", "/health"):
             ctx = self.server_ctx
             ready = ctx["ready"].is_set()
-            engine = ctx.get("engine")
+            engine = ctx["engine"]
             gang = ctx.get("gang")
             if ctx["warmup_error"]:
                 # Terminal: the warm-up raised (the compiler refused a
@@ -272,7 +187,7 @@ class _Handler(BaseHTTPRequestHandler):
                 # monitor), so a dead follower can never hide behind a
                 # READY replica serving partial-gang garbage.
                 self._json(503, {"status": "gang_degraded"})
-            elif engine is not None and not engine.healthy():
+            elif not engine.healthy():
                 # The readiness probe must tell the truth about the
                 # ENGINE, not just the HTTP process: a dead/restarting
                 # engine behind a 200 probe is a zombie replica that
@@ -329,55 +244,50 @@ class _Handler(BaseHTTPRequestHandler):
         # bytes are: per device, the parameter and KV bytes resident
         # there (from the arrays' shards) and the allocator's own
         # counters (None where the backend keeps none, e.g. the CPU).
+        engine = ctx["engine"]
         doc["device"] = dict(ctx["device"], memory=_device_memory(
-            ctx["param_bytes"], ctx.get("engine")))
-        engine = ctx.get("engine")
-        if engine is not None:
-            doc["engine"] = {
-                "healthy": engine.healthy(),
-                "in_flight": engine.in_flight(),
-                "draining": engine.draining(),
-                "restarts": getattr(engine, "restarts", 0),
+            ctx["param_bytes"], engine))
+        doc["engine"] = {
+            "healthy": engine.healthy(),
+            "in_flight": engine.in_flight(),
+            "draining": engine.draining(),
+            "restarts": engine.restarts,
+        }
+        kv = engine.kv_config()
+        if kv:
+            # Quant mode line for `stpu perf`: which int8 paths
+            # this replica serves with (resolve_kv_geometry output
+            # — the same dict the gang handshake compares).
+            doc["quant"] = {
+                "kv_quant": int(kv.get("kv_quant", 0)),
+                "weight_quant": int(kv.get("weight_quant", 0)),
+                "pool_blocks": int(kv.get("pool_blocks", 0)),
             }
-            kv = engine.kv_config()
-            if kv:
-                # Quant mode line for `stpu perf`: which int8 paths
-                # this replica serves with (resolve_kv_geometry output
-                # — the same dict the gang handshake compares).
-                doc["quant"] = {
-                    "kv_quant": int(kv.get("kv_quant", 0)),
-                    "weight_quant": int(kv.get("weight_quant", 0)),
-                    "pool_blocks": int(kv.get("pool_blocks", 0)),
-                }
-                # Tuning line for `stpu perf`: the constants this
-                # replica actually decodes with and which manifest
-                # (payload-sha tag, or "default") supplied them.
-                doc["tuning"] = {
-                    "block": int(kv.get("block", 0)),
-                    "chunk": int(kv.get("chunk", 0)),
-                    "window": int(kv.get("window", 0)),
-                    "spec_k": int(kv.get("spec_k", 0)),
-                    "manifest": kv.get("manifest", "default"),
-                }
-            # Host KV tier line for `stpu perf`: spill/re-admit and
-            # residency counters from the engine's HostBlockPool
-            # (absent while the tier is off).
-            tier = {}
-            get_tier = getattr(engine, "host_tier_stats", None)
-            if callable(get_tier):
-                tier = get_tier() or {}
-            if tier:
-                doc["tier"] = {
-                    "budget_mb": float(tier.get("budget_mb", 0.0)),
-                    "bytes": int(tier.get("bytes", 0)),
-                    "blocks": int(tier.get("blocks", 0)),
-                    "spilled": int(tier.get("spilled", 0)),
-                    "dropped": int(tier.get("evict_drops", 0)),
-                    "lru_dropped": int(tier.get("lru_dropped", 0)),
-                    "readmitted": int(tier.get("readmitted_blocks",
-                                               0)),
-                    "rehits": int(tier.get("rehits", 0)),
-                }
+            # Tuning line for `stpu perf`: the constants this
+            # replica actually decodes with and which manifest
+            # (payload-sha tag, or "default") supplied them.
+            doc["tuning"] = {
+                "block": int(kv.get("block", 0)),
+                "chunk": int(kv.get("chunk", 0)),
+                "window": int(kv.get("window", 0)),
+                "spec_k": int(kv.get("spec_k", 0)),
+                "manifest": kv.get("manifest", "default"),
+            }
+        # Host KV tier line for `stpu perf`: spill/re-admit and
+        # residency counters from the engine's HostBlockPool
+        # (absent while the tier is off).
+        tier = engine.host_tier_stats()
+        if tier:
+            doc["tier"] = {
+                "budget_mb": float(tier.get("budget_mb", 0.0)),
+                "bytes": int(tier.get("bytes", 0)),
+                "blocks": int(tier.get("blocks", 0)),
+                "spilled": int(tier.get("spilled", 0)),
+                "dropped": int(tier.get("evict_drops", 0)),
+                "lru_dropped": int(tier.get("lru_dropped", 0)),
+                "readmitted": int(tier.get("readmitted_blocks", 0)),
+                "rehits": int(tier.get("rehits", 0)),
+            }
         return doc
 
     def _start_profile(self) -> None:
@@ -421,17 +331,13 @@ class _Handler(BaseHTTPRequestHandler):
         ctx = self.server_ctx
         with ctx["inflight_lock"]:
             handler_inflight = ctx["inflight"][0]
-        engine = ctx.get("engine")
-        if engine is not None:
-            # The engine's slot count hits zero while a handler thread
-            # may still be FLUSHING queued tokens to a slow client —
-            # the handler count covers that tail, so report the max of
-            # the two views or a drain could truncate a live stream.
-            return {"draining": engine.draining(),
-                    "in_flight": max(engine.in_flight(),
-                                     handler_inflight)}
-        return {"draining": ctx["draining"].is_set(),
-                "in_flight": handler_inflight}
+        engine = ctx["engine"]
+        # The engine's slot count hits zero while a handler thread
+        # may still be FLUSHING queued tokens to a slow client — the
+        # handler count covers that tail, so report the max of the
+        # two views or a drain could truncate a live stream.
+        return {"draining": engine.draining(),
+                "in_flight": max(engine.in_flight(), handler_inflight)}
 
     def _start_drain(self) -> None:
         """POST /drain: stop admitting new generations, report what is
@@ -439,10 +345,7 @@ class _Handler(BaseHTTPRequestHandler):
         in_flight hits 0 (or its deadline) before terminating, so live
         token streams finish instead of truncating mid-rollout."""
         ctx = self.server_ctx
-        ctx["draining"].set()
-        engine = ctx.get("engine")
-        if engine is not None:
-            engine.drain()
+        ctx["engine"].drain()
         gang = ctx.get("gang")
         if gang is not None:
             # Drain is gang-wide: follower engines stop admitting too,
@@ -465,10 +368,10 @@ class _Handler(BaseHTTPRequestHandler):
         if self.path != "/generate":
             self._json(404, {"error": "not found"})
             return
-        if self.server_ctx["draining"].is_set():
-            # Engine-path submits would raise EngineError anyway; this
-            # also covers the legacy path and keeps the refusal shape
-            # uniform (503 → the LB retries on a non-draining peer).
+        if self.server_ctx["engine"].draining():
+            # A submit would raise EngineError anyway; this answers
+            # before the body is parsed (503 → the LB retries on a
+            # non-draining peer).
             self._json(503, {"error": "replica draining"})
             return
         try:
@@ -511,13 +414,7 @@ class _Handler(BaseHTTPRequestHandler):
         except (KeyError, ValueError, TypeError) as e:
             self._json(400, {"error": str(e)})
             return
-        engine = ctx.get("engine")
-        if resume is not None and engine is None:
-            # The legacy locked path has no absolute-position sampling
-            # contract to resume into; only engine replicas honor it.
-            self._json(400, {"error": "resume requires the decode "
-                                      "engine (engine_slots > 0)"})
-            return
+        engine = ctx["engine"]
         # Replica hop of the request's trace, continued from the LB's
         # X-STPU-Trace header (tracing.ENABLED guard = zero tracing
         # cost unarmed); the engine parents its queue/prefill/decode
@@ -529,20 +426,15 @@ class _Handler(BaseHTTPRequestHandler):
                 parent=tracing.extract(self.headers),
                 attrs={"prompt_tokens": len(prompt), "max_tokens": mt,
                        "stream": stream,
-                       "resume": len(resume) if resume else 0,
-                       "engine": engine is not None})
-        # Legacy-path in-flight accounting (the engine tracks its own):
-        # GET /drain must see requests this handler is still streaming.
+                       "resume": len(resume) if resume else 0})
+        # GET /drain must see requests this handler is still streaming
+        # after the engine has handed their last token over.
         with ctx["inflight_lock"]:
             ctx["inflight"][0] += 1
         status = "error"
         try:
-            if engine is not None:
-                self._engine_generate(engine, prompt, mt, temperature,
-                                      seed, stream, span, resume)
-            else:
-                self._legacy_generate(ctx, prompt, mt, temperature,
-                                      seed, stream, span)
+            self._engine_generate(engine, prompt, mt, temperature,
+                                  seed, stream, span, resume)
             status = "ok"
         except decode_engine.EngineError as e:
             if span is not None:
@@ -551,10 +443,9 @@ class _Handler(BaseHTTPRequestHandler):
         except (KeyError, ValueError, TypeError) as e:
             self._json(400, {"error": str(e)})
         except Exception as e:  # noqa: BLE001 — pre-header failures
-            # (jit compile/runtime errors on a fresh bucket) must still
-            # produce a clean JSON error; once headers are out, _sse
-            # has already swallowed the exception and dropped the
-            # connection, so this catch never corrupts a stream.
+            # must still produce a clean JSON error; once headers are
+            # out, _sse has already swallowed the exception and dropped
+            # the connection, so this catch never corrupts a stream.
             self._json(500, {"error": f"{type(e).__name__}: {e}"})
         finally:
             with ctx["inflight_lock"]:
@@ -626,44 +517,6 @@ class _Handler(BaseHTTPRequestHandler):
         self._sse(req, [first], it, span,
                   resume_len=len(resume) if resume else 0)
 
-    # ----------------------------------------------------- legacy path
-    def _legacy_generate(self, ctx, prompt, mt, temperature, seed,
-                         stream, span=None) -> None:
-        s = len(prompt)
-        s_pad = _ceil_to(s, PROMPT_BUCKET)
-        mt_pad = _ceil_to(mt, GEN_BUCKET)
-        buf = jnp.zeros((s_pad,), jnp.int32).at[:s].set(
-            jnp.asarray(prompt, dtype=jnp.int32))
-        if not stream:
-            toks = _decode_locked(ctx, buf, s, mt_pad, temperature,
-                                  seed)
-            self._json(200, {"tokens": [int(t) for t in toks[:mt]]})
-            return
-        cfg, params = ctx["cfg"], ctx["params"]
-        key = jax.random.key(seed)
-        # Prefill BEFORE the headers go out (clean-error contract, as
-        # above). The model lock is held ONLY around compute, never
-        # across socket writes — a stalled client (TCP backpressure on
-        # emit) must not block other requests.
-        key, k = jax.random.split(key)
-        with ctx["lock"]:
-            tok, cache = _prefill(cfg, params, buf, s_pad + mt_pad,
-                                  jnp.int32(s), temperature, k)
-            tok.block_until_ready()
-
-        def tokens():
-            nonlocal tok, cache, key
-            for i in range(mt - 1):
-                key, k2 = jax.random.split(key)
-                with ctx["lock"]:
-                    tok, cache = _gen_step(cfg, params, tok, cache,
-                                           jnp.int32(s + i),
-                                           temperature, k2)
-                    tok.block_until_ready()
-                yield int(tok[0])
-
-        self._sse(None, [int(tok[0])], tokens(), span)
-
     # ------------------------------------------------------------- SSE
     def _sse(self, req, first_tokens, rest_iter, span=None,
              resume_len: int = 0) -> None:
@@ -697,7 +550,7 @@ class _Handler(BaseHTTPRequestHandler):
             for tok in rest_iter:
                 emit(json.dumps({"token": int(tok)}))
                 sent += 1
-            if reqlog.ENABLED and req is not None:
+            if reqlog.ENABLED:
                 self._emit_stats_frame(req)
             emit("[DONE]")
             end_chunks(self.wfile)
@@ -707,8 +560,7 @@ class _Handler(BaseHTTPRequestHandler):
                                     span.context(), start_mono=t0,
                                     attrs={"tokens": sent})
         except Exception:  # noqa: BLE001 — client gone / engine died
-            if req is not None:
-                req.cancel()  # free the slot; don't decode into a void
+            req.cancel()  # free the slot; don't decode into a void
             self.close_connection = True
             if span is not None:
                 tracing.record_span("replica.stream", "replica",
@@ -732,13 +584,11 @@ class _Handler(BaseHTTPRequestHandler):
         half = getattr(req, "reqlog_record", None)
         if half is None:
             return
-        engine = self.server_ctx.get("engine")
-        if engine is not None:
-            kv = engine.kv_config()
-            half["kv_quant"] = bool(kv.get("kv_quant"))
-            half["weight_quant"] = bool(kv.get("weight_quant"))
-            half["kv_paged"] = bool(kv.get("paged"))
-            half["restarts"] = int(getattr(engine, "restarts", 0))
+        engine = self.server_ctx["engine"]
+        kv = engine.kv_config()
+        half["kv_quant"] = bool(kv.get("kv_quant"))
+        half["weight_quant"] = bool(kv.get("weight_quant"))
+        half["restarts"] = engine.restarts
         write_chunk(self.wfile,
                     b"event: stats\ndata: "
                     + json.dumps(half, default=str).encode()
@@ -780,7 +630,6 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
           topology: "gang_replica.ReplicaTopology" = None,
           mesh=None, rules=None,
           gang: "gang_replica.GangLeader" = None,
-          kv_paged: bool = None,
           kv_pool_blocks: int = None,
           kv_block_tokens: int = None,
           kv_quant: bool = None,
@@ -789,14 +638,13 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
           spec_ngram: int = None,
           spec_min_accept: float = None
           ) -> ThreadingHTTPServer:
-    """Start the replica server. ``engine_slots`` > 0 (default: env
-    STPU_ENGINE_SLOTS or 4) serves through the continuous-batching
-    decode engine; 0 keeps the legacy locked fixed-batch path.
+    """Start the replica server: requests are served through the
+    continuous-batching decode engine with ``engine_slots`` slots
+    (default: env STPU_ENGINE_SLOTS or 4; at least 1).
     ``prefix_cache_mb`` (default: env STPU_PREFIX_CACHE_MB or 64) is
-    the host-RAM KV spill tier budget in MiB under the paged pool's
-    trie — evicted prefix blocks spill D2H and re-admit H2D on a warm
-    match; 0 turns the tier off (dense mode has no trie and ignores
-    it).
+    the host-RAM KV spill tier budget in MiB under the pool's trie —
+    evicted prefix blocks spill D2H and re-admit H2D on a warm match;
+    0 turns the tier off.
     ``stream_timeout`` (default: env STPU_STREAM_TIMEOUT or 600) is the
     per-token wait before a wedged engine surfaces as a clean error.
     ``kv_quant``/``weight_quant`` (default: env STPU_KV_QUANT /
@@ -817,6 +665,8 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
     an engine crash-restart restarts every host's engine."""
     if engine_slots is None:
         engine_slots = ENGINE_SLOTS
+    if engine_slots < 1:
+        raise ValueError(NO_ENGINE_SLOTS)
     if prefix_cache_mb is None:
         prefix_cache_mb = ENGINE_PREFIX_CACHE_MB
     if stream_timeout is None:
@@ -825,8 +675,6 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
         engine_max_restarts = ENGINE_MAX_RESTARTS
     if engine_restart_backoff is None:
         engine_restart_backoff = ENGINE_RESTART_BACKOFF
-    if kv_paged is None:
-        kv_paged = ENGINE_KV_PAGED
     if kv_pool_blocks is None:
         kv_pool_blocks = ENGINE_KV_POOL_BLOCKS
     if kv_block_tokens is None:
@@ -841,10 +689,8 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
         spec_ngram = ENGINE_SPEC_NGRAM
     if spec_min_accept is None:
         spec_min_accept = ENGINE_SPEC_MIN_ACCEPT
-    ctx = {"cfg": cfg, "params": params, "lock": threading.Lock(),
-           "ready": ready_event or threading.Event(), "engine": None,
-           "stream_timeout": float(stream_timeout),
-           "draining": threading.Event(), "gang": gang,
+    ctx = {"ready": ready_event or threading.Event(),
+           "stream_timeout": float(stream_timeout), "gang": gang,
            "warmup_error": None, "device": mesh_lib.device_info(),
            "param_bytes": mesh_lib.bytes_per_device(params),
            "gang_admit_lock": threading.Lock(),
@@ -853,32 +699,30 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
     _TOPOLOGY_INFO.labels(
         hosts=str(topology.hosts if topology else 1),
         tp=str(topology.tp if topology else 1)).set(1)
-    if engine_slots > 0:
-        first_build = [True]
+    first_build = [True]
 
-        def _engine_factory():
-            if gang is not None and not first_build[0]:
-                # Supervisor crash-restart: followers rebuild in
-                # lockstep or the gang serves from desynced caches.
-                gang.broadcast_restart()
-            first_build[0] = False
-            return decode_engine.DecodeEngine(
-                cfg, params, slots=engine_slots,
-                max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
-                prefix_cache_mb=prefix_cache_mb,
-                mesh=mesh, rules=rules,
-                paged=bool(kv_paged),
-                kv_pool_blocks=int(kv_pool_blocks),
-                kv_block_tokens=int(kv_block_tokens),
-                kv_quant=bool(kv_quant),
-                weight_quant=bool(weight_quant),
-                spec_k=int(spec_k),
-                spec_ngram=int(spec_ngram),
-                spec_min_accept=float(spec_min_accept))
+    def _engine_factory():
+        if gang is not None and not first_build[0]:
+            # Supervisor crash-restart: followers rebuild in
+            # lockstep or the gang serves from desynced caches.
+            gang.broadcast_restart()
+        first_build[0] = False
+        return decode_engine.DecodeEngine(
+            cfg, params, slots=engine_slots,
+            max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
+            prefix_cache_mb=prefix_cache_mb,
+            mesh=mesh, rules=rules,
+            kv_pool_blocks=int(kv_pool_blocks),
+            kv_block_tokens=int(kv_block_tokens),
+            kv_quant=bool(kv_quant),
+            weight_quant=bool(weight_quant),
+            spec_k=int(spec_k),
+            spec_ngram=int(spec_ngram),
+            spec_min_accept=float(spec_min_accept))
 
-        ctx["engine"] = decode_engine.EngineSupervisor(
-            _engine_factory, max_restarts=engine_max_restarts,
-            backoff_base=engine_restart_backoff).start()
+    ctx["engine"] = decode_engine.EngineSupervisor(
+        _engine_factory, max_restarts=engine_max_restarts,
+        backoff_base=engine_restart_backoff).start()
 
     handler = type("Handler", (_Handler,), {"server_ctx": ctx})
     httpd = ThreadingHTTPServer(("0.0.0.0", port), handler)
@@ -891,12 +735,7 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
                 raise gang_replica.GangError(
                     f"the serving gang of {gang.topology.hosts} hosts "
                     f"did not form: {gang.members_info()}")
-            if ctx["engine"] is not None:
-                ctx["engine"].warmup()
-            else:
-                buf = jnp.zeros((PROMPT_BUCKET,), jnp.int32)
-                _decode_locked(ctx, buf, 8, GEN_BUCKET, 0.0,
-                               0).block_until_ready()
+            ctx["engine"].warmup()
         except Exception as e:  # noqa: BLE001 — thread boundary: a
             # warm-up that dies in silence leaves /health "warming"
             # for ever; record the cause where probes read it.
@@ -918,11 +757,9 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
 def _resolve_kv(args) -> dict:
     """CLI flags > STPU_KV_* env > defaults — resolved ONCE and used
     for the local engine, the follower engines, and the gang kv-config
-    handshake, so every host of a gang replica pages (or not)
+    handshake, so every host of a gang replica sizes its pool
     identically."""
     return {
-        "paged": (bool(args.kv_paged) if args.kv_paged is not None
-                  else ENGINE_KV_PAGED),
         "pool_blocks": (int(args.kv_pool_blocks)
                         if args.kv_pool_blocks is not None
                         else ENGINE_KV_POOL_BLOCKS),
@@ -1015,8 +852,6 @@ def _spawn_follower_cmd(args, rank: int, topology, leader_port: int):
         argv += ["--engine-slots", str(args.engine_slots)]
     if args.prefix_cache_mb is not None:
         argv += ["--prefix-cache-mb", str(args.prefix_cache_mb)]
-    if args.kv_paged is not None:
-        argv += ["--kv-paged", str(int(args.kv_paged))]
     if args.kv_pool_blocks is not None:
         argv += ["--kv-pool-blocks", str(args.kv_pool_blocks)]
     if args.kv_block_tokens is not None:
@@ -1065,26 +900,18 @@ def main(argv=None):
                         "unsharded engine; bfloat16 matches only to "
                         "bf16 rounding, like any resharding)")
     p.add_argument("--engine-slots", type=int, default=None,
-                   help="decode-engine slots (0 = legacy locked path; "
-                        "default env STPU_ENGINE_SLOTS or 4)")
+                   help="decode-engine slots, at least 1 (default env "
+                        "STPU_ENGINE_SLOTS or 4)")
     p.add_argument("--prefix-cache-mb", type=float, default=None,
                    help="host-RAM KV spill tier budget in MiB under "
                         "the paged trie: LRU-evicted prefix blocks "
                         "spill D2H and re-admit H2D on a warm match. "
                         "0 = tier off (evictions drop). Default env "
                         "STPU_PREFIX_CACHE_MB or 64")
-    p.add_argument("--kv-paged", type=int, choices=(0, 1),
-                   default=None,
-                   help="1 serves from the paged KV block pool (one "
-                        "device pool + per-slot block tables; prefix "
-                        "hits alias blocks zero-copy; admission is "
-                        "free-block based). Default env STPU_KV_PAGED "
-                        "or 0. Bit-identical to the dense path")
     p.add_argument("--kv-pool-blocks", type=int, default=None,
-                   help="paged-KV pool size in blocks incl. scratch "
-                        "(0 = auto: slots * max_seq / block + 1, the "
-                        "dense HBM budget; default env "
-                        "STPU_KV_POOL_BLOCKS)")
+                   help="KV pool size in blocks incl. scratch "
+                        "(0 = auto: slots * max_seq / block + 1; "
+                        "default env STPU_KV_POOL_BLOCKS)")
     p.add_argument("--kv-block-tokens", type=int, default=None,
                    help="paged-KV block size in tokens (also the "
                         "prefill chunk; 0 = the default 64-token "
@@ -1093,7 +920,7 @@ def main(argv=None):
                    default=None,
                    help="1 stores int8 KV blocks (+ per-block/head "
                         "scales) in the paged pool — ~2x blocks at "
-                        "the same HBM budget; requires --kv-paged. "
+                        "the same HBM budget. "
                         "NOT bit-identical to bf16 (parity-gated by "
                         "tests/test_quant.py). Default env "
                         "STPU_KV_QUANT or 0")
@@ -1144,6 +971,10 @@ def main(argv=None):
                         "service.load_balancing_policy in the YAML "
                         "instead.")
     args = p.parse_args(argv)
+    engine_slots = (args.engine_slots if args.engine_slots is not None
+                    else ENGINE_SLOTS)
+    if engine_slots < 1:
+        p.error(NO_ENGINE_SLOTS)
     if args.lb_policy and not args.lb_port:
         p.error("--lb-policy only configures the --lb-port balancer; "
                 "deployed services set service.load_balancing_policy "
@@ -1169,10 +1000,8 @@ def main(argv=None):
     # different slot counts would auto-size different pools and pass a
     # raw-knob check while diverging in admission.
     kv_geo = decode_engine.resolve_kv_geometry(
-        slots=(args.engine_slots if args.engine_slots
-               else ENGINE_SLOTS),
+        slots=engine_slots,
         max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
-        paged=kv["paged"],
         kv_pool_blocks=kv["pool_blocks"],
         kv_block_tokens=kv["block_tokens"],
         kv_quant=kv["kv_quant"], weight_quant=kv["weight_quant"],
@@ -1188,12 +1017,10 @@ def main(argv=None):
         def _follower_engine():
             return decode_engine.DecodeEngine(
                 cfg, params,
-                slots=(args.engine_slots
-                       if args.engine_slots else ENGINE_SLOTS),
+                slots=engine_slots,
                 max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
                 prefix_cache_mb=kv["prefix_cache_mb"],
                 mesh=mesh, rules=rules,
-                paged=kv["paged"],
                 kv_pool_blocks=kv["pool_blocks"],
                 kv_block_tokens=kv["block_tokens"],
                 kv_quant=kv["kv_quant"],
@@ -1231,19 +1058,19 @@ def main(argv=None):
             gang.start_followers()
 
     httpd = serve(cfg, params, args.port,
-                  engine_slots=args.engine_slots,
+                  engine_slots=engine_slots,
                   prefix_cache_mb=kv["prefix_cache_mb"],
                   stream_timeout=args.stream_timeout,
                   engine_max_restarts=args.engine_max_restarts,
                   topology=topology, mesh=mesh, rules=rules,
-                  gang=gang, kv_paged=kv["paged"],
+                  gang=gang,
                   kv_pool_blocks=kv["pool_blocks"],
                   kv_block_tokens=kv["block_tokens"],
                   kv_quant=kv["kv_quant"],
                   weight_quant=kv["weight_quant"],
                   spec_k=kv["spec_k"], spec_ngram=kv["spec_ngram"],
                   spec_min_accept=kv["spec_min_accept"])
-    if gang is not None and httpd.engine is not None:
+    if gang is not None:
         # Whole-gang restart rebuilds host 0's engine too.
         gang.set_engine_reset(httpd.engine.restart_now)
 

@@ -1,66 +1,56 @@
-"""Slot-based continuous-batching decode engine.
+"""Slot-based continuous-batching decode engine over one paged KV pool.
 
-The throughput lever the fixed-batch serving path cannot reach: under a
-heterogeneous request mix, bucketed batching only ever co-schedules
-same-length prompts and a per-replica model lock serializes everything
-else. This engine holds ONE KV cache of ``slots`` rows and runs one
-jitted decode step over all of them every iteration:
+The one way a request reaches a model: HTTP handler
+(recipes/serve_llm.py) -> :class:`DecodeEngine` -> serve/kv_pool.py ->
+models/* ``forward_with_paged_cache`` / ``verify_step_paged``. The
+engine holds ONE device-resident pool of fixed-size KV blocks (block =
+the prefill chunk) that backs every slot through per-slot block tables,
+and runs one jitted decode step over all slots every iteration:
 
   * requests join MID-FLIGHT into free slots — the prompt is prefilled
     in fixed-size chunks interleaved with decode steps, so a long
     arriving prompt never stalls tokens already streaming from other
     slots for more than one chunk;
   * every slot sits at its own sequence position — the model's
-    per-slot (B,) ``start_pos``/``valid_len`` contract
-    (models/llama.forward_with_cache) masks each row to its own valid
-    prefix, and split-KV attention reads only up to the longest live
-    frontier;
-  * finished slots free immediately and the next queued request takes
-    the row over — stale K/V left behind is never attendable (masked
-    until overwritten), the invariant the ragged-parity tests pin;
-  * the cache is DONATED through both jitted entry points (prefill
-    chunk and decode step), so the engine keeps one
-    O(layers * slots * max_seq) buffer from step to step. Donation
-    names the buffer the result lands in, not what the program builds
-    on the way: in the paged programs the layer scan SCANS the layer
-    parameters and the layer index and CARRIES the stacked pool, each
-    layer scattering rows into and gathering blocks from [layer, ...]
-    of that one buffer (models/llama.forward_with_paged_cache). That
-    no second pool exists inside a program is held by the compiled
-    temporaries (tests/test_paged_kv.py::
+    per-slot (B,) ``start_pos``/``valid_len`` contract masks each slot
+    to its own valid prefix, and the split-KV attention loop gathers
+    K/V through the table only up to the longest live frontier;
+  * slots acquire blocks lazily as they prefill and decode; admission
+    reserves the request's worst-case block count up front (free-block
+    based, with deterministic FIFO head-of-line backpressure, so
+    admitted work is never preempted). Finished slots free at once;
+    stale K/V in a block handed out again is never attendable (masked
+    until overwritten), the invariant the parity tests pin;
+  * the pool IS the shared-prefix cache: identical leading tokens
+    produce identical KV blocks (causal attention), so a trie maps
+    chunk hashes to refcounted blocks, a hit is a block-table entry
+    write (zero-copy, no host round-trip) and publish-on-free is a
+    refcount transfer. At least one trailing prompt token is always
+    prefilled so the first token is sampled from real logits.
+    ``prefix_cache_mb`` sizes the host-RAM spill tier under the trie;
+  * the pool is DONATED through all three jitted programs (prefill
+    chunk, decode step, verify step), so the engine keeps one buffer
+    from step to step. Donation names the buffer the result lands in,
+    not what the program builds on the way: the layer scan SCANS the
+    layer parameters and the layer index and CARRIES the stacked pool,
+    each layer scattering rows into and gathering blocks from
+    [layer, ...] of that one buffer
+    (models/llama.forward_with_paged_cache). That no second pool
+    exists inside a program is held by the compiled temporaries
+    (tests/test_paged_kv.py::
     test_paged_entry_points_hold_one_pool_buffer), not by the
-    donation. The dense programs still scan their row cache in and
-    out, layer by layer.
+    donation.
+
+The reference the engine's tokens are held to is the models' own
+row-cache ``decode`` (models/llama.decode over ``forward_with_cache``):
+bit-identical when the attention window's tile boundaries align.
 
 Sampling is reproducible per request: the key for the token at
 position p is fold_in(fold_in(root, seed), p), independent of which
 slot the request landed in or what else shared the batch.
 
-Paged KV-cache block pool (``paged=True`` / STPU_KV_PAGED=1): the
-capacity lever over the dense row layout. Instead of every slot owning
-a dense ``(layers, max_seq, ...)`` cache row — concurrency sized for
-the worst-case sequence — ONE device-resident pool of fixed-size
-blocks (block = the prefill chunk) backs every slot through per-slot
-block tables (serve/kv_pool.py owns the accounting; models/*
-forward_with_paged_cache gathers K/V through the table inside the same
-split-KV online-softmax loop, bit-identical to dense when tile
-boundaries align). Slots acquire blocks lazily as they prefill/decode;
-admission reserves the request's worst-case block count up front
-(free-block based — NOT a full max_seq row — with deterministic FIFO
-head-of-line backpressure, so admitted work is never preempted). The
-pool IS the shared-prefix cache: production traffic shares system
-prompts / few-shot templates, so identical leading tokens produce
-identical KV blocks (causal attention) — a trie maps chunk hashes to
-refcounted blocks, a hit is a block-table entry write (zero-copy, no
-row splice, no host round-trip) and publish-on-free is a refcount
-transfer. Prefix caching exists ONLY in paged mode; the dense path's
-host-pinned splice cache was retired with the quantized pool (one
-cache representation — the ``prefix_cache_mb`` kwarg is accepted but
-inert). At least one trailing prompt token is always prefilled so the
-first token is sampled from real logits.
-
-Quantized KV serving (``kv_quant=True`` / STPU_KV_QUANT=1, paged
-only): every pool block stores int8 K/V codes plus ONE f32 scale per
+Quantized KV serving (``kv_quant=True`` / STPU_KV_QUANT=1): every
+pool block stores int8 K/V codes plus ONE f32 scale per
 (layer, block, kv_head) in a parallel scales array sized off the same
 block table (models/llama.init_paged_cache(quantized=True)). Blocks
 quantize on write inside paged_attention_block — symmetric absmax
@@ -85,26 +75,18 @@ the lever batching can't reach: a free n-gram / prompt-lookup matcher
 over each slot's OWN token history (prompt + output; an O(1)
 incremental index, no second model) drafts up to k tokens per slot
 per step, and one batched forward verifies all k+1 positions at once
-(models/*.verify_step — the (B,) start_pos/valid_len contract
+(models/*.verify_step_paged — the (B,) start_pos/valid_len contract
 generalized to a (B, K+1) logits-at-positions window). Targets are
 re-sampled with the engine's own fold_in(seed, pos) keys, so
 acceptance is exact-match and the output stream is BIT-IDENTICAL to
 non-speculative decode for greedy and seeded sampling alike (under
 deterministic per-position keys, rejection sampling against a
 deterministic draft degenerates to exact match — stronger than
-distribution-preserving). A rejected suffix rolls back for free:
-dense rows past the accepted frontier stay valid_len-masked exactly
-like stale slot-reuse rows, and the paged path truncates the grown
-block-table tail back into the pool. Slots whose traffic doesn't
-repeat (acceptance below STPU_SPEC_MIN_ACCEPT) stop drafting
+distribution-preserving). A rejected suffix rolls back by truncating
+the grown block-table tail back into the pool. Slots whose traffic
+doesn't repeat (acceptance below STPU_SPEC_MIN_ACCEPT) stop drafting
 automatically, so the worst case degrades to the plain step plus one
 dict lookup.
-
-Used by recipes/serve_llm.py (replacing its model-lock-per-request
-path) and benchmark/decode_bench.measure_engine_ragged (the
-`engine_ragged_tok_s` bench leg) / measure_engine_paged (the
-`engine_paged_tok_s` + pool-utilization legs) / measure_engine_spec
-(the `engine_spec_tok_s` + acceptance-rate legs).
 """
 from __future__ import annotations
 
@@ -449,9 +431,9 @@ class Request:
 
 
 class _Slot:
-    """Host-side state of one cache row (or, paged, one block table):
-    what the engine knows when it DISPATCHES a program. Nothing here
-    depends on a token's value; what arrives with the values is on the
+    """Host-side state of one slot and its block table: what the
+    engine knows when it DISPATCHES a program. Nothing here depends
+    on a token's value; what arrives with the values is on the
     :class:`Request` and in the slot's draft history."""
 
     __slots__ = ("request", "pos", "generated", "prefilled",
@@ -461,13 +443,13 @@ class _Slot:
 
     def __init__(self):
         self.request: Optional[Request] = None
-        self.pos = 0          # valid length of the row (= next write)
+        self.pos = 0          # valid length of the slot (= next write)
         self.generated = 0    # tokens whose sampling is dispatched
         self.prefilled = 0    # prompt tokens already prefilled
         self.held: List[Any] = []           # pinned prefix-pool nodes
         self.cached = 0       # prompt tokens restored from the pool
-        self.blocks = 0       # paged: valid block-table entries
-        self.reserved = 0     # paged: blocks still promised, unclaimed
+        self.blocks = 0       # valid block-table entries
+        self.reserved = 0     # blocks still promised, unclaimed
         # Host-tier re-admits this slot still owes: (logical chunk
         # index, trie node, fetched host payload) in chunk order,
         # consumed one per engine iteration by _restore_one.
@@ -515,33 +497,6 @@ def _first_token(logits, toks, tok_row, seed, pos, temp):
     return toks.at[tok_row].set(tok[0], mode="drop")
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7),
-                   donate_argnums=(2,))
-def _prefill_chunk(cfg, params, cache, buf, slot, start, valid,
-                   block, toks, tok_row, seed, temp):
-    """Prefill ONE chunk of ONE slot's prompt into the shared cache.
-
-    buf: (P,) tokens for positions [start, start+P) of row ``slot``
-    (tail may be padding on the prompt's final chunk). ``valid`` is the
-    absolute count of real tokens after this chunk — padding K/V
-    written past it stays masked until decode steps overwrite it. The
-    cache is donated: the row splice happens in place. Returns
-    (``toks`` with the first token in ``tok_row``
-    (:func:`_first_token`), cache).
-    """
-    api = model_api(cfg)
-    row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-           for k, v in cache.items()}
-    logits, row = api.forward_with_cache(
-        cfg, params, buf[None, :], row, start, valid_len=valid,
-        logits_at=jnp.maximum(valid - start - 1, 0), block=block)
-    cache = {k: jax.lax.dynamic_update_slice_in_dim(cache[k], row[k],
-                                                    slot, axis=1)
-             for k in cache}
-    return _first_token(logits[:, 0], toks, tok_row, seed, valid,
-                        temp), cache
-
-
 @functools.partial(jax.jit, static_argnums=(0, 8),
                    donate_argnums=(2,))
 def _paged_prefill_chunk(cfg, params, cache, buf, table_row, start,
@@ -549,13 +504,17 @@ def _paged_prefill_chunk(cfg, params, cache, buf, table_row, start,
                          temp):
     """Prefill ONE chunk of ONE slot's prompt into the paged pool.
 
-    The block-table twin of :func:`_prefill_chunk`: ``table_row`` is
-    the slot's block table (the attention gather path) and ``wb`` the
-    physical block the chunk lands in (a whole-block write — chunks
-    and blocks are the same granularity, which is what lets prefix
-    hits alias whole blocks instead of splicing rows). The pool is
-    donated: the write happens in place. Returns (``toks`` with the
-    first token in ``tok_row`` (:func:`_first_token`), pool)."""
+    buf: (P,) tokens for positions [start, start+P) of the slot (tail
+    may be padding on the prompt's final chunk). ``valid`` is the
+    absolute count of real tokens after this chunk — padding K/V
+    written past it stays masked until decode steps overwrite it.
+    ``table_row`` is the slot's block table (the attention gather
+    path) and ``wb`` the physical block the chunk lands in (a
+    whole-block write — chunks and blocks are the same granularity,
+    which is what lets prefix hits alias whole blocks instead of
+    splicing rows). The pool is donated: the write happens in place.
+    Returns (``toks`` with the first token in ``tok_row``
+    (:func:`_first_token`), pool)."""
     api = model_api(cfg)
     logits, cache, *_ = api.forward_with_paged_cache(
         cfg, params, buf[None, :], cache, table_row[None, :], start,
@@ -616,21 +575,6 @@ def _paged_step(cfg, params, cache, toks, pos, table, window, temps,
     return nxt, cache
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7),
-                   donate_argnums=(2,))
-def _engine_step(cfg, params, cache, toks, pos, temps, seeds, block):
-    """One decode step over ALL slots: write each slot's last token at
-    its own position, attend its own valid prefix, sample its next
-    token. Free slots ride along with pos 0 and are ignored host-side.
-    The cache is donated (in-place update)."""
-    api = model_api(cfg)
-    logits, cache = api.forward_with_cache(
-        cfg, params, toks[:, None], cache, pos, block=block)
-    logits = logits[:, -1]
-    nxt = _sample(logits, seeds, pos + 1, temps)
-    return nxt, cache
-
-
 def _sample_multi(logits, seeds, pos, temps):
     """Per-slot, per-column target sampling for a verify window:
     column j of ``logits`` (B, T, vocab) is the distribution of the
@@ -675,38 +619,21 @@ def _verified(toks, logits, pos, spec_len, temps, seeds):
     return targets, accepts, last
 
 
-@functools.partial(jax.jit, static_argnums=(0, 9),
-                   donate_argnums=(2,))
-def _spec_step(cfg, params, cache, last, drafts, pos, spec_len, temps,
-               seeds, block):
-    """One speculative verify step over ALL slots (dense cache): each
-    slot's window [last token, draft_1..draft_k, padding] forwards in
-    one pass (models verify_step), targets are sampled per position
-    with the engine's fold_in(seed, pos) keys, and drafts are accepted
-    up to the first mismatch. Returns (targets (B, T), accepts (B,),
-    last (B,), cache) — the engine emits targets[b, :accepts[b] + 1]
-    per live slot, so the device->host transfer is two small int
-    arrays, never the (B, T, vocab) logits. The cache is donated
-    (in-place update); rejected-suffix rows beyond each slot's
-    accepted frontier stay masked exactly like any stale slot-reuse
-    row."""
-    api = model_api(cfg)
-    toks = _verify_window(last, drafts)
-    logits, cache = api.verify_step(cfg, params, toks, cache, pos,
-                                    spec_len, block=block)
-    return (*_verified(toks, logits, pos, spec_len, temps, seeds),
-            cache)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 8),
                    donate_argnums=(2,))
 def _paged_spec_step(cfg, params, cache, last, drafts, pos, spec_len,
                      table, window, temps, seeds):
-    """The paged twin of :func:`_spec_step`: the verify window writes
-    and gathers through each slot's block table (models
-    verify_step_paged); the pool is donated. The engine truncates the
-    rejected suffix's blocks back afterwards (block-table truncate +
-    reservation return)."""
+    """One speculative verify step over ALL slots: each slot's window
+    [last token, draft_1..draft_k, padding] forwards in one pass,
+    writing and gathering through its block table (models
+    verify_step_paged), targets are sampled per position with the
+    engine's fold_in(seed, pos) keys, and drafts are accepted up to
+    the first mismatch. Returns (targets (B, T), accepts (B,),
+    last (B,), pool) — the engine emits targets[b, :accepts[b] + 1]
+    per live slot, so the device->host transfer is two small int
+    arrays, never the (B, T, vocab) logits. The pool is donated; the
+    engine truncates the rejected suffix's blocks back afterwards
+    (block-table truncate + reservation return)."""
     api = model_api(cfg)
     toks = _verify_window(last, drafts)
     logits, cache = api.verify_step_paged(cfg, params, toks, cache,
@@ -750,7 +677,7 @@ DEFAULT_PREFILL_CHUNK = 64
 
 
 def resolve_kv_geometry(*, slots: int, max_seq: int,
-                        prefill_chunk: int = 0, paged: bool = False,
+                        prefill_chunk: int = 0,
                         kv_pool_blocks: int = 0,
                         kv_block_tokens: int = 0,
                         kv_quant: bool = False,
@@ -781,8 +708,8 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     Tuned constants (skypilot_tpu/tune/): when ``family`` is given and
     ``use_manifest`` is left on, the sha-pinned tuning manifest is
     consulted for the key ``(family, batch-band(slots), tp,
-    quant-mode)`` and supplies ``block`` (split-KV attention tile),
-    ``chunk``, ``window_blocks`` (paged gather window, in blocks) and
+    quant-mode)`` and supplies ``block`` (the attention tile the window
+    mirrors), ``chunk``, ``window_blocks`` (gather window, in blocks) and
     ``spec_k`` — but ONLY for knobs the caller left at their 0
     sentinel: explicit arguments (CLI flags, env knobs, sweep
     candidates) always win over the manifest, and
@@ -793,11 +720,6 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     happen to coincide — tuned geometry drifts are join-fatal exactly
     like kv/quant drifts."""
     max_seq = int(max_seq)
-    if kv_quant and not paged:
-        raise ValueError(
-            "kv_quant requires paged=True — int8 KV lives in the "
-            "paged block pool (the dense row cache has no scales "
-            "array and was retired as a prefix-cache representation)")
     manifest_tag = "default"
     if use_manifest and family:
         from skypilot_tpu.tune import manifest as tune_manifest
@@ -813,57 +735,53 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
                 window_blocks = int(entry.get("window_blocks", 0))
             if not spec_k:
                 spec_k = int(entry.get("spec_k", 0))
-    if paged and kv_block_tokens:
+    if kv_block_tokens:
         prefill_chunk = int(kv_block_tokens)
     if not prefill_chunk:
         prefill_chunk = DEFAULT_PREFILL_CHUNK
     chunk = max(min(int(prefill_chunk), max_seq), 1)
     while max_seq % chunk:
         chunk //= 2
-    # Effective dense attention tile: the tuned (or default) width
-    # clamped to the cache rows — always concrete in the dict, so the
-    # jitted dense entry points take it as a static argument and the
-    # handshake compares the value the kernel actually tiles by.
+    # The row-cache reference's attention tile (models/llama.
+    # _split_kv_attention), tuned or default, clamped to max_seq: the
+    # width the attention window mirrors unless window_blocks says
+    # otherwise.
     block_eff = max(min(int(block) or _default_split_kv_block(),
                         max_seq), 1)
-    out: Dict[str, Any] = {
-        "paged": int(bool(paged)), "slots": int(slots),
-        "max_seq": max_seq, "chunk": chunk,
+    # Auto sizing: slots * max_seq tokens of bf16 KV plus the scratch
+    # block. An int8 block (codes + one f32 scale per layer/head) is
+    # ~half the bytes, so the same budget holds 2x the blocks.
+    total = int(kv_pool_blocks) or (
+        (2 if kv_quant else 1) * int(slots) * (max_seq // chunk) + 1)
+    if window_blocks:
+        window = max(min(int(window_blocks) * chunk,
+                         max_seq // chunk * chunk), chunk)
+    else:
+        # Mirror the reference's tile so the tile boundaries align
+        # (the bit-parity condition), floored to whole blocks.
+        window = max(block_eff // chunk * chunk, chunk)
+    nbw = window // chunk
+    # The host spill-tier budget (MiB) rides the geometry dict: the
+    # tier changes eviction outcomes and therefore admission timing,
+    # so a leader/follower budget drift is join-fatal via the same
+    # welcome comparison as a pool or quant drift.
+    # "paged": 1 says nothing any more (there is one engine); it stays
+    # because chip_smoke.py, which a deleting PR may not edit, reads it.
+    return {
+        "paged": 1, "slots": int(slots), "max_seq": max_seq,
+        "chunk": chunk,
         "block": block_eff, "manifest": manifest_tag,
         "kv_quant": int(bool(kv_quant)),
         "weight_quant": int(bool(weight_quant)),
         "spec_k": int(spec_k), "spec_ngram": int(spec_ngram),
-        "spec_min_accept": float(spec_min_accept)}
-    if paged:
-        # Auto sizing targets the dense path's HBM budget: slots *
-        # max_seq tokens of bf16 KV plus the scratch block. An int8
-        # block (codes + one f32 scale per layer/head) is ~half the
-        # bytes, so the same budget holds 2x the blocks — the capacity
-        # lever the q8 bench leg gates at >= 1.8x.
-        total = int(kv_pool_blocks) or (
-            (2 if kv_quant else 1) *
-            int(slots) * (max_seq // chunk) + 1)
-        if window_blocks:
-            window = max(min(int(window_blocks) * chunk,
-                             max_seq // chunk * chunk), chunk)
-        else:
-            # Mirror the dense tile so paged and dense tile boundaries
-            # align (the bit-parity condition), floored to whole
-            # blocks.
-            window = max(block_eff // chunk * chunk, chunk)
-        nbw = window // chunk
-        # Host spill-tier budget (MiB) rides the geometry dict: the
-        # tier changes eviction outcomes and therefore admission
-        # timing, so a leader/follower budget drift is join-fatal via
-        # the same welcome comparison as a pool or quant drift.
-        out.update(pool_blocks=total, window=window,
-                   table_len=-(-(total - 1) // nbw) * nbw,
-                   host_mb=float(host_cache_mb))
-    return out
+        "spec_min_accept": float(spec_min_accept),
+        "pool_blocks": total, "window": window,
+        "table_len": -(-(total - 1) // nbw) * nbw,
+        "host_mb": float(host_cache_mb)}
 
 
 class DecodeEngine:
-    """Fixed-slot continuous-batching scheduler over one shared cache.
+    """Fixed-slot continuous-batching scheduler over one paged KV pool.
 
     One background thread owns all device compute: each iteration it
     (1) admits queued requests into free slots, (2) advances at most
@@ -883,18 +801,16 @@ class DecodeEngine:
     def __init__(self, cfg, params, *, slots: int = 4,
                  max_seq: int = 1024, prefill_chunk: int = 0,
                  max_queue: int = 256, prefix_cache_mb: float = 0.0,
-                 mesh=None, rules=None, paged: bool = False,
+                 mesh=None, rules=None,
                  kv_pool_blocks: int = 0, kv_block_tokens: int = 0,
                  kv_quant: bool = False, weight_quant: bool = False,
                  spec_k: int = 0, spec_ngram: int = 3,
                  spec_min_accept: float = 0.0, block: int = 0,
                  window_blocks: int = 0, use_manifest: bool = True):
         # prefix_cache_mb is the HOST-TIER byte budget (MiB) for the
-        # paged pool's trie: evicted prefix blocks spill D2H into a
-        # bounded host pool and re-admit H2D on a warm match. 0 turns
-        # the tier off (evictions drop the leaf, exactly the pre-tier
-        # engine). Dense mode has no trie, hence no tier — the knob is
-        # ignored there like the retired splice cache it once sized.
+        # pool's trie: evicted prefix blocks spill D2H into a bounded
+        # host pool and re-admit H2D on a warm match. 0 turns the tier
+        # off (evictions drop the leaf).
         host_mb = float(prefix_cache_mb or 0.0)
         if slots < 1:
             raise ValueError("slots must be >= 1")
@@ -917,13 +833,10 @@ class DecodeEngine:
                                                    rules)
         self._params = params
         self._slots = [_Slot() for _ in range(slots)]
-        self._max_seq = int(max_seq)
-        self._paged = bool(paged)
         self._kv_quant = bool(kv_quant)
         # Self-speculative decoding (module docstring): k drafted
         # tokens per slot per step, verified in one batched forward.
-        # 0 disables — the decode step is then byte-for-byte the
-        # pre-speculation path.
+        # 0 disables.
         self._spec_k = int(spec_k)
         self._spec_ngram = int(spec_ngram)
         self._spec_min_accept = float(spec_min_accept)
@@ -934,24 +847,20 @@ class DecodeEngine:
         self.peak_live_slots = 0
         # Tensor-parallel serving (serve/gang_replica.py): with a mesh,
         # params arrive pre-sharded (ShardingRules over param_specs)
-        # and the KV cache is placed by cache_specs — the jitted entry
+        # and the KV pool is placed by cache_specs — the jitted entry
         # points are unchanged, GSPMD partitions them from the operand
         # shardings and donation still aliases in place (pinned by
         # tests/test_sharded_replica.py).
         self._mesh = mesh
         self._rules = rules
-        # Chunks must tile the cache rows: prefill starts land on chunk
-        # multiples, so chunk | max_seq guarantees every chunk window
-        # fits the row (dynamic_update_slice would otherwise clamp the
-        # start and silently corrupt earlier positions). Paged mode
-        # reuses the same granularity as the BLOCK size — blocks and
+        # The prefill chunk is the pool's BLOCK size — blocks and
         # chunks being the same unit is what makes a prefix hit a
         # whole-block alias. The derivation lives in
         # resolve_kv_geometry so the gang handshake compares exactly
         # what this engine runs.
         geo = resolve_kv_geometry(
-            slots=slots, max_seq=self._max_seq,
-            prefill_chunk=prefill_chunk, paged=self._paged,
+            slots=slots, max_seq=max_seq,
+            prefill_chunk=prefill_chunk,
             kv_pool_blocks=kv_pool_blocks,
             kv_block_tokens=kv_block_tokens,
             kv_quant=self._kv_quant,
@@ -959,22 +868,19 @@ class DecodeEngine:
             spec_ngram=self._spec_ngram,
             spec_min_accept=self._spec_min_accept,
             block=block, window_blocks=window_blocks,
-            host_cache_mb=(host_mb if self._paged else 0.0),
+            host_cache_mb=host_mb,
             family=family_name(cfg),
             tp=(mesh.devices.size if mesh is not None else 1),
             use_manifest=use_manifest)
         self._kv_geometry = geo
         chunk = geo["chunk"]
         self._chunk = chunk
-        # Tuned constants may enable drafting / resize the tile even
-        # when the caller passed the 0 sentinel — read the EFFECTIVE
-        # values back from the geometry, the same dict the handshake
-        # compares.
-        self._block = geo["block"]
+        # Tuned constants may enable drafting even when the caller
+        # passed the 0 sentinel — read the EFFECTIVE value back from
+        # the geometry, the same dict the handshake compares.
         self._spec_k = geo["spec_k"]
         self._max_queue = int(max_queue)
-        self.prefix_cache: Optional[Any] = None
-        # Host-RAM spill tier state (paged + host_mb > 0 only, but the
+        # Host-RAM spill tier state (host_mb > 0 only, but the
         # attributes always exist — shutdown and introspection touch
         # them on every engine).
         self._host_pool: Optional[kv_pool.HostBlockPool] = None
@@ -982,53 +888,41 @@ class DecodeEngine:
         self._spill_thread: Optional[threading.Thread] = None
         self._spill_stop = False
         self._readmitted_blocks = 0
-        if self._paged:
-            # ONE device-resident pool for slot growth AND the prefix
-            # cache (serve/kv_pool.py). Default sizing matches the
-            # dense path's HBM budget exactly: slots * max_seq tokens
-            # of KV, plus the scratch block.
-            total = geo["pool_blocks"]
-            self._pool = kv_pool.BlockPool(total, chunk)
-            # Attention tile width: by default it mirrors the dense
-            # engine's effective block so paged and dense tile
-            # boundaries align (the bit-parity condition), floored to
-            # a block multiple so each tile gathers whole blocks; a
-            # tuned window_blocks overrides the multiple (parity-gated
-            # by the sweep before it can reach a manifest).
-            self._window = geo["window"]
-            # Per-slot LOGICAL capacity is the pool, not a row: the
-            # table can address every usable block (rounded up so the
-            # last attention tile's table slice stays in bounds).
-            self._table_len = geo["table_len"]
-            self._table = np.zeros((slots, self._table_len), np.int32)
-            make_cache = functools.partial(
-                self._api.init_paged_cache, cfg, total, chunk,
-                quantized=self._kv_quant)
-            # Host-RAM spill tier under the trie: evictions demote
-            # blocks D2H through a bounded queue drained off the
-            # compute thread; warm matches re-admit H2D during the
-            # prefill phase (_restore_one). Budget 0 = tier off.
-            host_mb_eff = float(geo.get("host_mb", 0.0))
-            if host_mb_eff > 0:
-                self._host_pool = kv_pool.HostBlockPool(
-                    int(host_mb_eff * (1 << 20)))
-                self._spill_q = queue.Queue(maxsize=32)
-                self._spill_thread = threading.Thread(
-                    target=self._drain_spills, name="kv-spill-drain",
-                    daemon=True)
-                self._spill_thread.start()
-            # The unified pool IS the prefix cache: the trie is just an
-            # index over blocks, so it is always on in paged mode (a
-            # hit is a table write; a miss costs one dict walk).
-            self.prefix_cache = kv_pool.PagedPrefixCache(
-                self._pool, chunk, host_pool=self._host_pool,
-                spill=(self._spill_block
-                       if self._host_pool is not None else None))
-            _KV_POOL_TOTAL.set(self._pool.usable_blocks)
-            _KV_POOL_FREE.set(self._pool.free_blocks())
-        else:
-            make_cache = functools.partial(self._api.init_cache, cfg,
-                                           slots, max_seq)
+        # ONE device-resident pool for slot growth AND the prefix
+        # cache (serve/kv_pool.py), sized and tiled by
+        # resolve_kv_geometry.
+        total = geo["pool_blocks"]
+        self._pool = kv_pool.BlockPool(total, chunk)
+        self._window = geo["window"]    # attention tile, whole blocks
+        # Per-slot LOGICAL capacity is the pool, not a row: the table
+        # can address every usable block (rounded up so the last
+        # attention tile's table slice stays in bounds).
+        self._table_len = geo["table_len"]
+        self._table = np.zeros((slots, self._table_len), np.int32)
+        make_cache = functools.partial(
+            self._api.init_paged_cache, cfg, total, chunk,
+            quantized=self._kv_quant)
+        # Host-RAM spill tier under the trie: evictions demote blocks
+        # D2H through a bounded queue drained off the compute thread;
+        # warm matches re-admit H2D during the prefill phase
+        # (_restore_one). Budget 0 = tier off.
+        if geo["host_mb"] > 0:
+            self._host_pool = kv_pool.HostBlockPool(
+                int(geo["host_mb"] * (1 << 20)))
+            self._spill_q = queue.Queue(maxsize=32)
+            self._spill_thread = threading.Thread(
+                target=self._drain_spills, name="kv-spill-drain",
+                daemon=True)
+            self._spill_thread.start()
+        # The pool IS the prefix cache: the trie is just an index over
+        # blocks, so it is always on (a hit is a table write; a miss
+        # costs one dict walk).
+        self.prefix_cache = kv_pool.PagedPrefixCache(
+            self._pool, chunk, host_pool=self._host_pool,
+            spill=(self._spill_block
+                   if self._host_pool is not None else None))
+        _KV_POOL_TOTAL.set(self._pool.usable_blocks)
+        _KV_POOL_FREE.set(self._pool.free_blocks())
         if mesh is None:
             self._cache = make_cache()
         else:
@@ -1065,11 +959,9 @@ class DecodeEngine:
         # last token still unread (in_flight() counts them).
         self._retiring = 0
         # A slot whose next write would be the last position ends.
-        self._limit = (self._table_len * chunk if self._paged
-                       else self._max_seq)
-        if self._paged:
-            _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
-                cfg, chunk, quantized=self._kv_quant))
+        self._limit = self._table_len * chunk
+        _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
+            cfg, chunk, quantized=self._kv_quant))
         _KV_QUANT_ENABLED.set(int(self._kv_quant))
         _WEIGHT_QUANT_ENABLED.set(int(self._weight_quant))
         self._waiting: "collections.deque[Request]" = collections.deque()
@@ -1118,25 +1010,15 @@ class DecodeEngine:
             raise EngineError("max_tokens must be >= 1")
         if req.resume_len:
             _RESUME_ADMITS.inc()
-        if self._paged:
-            # Under paging the admission bound is POOL CAPACITY, not a
-            # per-slot row length: a request fits if its worst-case
-            # block count does — so a long prompt whose prefix is
-            # cached (or simply a pool sized beyond slots * max_seq)
-            # is admissible where the dense row check would reject it.
-            need = self._pool.blocks_for(len(req.prompt) +
-                                         req.max_tokens)
-            if need > self._pool.usable_blocks:
-                raise EngineError(
-                    f"prompt ({len(req.prompt)}) + max_tokens "
-                    f"({req.max_tokens}) exceeds the KV pool "
-                    f"({self._pool.usable_blocks} blocks x "
-                    f"{self._chunk} tokens)")
-        elif len(req.prompt) + req.max_tokens > self._max_seq:
+        # The admission bound is POOL CAPACITY, not a per-slot row
+        # length: a request fits if its worst-case block count does.
+        need = self._pool.blocks_for(len(req.prompt) + req.max_tokens)
+        if need > self._pool.usable_blocks:
             raise EngineError(
                 f"prompt ({len(req.prompt)}) + max_tokens "
-                f"({req.max_tokens}) exceeds the engine cache "
-                f"(max_seq={self._max_seq})")
+                f"({req.max_tokens}) exceeds the KV pool "
+                f"({self._pool.usable_blocks} blocks x "
+                f"{self._chunk} tokens)")
         with self._cond:
             if self._failed:
                 raise EngineError(f"engine failed: {self._failed}")
@@ -1156,7 +1038,7 @@ class DecodeEngine:
         """Compile the prefill-chunk and decode-step programs (one
         tiny request end to end). max_tokens=2 so the request survives
         past its prefill-sampled first token and forces one
-        _engine_step — with max_tokens=1 the decode-step program would
+        _paged_step — with max_tokens=1 the decode-step program would
         first compile on the first production request, stalling it for
         the full XLA compile."""
         self.start()
@@ -1284,11 +1166,11 @@ class DecodeEngine:
 
     def host_tier_stats(self) -> Dict[str, Any]:
         """Host-tier introspection for /perf and the CLI tier line;
-        {} while the tier is off (dense engine or budget 0)."""
+        {} while the tier is off (budget 0)."""
         if self._host_pool is None:
             return {}
         out = dict(self._host_pool.stats())
-        out["budget_mb"] = float(self._kv_geometry.get("host_mb", 0.0))
+        out["budget_mb"] = self._kv_geometry["host_mb"]
         out["readmitted_blocks"] = self._readmitted_blocks
         trie = self.prefix_cache.stats()
         out["host_chunks"] = trie["host_chunks"]
@@ -1307,8 +1189,7 @@ class DecodeEngine:
         the slot's own reference drops right after in _retire), not
         a gather. Zero device work, zero host copies. The final
         partial prompt block (prompt tail + generated tokens share it)
-        is never published, exactly like the dense path's full-chunk
-        granularity."""
+        is never published."""
         slot = self._slots[i]
         self.prefix_cache.publish(
             slot.request.prompt, slot.prefilled,
@@ -1320,8 +1201,7 @@ class DecodeEngine:
         (table[len(held):blocks]), hand back unused reservation.
         Idempotent at the slot level — held/blocks/reserved are
         cleared, so a second call is a no-op instead of a
-        double-decrement (the cancel-mid-prefill hole the dense host
-        pool had)."""
+        double-decrement."""
         slot = self._slots[i]
         if slot.pending:
             # Pending re-admits never took pool references — drop the
@@ -1354,13 +1234,12 @@ class DecodeEngine:
         it was last read. The request itself ends when its last token
         has been read: :meth:`_finish_request`, an iteration later."""
         slot = self._slots[i]
-        if self._paged:
-            if error is None:
-                # Refcount transfer into the trie BEFORE the slot's
-                # own references drop; skipped on engine failure/
-                # shutdown (device state not trustworthy).
-                self._publish_paged(i)
-            self._release_paged(i)
+        if error is None:
+            # Refcount transfer into the trie BEFORE the slot's own
+            # references drop; skipped on engine failure/shutdown
+            # (device state not trustworthy).
+            self._publish_paged(i)
+        self._release_paged(i)
         self._retiring += 1      # before the slot stops counting
         slot.request = None
         slot.pos = slot.generated = slot.prefilled = 0
@@ -1431,9 +1310,8 @@ class DecodeEngine:
 
     def _record_admission(self, i: int, req: Request,
                           slot: "_Slot") -> None:
-        """One admission-telemetry record, shared by the dense and
-        paged admit paths (only reached while stepstats.ENABLED — the
-        call sites guard)."""
+        """One admission-telemetry record (only reached while
+        stepstats.ENABLED — the call site guards)."""
         stepstats.record_admission(
             slot=i, prompt_tokens=len(req.prompt),
             max_tokens=req.max_tokens, cached_tokens=slot.cached,
@@ -1522,7 +1400,10 @@ class DecodeEngine:
             req.kv_tier = tier
         return True
 
-    def _admit_paged(self) -> None:
+    def _admit(self) -> None:
+        # Traced-phase stamps taken under the lock, RECORDED after it:
+        # record_span does file I/O, and a slow disk under the
+        # admission condition would stall every concurrent submit().
         emits: List[tuple] = []
         with self._cond:
             free = [i for i, s in enumerate(self._slots)
@@ -1603,47 +1484,6 @@ class DecodeEngine:
         slot.blocks = j + 1
         return block
 
-    def _admit(self) -> None:
-        if self._paged:
-            self._admit_paged()
-            return
-        # Traced-phase stamps taken under the lock, RECORDED after it:
-        # record_span does file I/O, and a slow disk under the
-        # admission condition would stall every concurrent submit().
-        emits: List[tuple] = []
-        with self._cond:
-            for i, slot in enumerate(self._slots):
-                if not self._waiting:
-                    break
-                if slot.request is None:
-                    req = self._waiting.popleft()
-                    if req.cancelled:
-                        req._finish()
-                        _REQUESTS.labels(outcome="cancelled").inc()
-                        continue
-                    slot.request = req
-                    slot.pos = slot.generated = slot.prefilled = 0
-                    traced = (tracing.ENABLED and req.trace is not None
-                              and req.trace.sampled)
-                    self._stamp_admitted(req)
-                    if traced:
-                        # Queue-wait child span, retroactive from the
-                        # submit/admission monotonic stamps.
-                        emits.append((
-                            "engine.queue", req.trace,
-                            req.submitted_at, req.admitted_at,
-                            {"slot": i}))
-                    if stepstats.ENABLED:
-                        self._record_admission(i, req, slot)
-            _QUEUE_DEPTH.set(len(self._waiting))
-        live = len(self._live())
-        self.peak_live_slots = max(self.peak_live_slots, live)
-        _SLOTS_OCCUPIED.set(live)
-        for name, trace, t0, t1, attrs in emits:
-            tracing.record_span(name, "engine", trace,
-                                start_mono=t0, end_mono=t1,
-                                attrs=attrs)
-
     def _prefill_one(self) -> int:
         """Advance the first slot with un-prefilled prompt by ONE
         chunk; the final chunk samples the first token on the device
@@ -1697,18 +1537,11 @@ class DecodeEngine:
                 fault_injection.fire("engine.prefill", slot=i,
                                      start=start)
             _STEP_KIND["prefill"].inc()
-            if self._paged:
-                wb = self._ensure_block(i, start // self._chunk)
-                self._toks, self._cache = _paged_prefill_chunk(
-                    self._cfg, self._params, self._cache, buf,
-                    self._table_upload(i), jnp.int32(start),
-                    jnp.int32(valid), jnp.int32(wb), self._window,
-                    *first)
-            else:
-                self._toks, self._cache = _prefill_chunk(
-                    self._cfg, self._params, self._cache, buf,
-                    jnp.int32(i), jnp.int32(start), jnp.int32(valid),
-                    self._block, *first)
+            wb = self._ensure_block(i, start // self._chunk)
+            self._toks, self._cache = _paged_prefill_chunk(
+                self._cfg, self._params, self._cache, buf,
+                self._table_upload(i), jnp.int32(start),
+                jnp.int32(valid), jnp.int32(wb), self._window, *first)
             req.prefill_chunks += 1
             slot.prefilled = valid
             slot.pos = valid
@@ -1774,10 +1607,9 @@ class DecodeEngine:
     def _first_token_out(self, req: Request) -> None:
         """What the engine records once per request, when its first
         token (a final prefill chunk's) has been read."""
-        if self.prefix_cache is not None:
-            _PREFIX_TTFT.labels(
-                cache="hit" if req.cached_prompt_tokens else "miss"
-            ).observe(req.first_token_at - req.submitted_at)
+        _PREFIX_TTFT.labels(
+            cache="hit" if req.cached_prompt_tokens else "miss"
+        ).observe(req.first_token_at - req.submitted_at)
         if tracing.ENABLED and req.trace is not None \
                 and req.trace.sampled:
             # Chunked-prefill child span, closing at the first token:
@@ -1928,10 +1760,9 @@ class DecodeEngine:
         next position, so this step is read before anything else is
         planned: never dispatched ahead, and nothing is unread when
         it is (the caller landed it all to draft). Rollback of a
-        rejected suffix is a host-side frontier rewind (dense: rows
-        past the frontier stay masked; paged: the grown block-table
-        tail is truncated and its reservation returned). Returns
-        tokens emitted."""
+        rejected suffix is a host-side frontier rewind: the grown
+        block-table tail is truncated and its reservation returned.
+        Returns tokens emitted."""
         drafts_np = np.zeros((len(self._slots), self._spec_k), np.int32)
         spec_np = np.zeros((len(self._slots),), np.int32)
         for i in live:
@@ -1945,26 +1776,19 @@ class DecodeEngine:
             fault_injection.fire("engine.verify", live=len(live),
                                  drafted=int(spec_np.sum()))
         _STEP_KIND["verify"].inc()
-        if self._paged:
-            # Back every position the window may write from the slots'
-            # admission reservations (the remaining-1 draft clamp keeps
-            # the window inside the reserved worst case).
-            for i in live:
-                slot = self._slots[i]
-                for j in range(slot.pos // self._chunk,
-                               (slot.pos + int(spec_np[i]))
-                               // self._chunk + 1):
-                    self._ensure_block(i, j)
-            targets, accepts, self._toks, self._cache = \
-                _paged_spec_step(
-                    self._cfg, self._params, self._cache, self._toks,
-                    jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
-                    self._table_upload(), self._window, temps, seeds)
-        else:
-            targets, accepts, self._toks, self._cache = _spec_step(
-                self._cfg, self._params, self._cache, self._toks,
-                jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
-                temps, seeds, self._block)
+        # Back every position the window may write from the slots'
+        # admission reservations (the remaining-1 draft clamp keeps
+        # the window inside the reserved worst case).
+        for i in live:
+            slot = self._slots[i]
+            for j in range(slot.pos // self._chunk,
+                           (slot.pos + int(spec_np[i]))
+                           // self._chunk + 1):
+                self._ensure_block(i, j)
+        targets, accepts, self._toks, self._cache = _paged_spec_step(
+            self._cfg, self._params, self._cache, self._toks,
+            jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
+            self._table_upload(), self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, accepts)
         self._phase.enter("fetch")
@@ -2004,23 +1828,22 @@ class DecodeEngine:
                     # This slot's traffic doesn't repeat: every future
                     # draft would widen the verify window for nothing.
                     slot.spec_off = True
-            if self._paged:
-                # Block-table truncate: blocks grown for the rejected
-                # suffix go back (refcount 1 — decode blocks are never
-                # shared) and their reservation draws are RE-PROMISED
-                # (release + reserve is atomic on this thread, and the
-                # just-freed block guarantees available() >= 1), so
-                # the preemption-free admission invariant holds: the
-                # slot keeps its worst case, it just returns the
-                # physical blocks until the frontier really gets there.
-                needed = (base_pos + a) // self._chunk + 1
-                while slot.blocks > needed:
-                    j = slot.blocks - 1
-                    self._pool.release(int(self._table[i, j]))
-                    self._pool.reserve(1)
-                    self._table[i, j] = 0
-                    slot.blocks = j
-                    slot.reserved += 1
+            # Block-table truncate: blocks grown for the rejected
+            # suffix go back (refcount 1 — decode blocks are never
+            # shared) and their reservation draws are RE-PROMISED
+            # (release + reserve is atomic on this thread, and the
+            # just-freed block guarantees available() >= 1), so the
+            # preemption-free admission invariant holds: the slot
+            # keeps its worst case, it just returns the physical
+            # blocks until the frontier really gets there.
+            needed = (base_pos + a) // self._chunk + 1
+            while slot.blocks > needed:
+                j = slot.blocks - 1
+                self._pool.release(int(self._table[i, j]))
+                self._pool.reserve(1)
+                self._table[i, j] = 0
+                slot.blocks = j
+                slot.reserved += 1
             # Its tokens are out already: both halves of its end.
             outcome = self._maybe_retire(i)
             if outcome is not None:
@@ -2092,19 +1915,14 @@ class DecodeEngine:
         _STEP_KIND["decode"].inc()
         if any(e.t0 is not None for e in self._behind):
             _LOOKAHEAD.inc()
-        if self._paged:
-            # Lazy growth BEFORE the step: each live slot's write
-            # position must be backed (reservation guarantees a block
-            # exists — admission is preemption-free).
-            for i in live:
-                self._ensure_block(i, self._slots[i].pos // self._chunk)
-            nxt, self._cache = _paged_step(
-                self._cfg, self._params, self._cache, self._toks, pos,
-                self._table_upload(), self._window, temps, seeds)
-        else:
-            nxt, self._cache = _engine_step(
-                self._cfg, self._params, self._cache, self._toks, pos,
-                temps, seeds, self._block)
+        # Lazy growth BEFORE the step: each live slot's write position
+        # must be backed (reservation guarantees a block exists —
+        # admission is preemption-free).
+        for i in live:
+            self._ensure_block(i, self._slots[i].pos // self._chunk)
+        nxt, self._cache = _paged_step(
+            self._cfg, self._params, self._cache, self._toks, pos,
+            self._table_upload(), self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
         self._toks, chosen = nxt if isinstance(nxt, tuple) \
@@ -2123,18 +1941,15 @@ class DecodeEngine:
     def _record_step(self, t0: float, pf: int, dc: int) -> None:
         """One step-ring record for an iteration that did work (only
         reached while stepstats.ENABLED — the caller guards)."""
-        kv_free = kv_usable = None
-        if self._paged:
-            kv_free = self._pool.free_blocks()
-            kv_usable = self._pool.usable_blocks
         stepstats.record(
             dur=time.perf_counter() - t0,
             phase=("mixed" if pf and dc
                    else "prefill" if pf else "decode"),
             live_slots=len(self._live()),
             queue_depth=len(self._waiting),
-            prefill_tokens=pf, decode_tokens=dc, paged=self._paged,
-            kv_free=kv_free, kv_usable=kv_usable,
+            prefill_tokens=pf, decode_tokens=dc, paged=True,
+            kv_free=self._pool.free_blocks(),
+            kv_usable=self._pool.usable_blocks,
             dispatch_s=self._step_dispatch_s if dc else None,
             device_s=self._step_device_s if dc else None,
             spec_drafted=self._step_spec_drafted if dc else 0,

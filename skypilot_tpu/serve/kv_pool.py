@@ -1,13 +1,11 @@
 """Paged KV-cache block pool: host-side bookkeeping for the ONE
-device-resident pool of KV blocks the paged decode engine allocates
-slots and the shared-prefix cache out of.
+device-resident pool of KV blocks the decode engine allocates slots
+and the shared-prefix cache out of.
 
-The dense engine gives every slot a full ``(layers, max_seq, ...)``
-cache row, so concurrency is sized for the worst-case sequence (and it
-has no prefix cache — the old host-side splice pool was retired when
-the paged trie subsumed it). Paging collapses slot growth and prefix
-sharing into one device buffer of ``num_blocks`` fixed-size blocks
-(block = the engine's prefill chunk):
+A full ``(layers, max_seq, ...)`` cache row a slot would size
+concurrency for the worst-case sequence. Paging collapses slot growth
+and prefix sharing into one device buffer of ``num_blocks`` fixed-size
+blocks (block = the engine's prefill chunk):
 
   * slots acquire blocks lazily as they prefill/decode (a per-slot
     block TABLE maps logical chunk index -> physical block id);
@@ -336,9 +334,8 @@ class _BlockNode:
 
 
 class PagedPrefixCache:
-    """Chunk-granular trie over POOL BLOCKS — the only prefix-cache
-    representation (the dense host-pool splice cache is retired):
-    a cached chunk IS a device block, a hit IS a table write.
+    """Chunk-granular trie over POOL BLOCKS: a cached chunk IS a
+    device block, a hit IS a table write.
 
     Eviction is LRU over unpinned leaves (an interior node's block is a
     dependency of every deeper cached prefix) and runs on demand from

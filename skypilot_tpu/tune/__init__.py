@@ -20,7 +20,7 @@ model family and topology. This package closes the loop the ROADMAP
   assumes it).
 * :mod:`skypilot_tpu.tune.sweep` — the offline sweep driver behind
   ``stpu tune``: candidate configs measured through the existing
-  ``decode_bench.measure_engine_{ragged,paged,spec,q8}`` legs (tok/s
+  ``decode_bench.measure_engine_{paged,spec,q8}`` legs (tok/s
   headline; stepstats dispatch/device means as diagnostics), losing
   configs pruned early at small step counts.
 

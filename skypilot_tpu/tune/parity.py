@@ -47,12 +47,11 @@ def _tiny_model(family: str):
     return mdl, cfg, mdl.init(cfg, jax.random.key(0))
 
 
-def _engine(cfg, params, *, paged: bool, kv_quant: bool,
-            max_seq: int, engine_kw: Optional[Dict[str, Any]] = None):
+def _engine(cfg, params, *, kv_quant: bool, max_seq: int,
+            engine_kw: Optional[Dict[str, Any]] = None):
     from skypilot_tpu.serve.decode_engine import DecodeEngine
     return DecodeEngine(cfg, params, slots=2, max_seq=max_seq,
-                        paged=paged, kv_quant=kv_quant,
-                        use_manifest=False,
+                        kv_quant=kv_quant, use_manifest=False,
                         **(engine_kw or {})).start()
 
 
@@ -64,7 +63,7 @@ def _drain(engine, specs):
 
 def check_parity(family: str, *, block: int = 0, chunk: int = 0,
                  window_blocks: int = 0, spec_k: int = 0,
-                 paged: bool = False, kv_quant: bool = False,
+                 kv_quant: bool = False,
                  max_seq: int = 64, n_requests: int = 4,
                  max_tokens: int = 6) -> None:
     """Raise :class:`ParityError` unless the engine at the candidate
@@ -83,9 +82,7 @@ def check_parity(family: str, *, block: int = 0, chunk: int = 0,
     if block:
         tuned_kw["block"] = int(block)
     if chunk:
-        tuned_kw["prefill_chunk"] = int(chunk)
-        if paged:
-            tuned_kw["kv_block_tokens"] = int(chunk)
+        tuned_kw["kv_block_tokens"] = int(chunk)
     if window_blocks:
         tuned_kw["window_blocks"] = int(window_blocks)
     if spec_k:
@@ -102,13 +99,13 @@ def check_parity(family: str, *, block: int = 0, chunk: int = 0,
         specs.append((prompt, max_tokens,
                       0.8 if seeded else 0.0, 40 + i))
 
-    tuned = _engine(cfg, params, paged=paged, kv_quant=kv_quant,
-                    max_seq=max_seq, engine_kw=tuned_kw)
+    tuned = _engine(cfg, params, kv_quant=kv_quant, max_seq=max_seq,
+                    engine_kw=tuned_kw)
     try:
         got = _drain(tuned, specs)
     finally:
         tuned.shutdown()
-    ref_engine = _engine(cfg, params, paged=paged, kv_quant=kv_quant,
+    ref_engine = _engine(cfg, params, kv_quant=kv_quant,
                          max_seq=max_seq)
     try:
         want = _drain(ref_engine, specs)
@@ -117,8 +114,7 @@ def check_parity(family: str, *, block: int = 0, chunk: int = 0,
 
     label = (f"{family} block={block or 'dflt'} chunk={chunk or 'dflt'}"
              f" window_blocks={window_blocks or 'dflt'}"
-             f" spec_k={spec_k or 'dflt'} paged={paged}"
-             f" kv_quant={kv_quant}")
+             f" spec_k={spec_k or 'dflt'} kv_quant={kv_quant}")
     for i, ((prompt, mt, temp, _seed), g, w) in enumerate(
             zip(specs, got, want)):
         if g != w:

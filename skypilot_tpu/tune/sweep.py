@@ -14,7 +14,6 @@ engine already threads through ``resolve_kv_geometry``):
 ====== ==================== ========================================
 mode   axes                 objective leg
 ====== ==================== ========================================
-ragged block x chunk        measure_engine_ragged (dense engine)
 paged  chunk x window       measure_engine_paged  (block pool)
 spec   spec_k               measure_engine_spec   (drafting depth)
 q8     chunk x window       measure_engine_q8     (int8 KV+weights)
@@ -23,9 +22,7 @@ q8     chunk x window       measure_engine_q8     (int8 KV+weights)
 tok/s is the headline objective; stepstats ``dispatch_ms_mean`` /
 ``device_ms_mean`` ride along as diagnostics in the manifest entry so
 a regression hunt can tell dispatch-bound from device-bound winners.
-Modes run in table order and merge into one entry per tuning key —
-``paged`` runs after ``ragged`` on purpose: both tune ``chunk`` and
-paged is the serving default, so its preference wins the shared knob.
+Modes run in table order and merge into one entry per tuning key.
 """
 from __future__ import annotations
 
@@ -37,14 +34,13 @@ from skypilot_tpu.tune import manifest as tune_manifest
 from skypilot_tpu.tune.parity import ParityError, check_parity
 
 FAMILIES = ("llama", "mixtral", "gemma")
-MODES = ("ragged", "paged", "spec", "q8")
+MODES = ("paged", "spec", "q8")
 
 # Candidate axes per mode. Values are chosen to stay aligned with the
 # engine's invariants by construction: chunk must divide max_seq
 # (resolve_kv_geometry halves it until it does), window is derived in
-# whole chunks, block is clamped to max_seq.
+# whole chunks.
 SEARCH_SPACE: Dict[str, Dict[str, Sequence[int]]] = {
-    "ragged": {"block": (128, 256, 512), "chunk": (32, 64, 128)},
     "paged": {"chunk": (32, 64, 128), "window_blocks": (2, 4, 8)},
     "spec": {"spec_k": (0, 2, 4, 8)},
     "q8": {"chunk": (32, 64, 128), "window_blocks": (2, 4, 8)},
@@ -56,14 +52,12 @@ SEARCH_SPACE: Dict[str, Dict[str, Sequence[int]]] = {
 # >= default holds by construction: both are measured the same way in
 # the same process).
 DEFAULTS: Dict[str, Dict[str, int]] = {
-    "ragged": {"block": 256, "chunk": 64},
     "paged": {"chunk": 64, "window_blocks": 4},
     "spec": {"spec_k": 0},
     "q8": {"chunk": 64, "window_blocks": 4},
 }
 
-_LEG_KEY = {"ragged": "engine_ragged_tok_s",
-            "paged": "engine_paged_tok_s",
+_LEG_KEY = {"paged": "engine_paged_tok_s",
             "spec": "engine_spec_tok_s",
             "q8": "engine_q8_tok_s"}
 
@@ -105,12 +99,6 @@ def _measure(mode: str, family: str, cand: Dict[str, int],
              budget: Dict[str, int], slots: int,
              shape_kw: Dict[str, Any]) -> Dict[str, Any]:
     from skypilot_tpu.benchmark import decode_bench
-    if mode == "ragged":
-        kw = {k: v for k, v in (("block", cand.get("block", 0)),
-                                ("prefill_chunk",
-                                 cand.get("chunk", 0))) if v}
-        return decode_bench.measure_engine_ragged(
-            family, slots=slots, engine_kw=kw, **budget, **shape_kw)
     if mode in ("paged", "q8"):
         kw = {}
         if cand.get("window_blocks"):
@@ -133,11 +121,9 @@ def _measure(mode: str, family: str, cand: Dict[str, int],
 def _gate(mode: str, family: str, cand: Dict[str, int]) -> None:
     kv_quant, _ = _QUANT.get(mode, (False, False))
     check_parity(
-        family,
-        block=cand.get("block", 0), chunk=cand.get("chunk", 0),
+        family, chunk=cand.get("chunk", 0),
         window_blocks=cand.get("window_blocks", 0),
-        spec_k=cand.get("spec_k", 0),
-        paged=(mode != "ragged"), kv_quant=kv_quant)
+        spec_k=cand.get("spec_k", 0), kv_quant=kv_quant)
 
 
 def _provenance(legs: Sequence[str]) -> Dict[str, str]:

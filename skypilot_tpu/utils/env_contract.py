@@ -201,17 +201,12 @@ _KNOBS = (
        "abort)."),
     # ------------------------------------------------ serve engine
     _k("STPU_ENGINE_SLOTS", "4",
-       "Decode-engine slot count (continuous-batching concurrency)."),
-    _k("STPU_KV_PAGED", "1",
-       "\"0\" falls back to dense per-slot cache rows (no prefix "
-       "cache, no quantized KV); default serves from the paged KV "
-       "block pool (one device pool + per-slot block tables, "
-       "zero-copy prefix aliasing). Bit-identical either way while "
-       "STPU_KV_QUANT=0."),
+       "Decode-engine slot count (continuous-batching concurrency), "
+       "at least 1."),
     _k("STPU_KV_QUANT", "0",
        "\"1\" stores int8 KV blocks + per-(layer, block, head) f32 "
        "scales in the paged pool — ~2x blocks at the same HBM "
-       "budget (auto pool sizing doubles). Requires STPU_KV_PAGED=1; "
+       "budget (auto pool sizing doubles). "
        "NOT bit-identical to bf16, gated by the tests/test_quant.py "
        "parity suite."),
     _k("STPU_WEIGHT_QUANT", "0",
@@ -232,7 +227,7 @@ _KNOBS = (
        "drafting."),
     _k("STPU_KV_POOL_BLOCKS", "0",
        "Paged-KV pool size in blocks incl. the scratch block (0 = "
-       "auto: slots * max_seq / block + 1, the dense HBM budget; "
+       "auto: slots * max_seq / block + 1; "
        "doubled under STPU_KV_QUANT=1 — int8 blocks are ~half the "
        "bytes)."),
     _k("STPU_KV_BLOCK_TOKENS", "0",
@@ -244,8 +239,7 @@ _KNOBS = (
        "trie: LRU-evicted prefix blocks spill D2H into a bounded "
        "host pool and re-admit H2D on a warm match instead of "
        "re-prefilling. 0 disables the tier (evictions drop the KV). "
-       "Rides the gang kv-config handshake; ignored on the dense "
-       "path."),
+       "Rides the gang kv-config handshake."),
     _k("STPU_TUNE_MANIFEST", None,
        "Tuning-manifest override for the decode engine: a path loads "
        "that sha256-pinned `stpu tune` manifest, \"0\" disables "

@@ -144,3 +144,54 @@ def tmp_state_dir(tmp_path, monkeypatch):
     paths.reset_for_tests()
     yield tmp_path / ".stpu"
     paths.reset_for_tests()
+
+
+def _reference_stream(mdl, cfg, params, prompt, max_tokens,
+                      temperature=0.0, seed=0, max_seq=64, chunk=None):
+    """The tokens the decode engine owes one request, from the
+    family's ROW-CACHE forward (``init_cache`` /
+    ``forward_with_cache``, what ``models.<family>.decode`` runs),
+    sampled by the engine's own rule: the token at absolute position p
+    is drawn with fold_in(fold_in(key(0), seed), p). Greedy it is
+    ``mdl.decode``'s stream; seeded it is what ``decode`` (which
+    splits one key) cannot say. ``max_seq`` is the engine's, so that
+    the attention tiles align (the bit-parity condition); ``chunk``
+    prefills the prompt in the engine's zero-padded chunks instead of
+    one pass, which makes a bf16 model's rounding the engine's too
+    (float32 agrees either way)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from skypilot_tpu.serve.decode_engine import _sample
+
+    def pick(logits, pos):
+        return _sample(logits, jnp.asarray([seed], jnp.uint32),
+                       jnp.asarray([pos], jnp.int32),
+                       jnp.asarray([temperature], jnp.float32))
+
+    n = len(prompt)
+    chunk = chunk or n
+    cache = mdl.init_cache(cfg, 1, max_seq)
+    for start in range(0, n, chunk):
+        piece = prompt[start:start + chunk]
+        buf = np.zeros((1, chunk), np.int32)
+        buf[0, :len(piece)] = piece
+        valid = start + len(piece)
+        logits, cache = mdl.forward_with_cache(
+            cfg, params, jnp.asarray(buf), cache, jnp.int32(start),
+            valid_len=jnp.int32(valid),
+            logits_at=jnp.int32(len(piece) - 1))
+    tok = pick(logits[:, 0], n)
+    out = [int(tok[0])]
+    for pos in range(n, n + max_tokens - 1):
+        logits, cache = mdl.forward_with_cache(
+            cfg, params, tok[:, None], cache, jnp.int32(pos))
+        tok = pick(logits[:, -1], pos + 1)
+        out.append(int(np.asarray(tok)[0]))
+    return out
+
+
+@pytest.fixture
+def reference_stream():
+    """:func:`_reference_stream`: the row-cache reference the engine's
+    seeded and greedy streams are held to."""
+    return _reference_stream

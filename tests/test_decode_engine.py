@@ -14,6 +14,7 @@ The contract under test, from strongest to weakest layer:
 """
 import json
 import threading
+import time
 import urllib.request
 
 import jax
@@ -196,8 +197,9 @@ def test_engine_sampling_reproducible_and_limits():
                            seed=42).result(timeout=300.0)
         other.result(timeout=300.0)
         assert r1 == r2
-        with pytest.raises(EngineError, match="exceeds"):
-            engine.submit(list(range(1, 30)), max_tokens=16)
+        # 2 slots x 32 tokens = 8 blocks of 8: 75 tokens need 10.
+        with pytest.raises(EngineError, match="exceeds the KV pool"):
+            engine.submit(list(range(1, 60)), max_tokens=16)
         with pytest.raises(EngineError, match="empty"):
             engine.submit([], max_tokens=4)
     finally:
@@ -273,32 +275,53 @@ def test_lb_metrics_include_replica_engine_families():
         httpd.shutdown()
 
 
-def test_serve_llm_legacy_path_still_serves():
-    """engine_slots=0 keeps the locked fixed-batch path working (the
-    comparability baseline), including its donated-cache _decode."""
+@pytest.mark.parametrize("how", ["call", "flag", "kv-paged"])
+def test_serve_llm_refuses_the_deleted_paths(how, capsys):
+    """One serving path: ``engine_slots=0`` / ``--engine-slots 0``
+    (which selected the locked fixed-batch path) is refused at
+    start-up with one sentence, and ``--kv-paged`` (which selected
+    the row-cache engine) is no argument at all."""
     from skypilot_tpu.recipes import serve_llm
-    cfg = llama.LlamaConfig.tiny(vocab_size=128)
-    params = llama.init(cfg, jax.random.key(0))
-    ready = threading.Event()
-    httpd = serve_llm.serve(cfg, params, 0, ready_event=ready,
-                            engine_slots=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    try:
-        assert ready.wait(timeout=300)
-        assert httpd.engine is None
-        port = httpd.server_address[1]
-        body = json.dumps({"prompt": [1, 2, 3],
-                           "max_tokens": 6}).encode()
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{port}/generate", data=body,
-            headers={"Content-Type": "application/json"})
-        with urllib.request.urlopen(req, timeout=120) as resp:
-            toks = json.loads(resp.read())["tokens"]
-        ref = llama.decode(cfg, params, jnp.asarray([[1, 2, 3]]),
-                           jnp.int32(3), 6, 128)
-        assert toks == [int(t) for t in ref[0]][:6]
-    finally:
-        httpd.shutdown()
+    if how == "call":
+        cfg = llama.LlamaConfig.tiny(vocab_size=128)
+        params = llama.init(cfg, jax.random.key(0))
+        with pytest.raises(ValueError) as err:
+            serve_llm.serve(cfg, params, 0, engine_slots=0)
+        assert str(err.value) == serve_llm.NO_ENGINE_SLOTS
+        return
+    argv = {"flag": ["--engine-slots", "0"],
+            "kv-paged": ["--kv-paged", "1"]}[how]
+    with pytest.raises(SystemExit) as err:
+        serve_llm.main(argv)
+    assert err.value.code == 2
+    said = capsys.readouterr().err
+    assert ("unrecognized arguments: --kv-paged" if how == "kv-paged"
+            else serve_llm.NO_ENGINE_SLOTS) in said
+
+
+def test_one_engine_no_paged_switch_in_any_signature():
+    """Nothing selects an engine: no ``paged`` / ``kv_paged`` argument
+    on the class, the geometry or the recipe."""
+    import inspect
+    from skypilot_tpu.recipes import serve_llm
+    from skypilot_tpu.serve import decode_engine
+    for fn in (DecodeEngine, decode_engine.resolve_kv_geometry,
+               serve_llm.serve):
+        names = set(inspect.signature(fn).parameters)
+        assert not names & {"paged", "kv_paged"}, fn
+
+
+def test_decode_engine_defines_only_the_programs_that_serve():
+    """The jitted programs of decode_engine.py are the three that
+    serve (found by these names in a device trace), the host tier's
+    two and the sampler — the set ``warmup()`` and the donation rule
+    (tests/test_static_analysis.py) have to know."""
+    from skypilot_tpu.serve import decode_engine
+    jitted = type(decode_engine._sample)
+    assert {name for name, obj in vars(decode_engine).items()
+            if isinstance(obj, jitted)} == {
+        "_paged_prefill_chunk", "_paged_step", "_paged_spec_step",
+        "_slice_block", "_host_restore_block", "_sample"}
 
 
 # ------------------------------------------------- shared-prefix KV cache
@@ -323,7 +346,7 @@ def test_prefix_hit_token_identical_and_fewer_steps(family):
     vocab = cfg.vocab_size
     params = mdl.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                          prefill_chunk=8, paged=True).start()
+                          prefill_chunk=8).start()
     try:
         shared = [int(t) for t in jax.random.randint(
             jax.random.key(11), (17,), 1, vocab)]  # 2 full 8-chunks
@@ -345,38 +368,35 @@ def test_prefix_hit_token_identical_and_fewer_steps(family):
         engine.shutdown()
 
 
-def test_prefix_hit_seeded_sampling_parity():
+def test_prefix_hit_seeded_sampling_parity(reference_stream):
     """A temperature>0 stream is bit-identical warm vs cold: the hit
     restores the exact KV rows prefill would recompute, and the
     fold_in(seed, position) keys never see the cache. The cold
-    baseline is the dense engine — which has NO prefix cache at all
-    now the splice pool is retired — and the warm engine is the paged
-    pool's always-on zero-copy trie."""
+    baseline is the row-cache reference, which has no prefix cache at
+    all; the warm run is the pool's always-on zero-copy trie."""
     cfg = llama.LlamaConfig.tiny(vocab_size=128)
     params = llama.init(cfg, jax.random.key(0))
     prompt = [int(t) for t in jax.random.randint(
         jax.random.key(3), (21,), 1, 128)]
 
-    def run(engine_paged):
-        engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                              prefill_chunk=8,
-                              paged=engine_paged).start()
-        try:
-            # Sequential on purpose: the second submission must see the
-            # first's published chunks (cache-hit path).
-            first = engine.submit(prompt, max_tokens=6,
-                                  temperature=0.9, seed=17)
-            first_toks = first.result(timeout=300.0)
-            second = engine.submit(prompt, max_tokens=6,
-                                   temperature=0.9, seed=17)
-            return first_toks, second.result(timeout=300.0), second
-        finally:
-            engine.shutdown()
-
-    cold1, cold2, _ = run(engine_paged=False)
-    warm1, warm2, warm_req = run(engine_paged=True)
-    assert cold1 == cold2 == warm1 == warm2
-    assert warm_req.cached_prompt_tokens > 0  # the hit really happened
+    engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
+                          prefill_chunk=8).start()
+    try:
+        # Sequential on purpose: the second submission must see the
+        # first's published chunks (cache-hit path).
+        first = engine.submit(prompt, max_tokens=6,
+                              temperature=0.9, seed=17)
+        first_toks = first.result(timeout=300.0)
+        second = engine.submit(prompt, max_tokens=6,
+                               temperature=0.9, seed=17)
+        second_toks = second.result(timeout=300.0)
+    finally:
+        engine.shutdown()
+    cold = reference_stream(llama, cfg, params, prompt, 6,
+                            temperature=0.9, seed=17, chunk=8)
+    assert cold == first_toks == second_toks
+    assert first.cached_prompt_tokens == 0
+    assert second.cached_prompt_tokens > 0    # the hit really happened
 
 
 # The dense splice cache (PrefixCache + _insert_chunk/_gather_chunk)
@@ -397,7 +417,7 @@ def test_engine_slot_churn_respects_pool_budget_and_parity():
     # 9 usable 8-token blocks: one live request plus a couple of
     # cached chunks — publish-on-free forces constant eviction.
     engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                          prefill_chunk=8, paged=True,
+                          prefill_chunk=8,
                           kv_pool_blocks=10)
     rng = random.Random(2)
     for _ in range(6):
@@ -428,7 +448,7 @@ def test_cancel_mid_prefill_releases_block_refcounts():
     cfg = llama.LlamaConfig.tiny(vocab_size=128)
     params = llama.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=1, max_seq=64,
-                          prefill_chunk=8, paged=True)
+                          prefill_chunk=8)
     # NOT started: drive _admit/_prefill_one/_decode_step directly.
     shared = [int(t) for t in jax.random.randint(
         jax.random.key(5), (18,), 1, 128)]
@@ -468,7 +488,7 @@ def test_prefix_metrics_reach_replica_endpoint():
     saved_before = metrics_lib.REGISTRY.counter(
         "stpu_engine_prefill_tokens_saved_total").get()
     engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                          prefill_chunk=8, paged=True).start()
+                          prefill_chunk=8).start()
     try:
         shared = list(range(1, 18))
         engine.submit(shared, max_tokens=2).result(timeout=300.0)
@@ -600,6 +620,11 @@ def test_lb_proxies_through_prefix_affinity_policy():
             data=body, headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=120) as resp:
             assert len(json.loads(resp.read())["tokens"]) == 3
+        # The LB returns the slot AFTER it has written the response,
+        # so the client can be here first.
+        deadline = time.monotonic() + 5.0
+        while policy._inflight[url] and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert policy._inflight[url] == 0    # slot returned
     finally:
         if lb is not None:

@@ -119,7 +119,7 @@ def test_engine_serves_the_reference_greedy_tokens():
     cfg = _tiny()
     params = deepseek.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                          prefill_chunk=8, paged=True,
+                          prefill_chunk=8,
                           use_manifest=False).start()
     try:
         prompts = [list(map(int, _tokens(n, seed=n))) for n in (19, 5, 9)]
@@ -311,7 +311,7 @@ def test_latent_pool_costs_1152_bytes_a_token_a_layer():
     kv_pool.block_bytes_for all read blocks x 64 x layers x 576 x 2."""
     cfg = _tiny(dtype=jnp.bfloat16, kv_lora_rank=512, qk_rope_head_dim=64)
     params = deepseek.init(cfg, jax.random.key(0))
-    engine = DecodeEngine(cfg, params, slots=2, max_seq=256, paged=True,
+    engine = DecodeEngine(cfg, params, slots=2, max_seq=256,
                           kv_block_tokens=64, use_manifest=False)
     try:
         blocks = engine.kv_config()["pool_blocks"]
@@ -357,7 +357,7 @@ def test_routing_counters_move_by_what_the_batch_chose():
     cfg = _tiny()
     params = deepseek.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                          prefill_chunk=8, paged=True, use_manifest=False)
+                          prefill_chunk=8, use_manifest=False)
     try:
         for n in (5, 7):
             engine.submit(list(map(int, _tokens(n, seed=n))),
@@ -400,18 +400,15 @@ def test_refusals_name_the_family():
     cfg = _tiny()
     params = deepseek.init(cfg, jax.random.key(0))
     with pytest.raises(NotImplementedError, match="deepseek.*int8 pool"):
-        DecodeEngine(cfg, params, slots=2, max_seq=64, paged=True,
+        DecodeEngine(cfg, params, slots=2, max_seq=64,
                      kv_quant=True, use_manifest=False)
     with pytest.raises(NotImplementedError, match="deepseek.*int8 wei"):
-        DecodeEngine(cfg, params, slots=2, max_seq=64, paged=True,
+        DecodeEngine(cfg, params, slots=2, max_seq=64,
                      weight_quant=True, use_manifest=False)
-    with pytest.raises(NotImplementedError, match="deepseek.*row cache"):
-        DecodeEngine(cfg, params, slots=2, max_seq=64, paged=False,
-                     use_manifest=False)
     mesh, rules = gang_replica.build_mesh(
         gang_replica.ReplicaTopology(hosts=1, ici_axes={"tp": 2}))
     with pytest.raises(NotImplementedError, match="deepseek.*tp > 1"):
-        DecodeEngine(cfg, params, slots=2, max_seq=64, paged=True,
+        DecodeEngine(cfg, params, slots=2, max_seq=64,
                      mesh=mesh, rules=rules, use_manifest=False)
     with pytest.raises(NotImplementedError, match="deepseek.*tp > 1"):
         gang_replica.cache_shardings(cfg, mesh, rules)
@@ -419,8 +416,10 @@ def test_refusals_name_the_family():
     lora["dense_layers"]["wq_a_lora_a"] = jnp.zeros((1, cfg.dim, 2))
     with pytest.raises(NotImplementedError, match="deepseek.*LoRA"):
         deepseek.forward(cfg, lora, jnp.zeros((1, 4), jnp.int32))
-    with pytest.raises(NotImplementedError, match="deepseek.*row cache"):
-        deepseek.decode(cfg, params, jnp.zeros((1, 4), jnp.int32), 4, 2, 8)
+    # No row cache to refuse: the family has the one cached forward.
+    for gone in ("init_cache", "forward_with_cache", "decode",
+                 "verify_step", "paged_cache_specs"):
+        assert not hasattr(deepseek, gone), gone
     with pytest.raises(ValueError, match="deepseek"):
         dataclasses.replace(cfg, ep_size=3)
 

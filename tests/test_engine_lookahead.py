@@ -5,8 +5,10 @@ when that step's tokens are READ, an iteration later. Held here:
 
   * the served tokens are the blocking order's (everything read before
     anything is planned — the drained case of the same loop) and the
-    reference decode's, for every family, both caches, greedy and
-    seeded sampling, through every way a request can end;
+    reference decode's, for every family, the bf16 and the int8 pool,
+    greedy and seeded sampling, through every way a request can end
+    (the int8 pool's reference is the same engine serving the request
+    alone, read before anything is planned);
   * a block freed at dispatch and handed out again at once is never
     written early;
   * ``stpu_engine_lookahead_steps_total`` says how often the loop ran
@@ -38,12 +40,15 @@ def _tiny(family="llama"):
     return llama, llama.LlamaConfig.tiny(vocab_size=128)
 
 
-def _engine(family="llama", paged=True, **kw):
+_SIZES = {"slots": 2, "max_seq": 64, "prefill_chunk": 8,
+          "use_manifest": False}
+
+
+def _engine(family="llama", int8=False, **kw):
     mdl, cfg = _tiny(family)
     params = mdl.init(cfg, jax.random.key(0))
-    kw = {"slots": 2, "max_seq": 64, "prefill_chunk": 8,
-          "use_manifest": False, **kw}
-    return mdl, cfg, params, DecodeEngine(cfg, params, paged=paged, **kw)
+    return mdl, cfg, params, DecodeEngine(
+        cfg, params, **{**_SIZES, "kv_quant": int8, **kw})
 
 
 def _drive(engine, blocking=False, each=None, rounds=600):
@@ -65,7 +70,16 @@ def _drive(engine, blocking=False, each=None, rounds=600):
     raise AssertionError("engine did not quiesce")
 
 
-def _reference(mdl, cfg, params, prompt, n):
+def _reference(mdl, cfg, params, prompt, n, int8=False):
+    """The greedy tokens a request is owed: the row-cache decode's,
+    or, for the int8 pool (not bit-identical to bf16 by design), what
+    the same engine serves when the request is alone and every
+    iteration is read before the next is planned."""
+    if int8:
+        engine = DecodeEngine(cfg, params, kv_quant=True, **_SIZES)
+        req = engine.submit(prompt, max_tokens=n)
+        _drive(engine, blocking=True)
+        return req.result(timeout=5.0)
     ref = mdl.decode(cfg, params, jnp.asarray([prompt]),
                      jnp.int32(len(prompt)), n, len(prompt) + n)
     return [int(t) for t in ref[0]]
@@ -99,16 +113,16 @@ def _specs(cfg, seed):
 
 
 # ===================================================== (a) token parity
-@pytest.mark.parametrize("family,paged", [
-    ("llama", True), ("llama", False), ("mixtral", True),
-    ("mixtral", False), ("gemma", True), ("deepseek", True)])
-def test_lookahead_serves_the_blocking_orders_tokens(family, paged):
+@pytest.mark.parametrize("family,int8", [
+    ("llama", False), ("llama", True), ("mixtral", False),
+    ("mixtral", True), ("gemma", False), ("deepseek", False)])
+def test_lookahead_serves_the_blocking_orders_tokens(family, int8):
     """Eight ragged requests over two slots, so slots are retired and
     taken over while their last tokens are unread: the loop that runs
     ahead, the same loop drained every iteration, and (greedy) the
     reference decode give the same tokens; and a request did end on
     the step after another was admitted into its slot."""
-    mdl, cfg, params, ahead = _engine(family, paged)
+    mdl, cfg, params, ahead = _engine(family, int8)
     specs = _specs(cfg, seed=3)
     took_over = []
 
@@ -126,28 +140,28 @@ def test_lookahead_serves_the_blocking_orders_tokens(family, paged):
         return [r.result(timeout=5.0) for r in reqs]
 
     got = serve(ahead, each=watch)
-    _, _, _, drained = _engine(family, paged)
+    _, _, _, drained = _engine(family, int8)
     assert got == serve(drained, blocking=True)
     assert took_over, "no slot changed hands with a token unread"
     assert decode_engine._LOOKAHEAD.get() > 0
     for (p, m, t, _), toks in zip(specs, got):
         assert len(toks) == m
         if t == 0.0 and family != "deepseek":   # no row-cache decode
-            assert toks == _reference(mdl, cfg, params, p, m)
+            assert toks == _reference(mdl, cfg, params, p, m, int8)
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_started_engine_matches_the_hand_driven_one(paged):
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_started_engine_matches_the_hand_driven_one(int8):
     """The engine thread's loop and the hand-driven one are the same
     code: same tokens, whenever the submissions arrive."""
-    mdl, cfg, params, byhand = _engine("llama", paged)
+    mdl, cfg, params, byhand = _engine("llama", int8)
     specs = _specs(cfg, seed=5)
     reqs = [byhand.submit(p, max_tokens=m, temperature=t, seed=s)
             for p, m, t, s in specs]
     _drive(byhand)
     want = [r.result(timeout=5.0) for r in reqs]
     engine = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                          prefill_chunk=8, paged=paged).start()
+                          prefill_chunk=8, kv_quant=int8).start()
     try:
         reqs = []
         for p, m, t, s in specs:
@@ -159,34 +173,35 @@ def test_started_engine_matches_the_hand_driven_one(paged):
         engine.shutdown()
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_cache_full_ends_a_request_at_dispatch(paged):
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_cache_full_ends_a_request_at_dispatch(int8):
     """A slot whose next write would be the row's last position is
     retired when that step is dispatched, with ``cache_full``, and its
     tokens are the reference's first ones."""
-    mdl, cfg, params, engine = _engine("llama", paged)
+    mdl, cfg, params, engine = _engine("llama", int8)
     prompt = list(range(1, 12))
+    want = _reference(mdl, cfg, params, prompt, 20, int8)
+    want_other = _reference(mdl, cfg, params, prompt[:4], 3, int8)
     engine._limit = 16           # pos 11 after the prompt: 5 tokens fit
     before = _outcomes()
     req = engine.submit(prompt, max_tokens=20)
     other = engine.submit(prompt[:4], max_tokens=3)
     _drive(engine)
     got = req.result(timeout=5.0)
-    assert got == _reference(mdl, cfg, params, prompt, 20)[:len(got)]
+    assert got == want[:len(got)]
     assert len(got) == 16 - 1 - len(prompt) + 1
-    assert other.result(timeout=5.0) == \
-        _reference(mdl, cfg, params, prompt[:4], 3)
+    assert other.result(timeout=5.0) == want_other
     after = _outcomes()
     assert after["cache_full"] - before["cache_full"] == 1
     assert after["ok"] - before["ok"] == 1
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_cancel_mid_decode_ends_behind_its_unread_tokens(paged):
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_cancel_mid_decode_ends_behind_its_unread_tokens(int8):
     """A cancel is seen when the next step is planned: the slot is
     retired there, the token already dispatched still arrives, and the
     stream ends after it — a prefix of the uncancelled stream."""
-    mdl, cfg, params, engine = _engine("llama", paged)
+    mdl, cfg, params, engine = _engine("llama", int8)
     prompt = [5, 9, 42, 7]
     before = _outcomes()
     req = engine.submit(prompt, max_tokens=30)
@@ -200,13 +215,13 @@ def test_cancel_mid_decode_ends_behind_its_unread_tokens(paged):
     _drive(engine)
     got = req.result(timeout=5.0)
     assert 0 < len(got) < 30
-    assert got == _reference(mdl, cfg, params, prompt, 30)[:len(got)]
+    assert got == _reference(mdl, cfg, params, prompt, 30,
+                             int8)[:len(got)]
     assert keep.result(timeout=5.0) == \
-        _reference(mdl, cfg, params, prompt[::-1], 12)
+        _reference(mdl, cfg, params, prompt[::-1], 12, int8)
     after = _outcomes()
     assert after["cancelled"] - before["cancelled"] == 1
-    if paged:
-        _pool_is_whole(engine)
+    _pool_is_whole(engine)
 
 
 @pytest.mark.parametrize("family", ["llama", "deepseek"])
@@ -237,7 +252,7 @@ def test_block_freed_at_dispatch_is_reused_at_once_and_never_early():
     those very blocks in the next iteration, its chunk queued behind
     that step on the device. Both streams are the reference's."""
     mdl, cfg, params, engine = _engine(
-        "llama", True, kv_pool_blocks=5)       # 4 usable blocks of 8
+        "llama", kv_pool_blocks=5)             # 4 usable blocks of 8
     first_p = list(range(1, 18))               # 17 + 7 = 24: 3 blocks
     second_p = list(range(40, 60))             # 20 + 10 = 30: 4 blocks
     first = engine.submit(first_p, max_tokens=7)
@@ -278,7 +293,7 @@ def test_block_freed_at_dispatch_is_reused_at_once_and_never_early():
 
 
 # ================================================= (c) the counter
-@pytest.mark.parametrize("mode", ["paged", "dense", "drafting",
+@pytest.mark.parametrize("mode", ["bf16", "int8", "drafting",
                                   "drafts-off"])
 def test_lookahead_counter_says_how_often_the_loop_ran_ahead(mode):
     """Over a run of plain decode steps nearly every step is
@@ -289,7 +304,7 @@ def test_lookahead_counter_says_how_often_the_loop_ran_ahead(mode):
     spec = {"drafting": {"spec_k": 4, "spec_ngram": 2},
             "drafts-off": {"spec_k": 4, "spec_ngram": 2,
                            "spec_min_accept": 1.5}}.get(mode, {})
-    mdl, cfg, params, engine = _engine("llama", mode != "dense", **spec)
+    mdl, cfg, params, engine = _engine("llama", mode == "int8", **spec)
     prompts = [[5, 6, 7] * 6, [9, 4, 9, 4, 9, 4, 9, 4]]
     ahead0 = decode_engine._LOOKAHEAD.get()
     steps0 = {k: c.get() for k, c in decode_engine._STEP_KIND.items()}
@@ -298,7 +313,7 @@ def test_lookahead_counter_says_how_often_the_loop_ran_ahead(mode):
     ahead = decode_engine._LOOKAHEAD.get() - ahead0
     steps = {k: c.get() - steps0[k]
              for k, c in decode_engine._STEP_KIND.items()}
-    _, _, _, plain = _engine("llama", mode != "dense")
+    _, _, _, plain = _engine("llama", mode == "int8")
     want = [plain.submit(p, max_tokens=40) for p in prompts]
     _drive(plain, blocking=True)
     assert [r.result(timeout=5.0) for r in reqs] == \
@@ -317,11 +332,11 @@ def test_lookahead_counter_says_how_often_the_loop_ran_ahead(mode):
 
 
 # ===================================== (d) ends with results unread
-def _mid_flight(paged=True):
+def _mid_flight(int8=False):
     """Three requests over two slots, driven by hand to the iteration
     in which the shortest one's last step has been dispatched and not
     read: its slot is retired, its request is not finished."""
-    mdl, cfg, params, engine = _engine("llama", paged)
+    mdl, cfg, params, engine = _engine("llama", int8)
     specs = [([7, 3, 9, 1], 3), ([2, 8, 6, 4, 1], 30),
              ([11, 12, 13], 25)]
     reqs = [engine.submit(p, max_tokens=m) for p, m in specs]
@@ -333,13 +348,13 @@ def _mid_flight(paged=True):
             break
     assert engine._retiring == 1 and engine._behind
     assert reqs[0].emitted < 3
-    refs = [_reference(mdl, cfg, params, p, m) for p, m in specs]
+    refs = [_reference(mdl, cfg, params, p, m, int8) for p, m in specs]
     return engine, reqs, refs
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_shutdown_reads_what_is_unread_before_it_frees_slots(paged):
-    engine, reqs, refs = _mid_flight(paged)
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_shutdown_reads_what_is_unread_before_it_frees_slots(int8):
+    engine, reqs, refs = _mid_flight(int8)
     before = _outcomes()
     assert engine.in_flight() == 3       # one retiring, one live, one queued
     engine._stop = True
@@ -356,8 +371,7 @@ def test_shutdown_reads_what_is_unread_before_it_frees_slots(paged):
     assert after["ok"] - before["ok"] == 1
     assert after["shutdown"] - before["shutdown"] == 2
     assert engine.in_flight() == 0
-    if paged:
-        _pool_is_whole(engine)
+    _pool_is_whole(engine)
 
 
 def test_injected_step_fault_with_results_unread():
@@ -410,7 +424,7 @@ def test_supervisor_restart_after_a_fault_with_results_unread():
     params = mdl.init(cfg, jax.random.key(0))
     sup = decode_engine.EngineSupervisor(
         lambda: DecodeEngine(cfg, params, slots=2, max_seq=64,
-                             prefill_chunk=8, paged=True),
+                             prefill_chunk=8),
         backoff_base=0.01, poll_interval=0.01).start()
     try:
         prompt = [4, 8, 15, 16, 23, 42]
@@ -466,9 +480,9 @@ def test_warmup_reaches_every_program_the_loop_dispatches():
 
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
-    for paged in (True, False):
+    for int8 in (False, True):
         engine = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                              prefill_chunk=8, paged=paged).start()
+                              prefill_chunk=8, kv_quant=int8).start()
         try:
             engine.warmup()
             before = built()
@@ -478,6 +492,6 @@ def test_warmup_reaches_every_program_the_loop_dispatches():
                 max_tokens=rng.randint(1, 9)) for _ in range(8)]
             for r in reqs:
                 r.result(timeout=120.0)
-            assert built() == before, paged
+            assert built() == before, int8
         finally:
             engine.shutdown()

@@ -72,7 +72,7 @@ PROMPTS = [[1 + i, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11][:3 + 2 * i]
 
 
 def test_every_phase_counts_and_steps_match_the_step_ring(armed):
-    delta, tokens = _serve(PROMPTS, 12, paged=True)
+    delta, tokens = _serve(PROMPTS, 12)
     for phase in PHASES:
         assert delta[f"phase/{phase}"] > 0.0, phase
     ring = stepstats.steps_tail()
@@ -94,8 +94,7 @@ def test_phases_partition_the_loop(armed):
     ``with`` blocks around the phases missed 3 % of it on the chip (a
     returning frame's device arrays, PERF.md PR 26); the clock's
     switch leaves nothing between two phases."""
-    delta, _ = _serve([[7 + i, 3, 9] * 4 for i in range(8)], 80,
-                      paged=True)
+    delta, _ = _serve([[7 + i, 3, 9] * 4 for i in range(8)], 80)
     ring = stepstats.steps_tail()
     span = ring[-1]["mono"] - (ring[0]["mono"] - ring[0]["dur"])
     assert span >= 0.5, f"the run was too short to judge: {span:.3f} s"
@@ -103,16 +102,19 @@ def test_phases_partition_the_loop(armed):
     assert abs(inside - span) <= 0.02 * span, (inside, span)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_queue_wait_and_prefill_split_the_ttft(paged):
-    delta, tokens = _serve(PROMPTS, 4, paged=paged)
+@pytest.mark.parametrize("pool_blocks", [0, 3], ids=["slots", "blocks"])
+def test_queue_wait_and_prefill_split_the_ttft(pool_blocks):
+    """Whatever a request waits for at the queue's head: a free slot
+    (five requests, two slots) or free blocks (two usable blocks, and
+    a request of up to 15 tokens reserves both, so one is served at a
+    time beside an empty slot)."""
+    delta, tokens = _serve(PROMPTS, 4, kv_pool_blocks=pool_blocks)
     n = len(tokens)
     assert delta["ttft/count"] == n
     assert delta["queue_wait/count"] == n
     assert delta["prefill/count"] == n
     assert delta["queue_wait/sum"] + delta["prefill/sum"] == \
         pytest.approx(delta["ttft/sum"], abs=1e-6 * n)
-    # Five requests, two slots: somebody waited for a slot.
     assert delta["queue_wait/sum"] > 0.0
 
 
@@ -121,7 +123,7 @@ def test_itl_counts_every_gap_after_a_first_token(spec_k):
     # A self-repeating prompt makes the n-gram matcher draft, so the
     # speculative case emits several tokens from one verify step.
     prompts = [[5, 6, 7] * 6, [9, 4, 9, 4, 9, 4, 9, 4], [3, 1, 2]]
-    delta, tokens = _serve(prompts, 10, paged=True, spec_k=spec_k,
+    delta, tokens = _serve(prompts, 10, spec_k=spec_k,
                            spec_ngram=2)
     emitted = sum(len(t) for t in tokens)
     assert delta["tokens"] == emitted

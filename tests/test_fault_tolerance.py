@@ -753,37 +753,6 @@ def test_scale_down_without_drain_support_terminates_immediately():
     server.shutdown()
 
 
-def test_serve_llm_drain_endpoint_legacy_path():
-    """The legacy (engine_slots=0) path honors /drain too: admissions
-    stop, in-flight handler count is reported."""
-    from skypilot_tpu.recipes import serve_llm
-
-    cfg, params = _tiny_llm()
-    ready = threading.Event()
-    httpd = serve_llm.serve(cfg, params, 0, ready_event=ready,
-                            engine_slots=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    assert ready.wait(timeout=120)
-    base = f"http://127.0.0.1:{httpd.server_address[1]}"
-    try:
-        req = urllib.request.Request(base + "/drain", data=b"{}",
-                                     method="POST")
-        with urllib.request.urlopen(req, timeout=10) as resp:
-            payload = json.loads(resp.read())
-        assert payload["draining"] is True
-        assert payload["in_flight"] == 0
-        gen = urllib.request.Request(
-            base + "/generate",
-            data=json.dumps({"prompt": [1], "max_tokens": 2}).encode(),
-            headers={"Content-Type": "application/json"},
-            method="POST")
-        with pytest.raises(urllib.error.HTTPError) as exc:
-            urllib.request.urlopen(gen, timeout=10)
-        assert exc.value.code == 503
-    finally:
-        httpd.shutdown()
-
-
 @pytest.mark.usefixtures("tmp_state_dir")
 def test_recovery_finishes_interrupted_drain():
     """A controller crash mid-drain leaves a DRAINING row; the
@@ -1112,7 +1081,7 @@ def test_gang_sigkill_mid_stream_lb_resume_bit_identical():
     decode + paged int8 KV on — the LB's journal resumes the stream
     on a peer replica and the CLIENT's bytes are bit-identical to the
     uninterrupted run, greedy and seeded."""
-    flags = ["--kv-paged", "1", "--kv-quant", "1", "--spec-k", "3",
+    flags = ["--kv-quant", "1", "--spec-k", "3",
              "--spec-ngram", "2"]
     port_a, port_b = _free_port(), _free_port()
     # A (the victim): 2-host gang, decode slowed through the fault

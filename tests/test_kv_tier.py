@@ -121,7 +121,7 @@ def test_tier_readmit_bit_identical_cold_prefill(family):
     pg = [rng.randint(1, cfg.vocab_size - 1) for _ in range(17)]
     ps = [rng.randint(1, cfg.vocab_size - 1) for _ in range(19)]
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        prefix_cache_mb=8).start()
     try:
         cold_g = eng.submit(pg, max_tokens=4)
@@ -158,7 +158,7 @@ def test_tier_readmit_bit_identical_int8_kv():
     prompt = [int(t) for t in jax.random.randint(
         jax.random.key(2), (21,), 1, 128)]
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, kv_quant=True,
+                       prefill_chunk=8, kv_quant=True,
                        prefix_cache_mb=8).start()
     try:
         cold = eng.submit(prompt, max_tokens=5)
@@ -192,7 +192,7 @@ def test_tier_readmit_bit_identical_tp2():
         jax.random.key(4), (18,), 1, 128)]
     eng = DecodeEngine(cfg, sparams, slots=2, max_seq=64,
                        prefill_chunk=8, mesh=mesh, rules=rules,
-                       paged=True, prefix_cache_mb=8).start()
+                       prefix_cache_mb=8).start()
     try:
         cold = eng.submit(prompt, max_tokens=5)
         cold_toks = cold.result(timeout=600.0)
@@ -214,7 +214,7 @@ def test_tier_churn_accounting_identity():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, prefix_cache_mb=8)
+                       prefill_chunk=8, prefix_cache_mb=8)
     rng = random.Random(11)
     prompts = [[rng.randint(1, 127) for _ in range(rng.randint(17, 25))]
                for _ in range(4)]
@@ -257,7 +257,7 @@ def test_decode_never_blocks_on_wedged_spill_drain():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, prefix_cache_mb=64)
+                       prefill_chunk=8, prefix_cache_mb=64)
     eng._spill_q = queue_lib.Queue(maxsize=2)
     unfreeze = threading.Event()
     orig_put = eng._host_pool.put
@@ -292,7 +292,7 @@ def test_injected_spill_fault_degrades_to_drop():
     params = mdl.init(cfg, jax.random.key(0))
     prompt = list(range(1, 18))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        prefix_cache_mb=8).start()
     try:
         cold_toks = eng.submit(prompt,
@@ -326,21 +326,16 @@ def test_tier_budget_is_kv_geometry():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     geo = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, prefill_chunk=8, paged=True,
+        slots=2, max_seq=64, prefill_chunk=8,
         host_cache_mb=8.0)
     assert geo["host_mb"] == 8.0
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, prefix_cache_mb=8)
+                       prefill_chunk=8, prefix_cache_mb=8)
     assert eng.kv_config() == geo
     drifted = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, prefill_chunk=8, paged=True,
+        slots=2, max_seq=64, prefill_chunk=8,
         host_cache_mb=64.0)
     assert drifted != geo
-    # The dense path has no tier: the knob must not leak geometry.
-    dense = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, prefill_chunk=8, paged=False,
-        host_cache_mb=8.0)
-    assert "host_mb" not in dense
     eng.shutdown()
 
 
@@ -350,7 +345,7 @@ def test_tier_off_by_zero_budget():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, prefix_cache_mb=0)
+                       prefill_chunk=8, prefix_cache_mb=0)
     eng.submit(list(range(1, 18)), max_tokens=2)
     _drive(eng)
     assert eng.prefix_cache.evict_one() == "dropped"
@@ -373,7 +368,7 @@ def test_tier_metrics_exposed():
         labelnames=("outcome",))
     spilled_before = evs.labels(outcome="spilled").get()
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        prefix_cache_mb=8).start()
     try:
         prompt = list(range(20, 37))

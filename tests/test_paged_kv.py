@@ -3,20 +3,22 @@ prefix cache, zero-copy shared-prefix aliasing.
 
 The contract under test, strongest first:
 
-  * paged decode is BIT-IDENTICAL to the dense path — greedy and
+  * the engine's decode is BIT-IDENTICAL to the row-cache reference
+    (``models.<family>.decode`` / ``forward_with_cache``) — greedy and
     seeded sampling, all three families, across slot reuse and chunked
     prefill (the block-table gather feeds the same online-softmax tile
-    as the dense slice, so aligned tiles produce the same floats);
+    as the reference's slice, so aligned tiles produce the same
+    floats);
   * a prefix hit is a block-table entry write: zero splice copies on
     the hot path (the dense splice entry points no longer exist), and
     publish-on-free is a refcount transfer;
   * block refcount/aliasing lifecycle: shared blocks survive a
     mid-stream cancel, eviction never frees a pinned block, and 500
     seeded admit/cancel cycles leak nothing;
-  * admission is pool-capacity based — a request longer than the dense
-    per-slot row is admitted when its blocks fit — and under the SAME
-    KV budget the paged engine sustains strictly more concurrent
-    slots than dense for mixed-length traffic;
+  * admission is pool-capacity based — a request longer than max_seq
+    is admitted when its blocks fit — and a budget of two max_seq
+    rows sustains strictly more than two concurrent slots for
+    mixed-length traffic;
   * KV-cache donation is preserved through both paged jitted entry
     points (single-device and TP-sharded), and the same admission
     sequence reproduces the same block tables on every gang host.
@@ -126,28 +128,24 @@ def test_paged_trie_lru_refcount_and_interior_protection():
 
 # ================================================= bit-parity: engine
 def test_paged_engine_matches_dense_and_reference():
-    """5 ragged greedy requests through 2 slots: paged streams equal
-    the dense engine's AND the fixed-path decode token-for-token —
-    slot reuse, chunked prefill, and the block-table gather all
-    covered by one workload."""
+    """5 ragged greedy requests through 2 slots: the engine's streams
+    equal the row-cache decode token-for-token — slot reuse, chunked
+    prefill, and the block-table gather all covered by one
+    workload."""
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     rng = random.Random(0)
     specs = [([rng.randint(1, 127) for _ in range(rng.randint(1, 19))],
               rng.randint(1, 8)) for _ in range(5)]
 
-    def run(paged):
-        eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=paged).start()
-        try:
-            reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
-            return [r.result(timeout=300.0) for r in reqs]
-        finally:
-            eng.shutdown()
-
-    dense, paged = run(False), run(True)
-    assert dense == paged
-    for (p, mt), got in zip(specs, paged):
+    eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
+                       prefill_chunk=8).start()
+    try:
+        reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
+        served = [r.result(timeout=300.0) for r in reqs]
+    finally:
+        eng.shutdown()
+    for (p, mt), got in zip(specs, served):
         ref = mdl.decode(cfg, params, jnp.asarray([p], jnp.int32),
                          jnp.int32(len(p)), mt, len(p) + mt)
         assert got == [int(t) for t in ref[0]], (p, mt)
@@ -164,50 +162,48 @@ def test_paged_parity_other_families(family):
                for _ in range(rng.randint(2, 18))],
               rng.randint(1, 6)) for _ in range(3)]
 
-    def run(paged):
-        eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=paged).start()
-        try:
-            reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
-            return [r.result(timeout=300.0) for r in reqs]
-        finally:
-            eng.shutdown()
+    eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
+                       prefill_chunk=8).start()
+    try:
+        reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
+        served = [r.result(timeout=300.0) for r in reqs]
+    finally:
+        eng.shutdown()
+    for (p, mt), got in zip(specs, served):
+        ref = mdl.decode(cfg, params, jnp.asarray([p], jnp.int32),
+                         jnp.int32(len(p)), mt, len(p) + mt)
+        assert got == [int(t) for t in ref[0]], (p, mt)
 
-    assert run(False) == run(True)
 
-
-def test_paged_seeded_sampling_parity_and_zero_copy_hit():
-    """temperature > 0 streams are bit-identical dense vs paged, AND
-    the paged repeat of the same prompt — a zero-copy aliased hit —
-    still samples the identical stream (the aliased blocks hold the
-    exact rows prefill would recompute)."""
+def test_paged_seeded_sampling_parity_and_zero_copy_hit(
+        reference_stream):
+    """A temperature > 0 stream is the row-cache reference's, sampled
+    by the same per-position keys, AND the repeat of the same prompt —
+    a zero-copy aliased hit — still samples the identical stream (the
+    aliased blocks hold the exact rows prefill would recompute)."""
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     prompt = [int(t) for t in jax.random.randint(
         jax.random.key(3), (21,), 1, 128)]
 
-    def run(paged):
-        eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=paged).start()
-        try:
-            first = eng.submit(prompt, max_tokens=6, temperature=0.9,
-                               seed=17).result(timeout=300.0)
-            second_req = eng.submit(prompt, max_tokens=6,
-                                    temperature=0.9, seed=17)
-            second = second_req.result(timeout=300.0)
-            return first, second, second_req.cached_prompt_tokens
-        finally:
-            eng.shutdown()
-
-    d1, d2, _ = run(False)
-    p1, p2, cached = run(True)
-    assert d1 == d2 == p1 == p2
-    assert cached == 16                      # 2 aliased 8-token blocks
+    eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
+                       prefill_chunk=8).start()
+    try:
+        first = eng.submit(prompt, max_tokens=6, temperature=0.9,
+                           seed=17).result(timeout=300.0)
+        second_req = eng.submit(prompt, max_tokens=6,
+                                temperature=0.9, seed=17)
+        second = second_req.result(timeout=300.0)
+    finally:
+        eng.shutdown()
+    assert first == second == reference_stream(
+        mdl, cfg, params, prompt, 6, temperature=0.9, seed=17, chunk=8)
+    assert second_req.cached_prompt_tokens == 16   # 2 aliased blocks
 
 
 # ========================================== zero-copy on the hot path
 def test_paged_prefix_hit_zero_copies_on_hot_path():
-    """Under paging a prefix hit performs NO splice work: the dense
+    """A prefix hit performs NO splice work: the row
     splice entry points (_insert_chunk/_gather_chunk and the per-model
     gather/insert_cache_rows) are RETIRED — asserted gone, so nothing
     can quietly reintroduce a copy path — and the warm request must
@@ -221,7 +217,7 @@ def test_paged_prefix_hit_zero_copies_on_hot_path():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True).start()
+                       prefill_chunk=8).start()
     try:
         shared = [int(t) for t in jax.random.randint(
             jax.random.key(11), (17,), 1, 128)]
@@ -247,23 +243,18 @@ def test_paged_prefix_hit_zero_copies_on_hot_path():
 
 # ======================================== admission: pool, not row
 def test_paged_admission_pool_bound_not_row_length():
-    """The dense engine rejects len(prompt) + max_tokens > max_seq.
-    Under paging the bound is POOL capacity: the same request is
-    admitted when its blocks fit (and still decodes correctly), while
-    a request bigger than the whole pool gets the pool-bound error."""
+    """The admission bound is POOL capacity, not max_seq: a request
+    with len(prompt) + max_tokens > max_seq is admitted when its
+    blocks fit (and still decodes correctly), while a request bigger
+    than the whole pool gets the pool-bound error."""
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     prompt = [int(t) for t in jax.random.randint(
         jax.random.key(5), (70,), 1, 128)]
 
-    dense = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                         prefill_chunk=8)
-    with pytest.raises(EngineError, match="exceeds the engine cache"):
-        dense.submit(prompt, max_tokens=8)
-
     # 32 usable blocks x 8 tokens = 256 logical tokens per request.
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        kv_pool_blocks=33).start()
     try:
         got = eng.submit(prompt, max_tokens=8).result(timeout=300.0)
@@ -285,7 +276,7 @@ def test_paged_aliasing_cancel_mid_stream_blocks_survive():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     shared = [int(t) for t in jax.random.randint(
         jax.random.key(9), (17,), 1, 128)]
     # Cold leg publishes the two full prompt chunks on free.
@@ -331,7 +322,7 @@ def test_paged_release_idempotent_500_cycle_churn():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     rng = random.Random(7)
     for _ in range(500):
         prompt = [rng.randint(1, 127)
@@ -355,33 +346,33 @@ def test_paged_release_idempotent_500_cycle_churn():
 
 # ============================================== capacity per KV byte
 def test_paged_more_live_slots_than_dense_same_budget():
-    """Same KV budget (128 cache-token rows): dense fits 2 max_seq=64
-    rows; the paged pool runs 6 slots over the identical byte budget
-    and admission packs by ACTUAL length — a mixed short-request burst
-    sustains strictly more concurrent slots."""
+    """A KV budget of 128 cache-token rows holds 2 whole max_seq=64
+    rows (slots x max_seq, the budget's arithmetic); the pool runs 6
+    slots over those bytes and admission packs by ACTUAL length — a
+    mixed short-request burst sustains strictly more concurrent
+    slots than whole rows would."""
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     rng = random.Random(4)
     specs = [([rng.randint(1, 127) for _ in range(8)], 4)
              for _ in range(6)]
 
-    dense = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                         prefill_chunk=8)
-    for p, mt in specs:
-        dense.submit(p, max_tokens=mt)
-    _drive(dense)
+    budget_tokens, max_seq = 128, 64
+    whole_rows = budget_tokens // max_seq
+    # The default pool IS that arithmetic: slots x max_seq tokens.
+    assert decode_engine.resolve_kv_geometry(
+        slots=whole_rows, max_seq=max_seq, prefill_chunk=8
+    )["pool_blocks"] == budget_tokens // 8 + 1
 
-    paged = DecodeEngine(cfg, params, slots=6, max_seq=64,
-                         prefill_chunk=8, paged=True,
-                         kv_pool_blocks=128 // 8 + 1)
-    for p, mt in specs:
-        paged.submit(p, max_tokens=mt)
+    paged = DecodeEngine(cfg, params, slots=6, max_seq=max_seq,
+                         prefill_chunk=8,
+                         kv_pool_blocks=budget_tokens // 8 + 1)
+    reqs = [paged.submit(p, max_tokens=mt) for p, mt in specs]
     _drive(paged)
 
-    assert dense.peak_live_slots == 2
-    assert paged.peak_live_slots > dense.peak_live_slots
-    # Same tokens either way — capacity, not correctness, changed.
-    assert paged.peak_live_slots == 6
+    assert whole_rows == 2
+    assert paged.peak_live_slots == 6 > whole_rows
+    assert all(len(r.result(timeout=5.0)) == 4 for r in reqs)
 
 
 # ===================================================== donation + TP
@@ -540,11 +531,12 @@ def test_paged_entry_points_hold_one_pool_buffer(family, entry,
     assert not moved, moved
 
 
-def test_paged_tp_engine_bit_identical_to_dense_single():
-    """The TP paged engine (params by param_specs, POOL by the same
-    cache_specs sharding, tp=2 mesh) reproduces the single-process
-    DENSE engine bit-identically in f32 — the full parity chain
-    paged+sharded == dense+unsharded, greedy and seeded."""
+def test_paged_tp_engine_bit_identical_to_dense_single(
+        reference_stream):
+    """The TP engine (params by param_specs, POOL by the same
+    cache_specs sharding, tp=2 mesh) reproduces the unsharded
+    row-cache reference bit-identically in f32 — the full parity chain
+    paged+sharded == row cache+unsharded, greedy and seeded."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
                               dtype=jnp.float32)
     params = llama.init(cfg, jax.random.key(0))
@@ -568,11 +560,12 @@ def test_paged_tp_engine_bit_identical_to_dense_single():
             engine.shutdown()
         return out
 
-    ref = run(DecodeEngine(cfg, params, slots=2, max_seq=128).start())
     tp_paged = run(DecodeEngine(cfg, sparams, slots=2, max_seq=128,
-                                mesh=mesh, rules=rules,
-                                paged=True).start())
-    assert tp_paged == ref
+                                mesh=mesh, rules=rules).start())
+    assert tp_paged == [
+        reference_stream(llama, cfg, params, p, mt, temperature=t,
+                         seed=s, max_seq=128)
+        for p, mt, t, s in reqs]
 
 
 # ============================================ gang lockstep + config
@@ -589,7 +582,7 @@ def test_paged_same_admission_sequence_same_block_tables():
 
     def run():
         eng = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                           prefill_chunk=8, paged=True)
+                           prefill_chunk=8)
         reqs = [eng.submit(p, max_tokens=mt) for p, mt in seq]
         tables = []
         for _ in range(400):
@@ -618,14 +611,14 @@ def test_kv_geometry_single_derivation_no_drift():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     geo = decode_engine.resolve_kv_geometry(
-        slots=3, max_seq=64, prefill_chunk=8, paged=True)
+        slots=3, max_seq=64, prefill_chunk=8)
     assert eng.kv_config() == geo
     assert geo["pool_blocks"] == 3 * (64 // 8) + 1
     # Same knobs, different slot count -> different effective pool.
     other = decode_engine.resolve_kv_geometry(
-        slots=4, max_seq=64, prefill_chunk=8, paged=True)
+        slots=4, max_seq=64, prefill_chunk=8)
     assert other != geo
 
 
@@ -636,7 +629,7 @@ def test_gang_welcome_carries_kv_config_and_mismatch_kills_follower():
     lockstep."""
     topo = gang_replica.ReplicaTopology(hosts=2)
     kv = decode_engine.resolve_kv_geometry(
-        slots=4, max_seq=64, prefill_chunk=8, paged=True)
+        slots=4, max_seq=64, prefill_chunk=8)
     leader = gang_replica.GangLeader(topo, port=0, kv_config=kv)
     try:
         # Raw peek: welcome carries the kv block verbatim.
@@ -665,8 +658,7 @@ def test_gang_welcome_carries_kv_config_and_mismatch_kills_follower():
             rc_box.append(gang_replica.follower_serve(
                 _StubEngine, topo, f"127.0.0.1:{leader.port}", rank=1,
                 kv_config=decode_engine.resolve_kv_geometry(
-                    slots=8, max_seq=64, prefill_chunk=8,
-                    paged=True)))
+                    slots=8, max_seq=64, prefill_chunk=8)))
 
         t = threading.Thread(target=follower, daemon=True)
         t.start()
@@ -686,7 +678,7 @@ def test_paged_pool_metrics_exposed():
     zero_before = metrics_lib.REGISTRY.counter(
         "stpu_engine_prefix_zero_copy_hits_total").get()
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True).start()
+                       prefill_chunk=8).start()
     try:
         shared = list(range(1, 18))
         eng.submit(shared, max_tokens=2).result(timeout=300.0)

@@ -95,7 +95,7 @@ def test_quant_parity_single_device(family):
     params = mdl.init(cfg, jax.random.key(0))
     specs = _workload(cfg)
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        kv_quant=True, weight_quant=True).start()
     try:
         reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
@@ -130,7 +130,7 @@ def test_quant_parity_tp_sharded(family):
     sparams = gang_replica.shard_params(cfg, params, mesh, rules)
     eng = DecodeEngine(cfg, sparams, slots=2, max_seq=64,
                        prefill_chunk=8, mesh=mesh, rules=rules,
-                       paged=True, kv_quant=True,
+                       kv_quant=True,
                        weight_quant=True).start()
     try:
         reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
@@ -209,7 +209,7 @@ def test_gang_welcome_rejects_quant_geometry_drift():
     pool-size drift (the quant flags ride resolve_kv_geometry)."""
     topo = gang_replica.ReplicaTopology(hosts=2)
     kv = decode_engine.resolve_kv_geometry(
-        slots=4, max_seq=64, prefill_chunk=8, paged=True,
+        slots=4, max_seq=64, prefill_chunk=8,
         kv_quant=True, weight_quant=True)
     assert kv["kv_quant"] == 1 and kv["weight_quant"] == 1
     leader = gang_replica.GangLeader(topo, port=0, kv_config=kv)
@@ -239,7 +239,7 @@ def test_gang_welcome_rejects_quant_geometry_drift():
             rc_box.append(gang_replica.follower_serve(
                 _StubEngine, topo, f"127.0.0.1:{leader.port}", rank=1,
                 kv_config=decode_engine.resolve_kv_geometry(
-                    slots=4, max_seq=64, prefill_chunk=8, paged=True,
+                    slots=4, max_seq=64, prefill_chunk=8,
                     kv_quant=False, weight_quant=True)))
 
         t = threading.Thread(target=follower, daemon=True)
@@ -248,21 +248,6 @@ def test_gang_welcome_rejects_quant_geometry_drift():
         assert rc_box == [1]
     finally:
         leader.shutdown()
-
-
-def test_kv_quant_requires_paged():
-    """int8 KV lives in the paged block pool; asking for it on the
-    dense cache is a config error, at geometry-resolve time and at
-    engine construction."""
-    with pytest.raises(ValueError, match="kv_quant requires paged"):
-        decode_engine.resolve_kv_geometry(
-            slots=2, max_seq=64, prefill_chunk=8, paged=False,
-            kv_quant=True)
-    mdl, cfg = _tiny("llama")
-    params = mdl.init(cfg, jax.random.key(0))
-    with pytest.raises(ValueError, match="kv_quant requires paged"):
-        DecodeEngine(cfg, params, slots=2, max_seq=64,
-                     prefill_chunk=8, kv_quant=True)
 
 
 # ================================================ speculative decode
@@ -283,7 +268,7 @@ def test_spec_decode_parity_with_quantized_kv():
 
     def run(spec_k):
         eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=True, kv_quant=True,
+                           prefill_chunk=8, kv_quant=True,
                            weight_quant=True, spec_k=spec_k,
                            spec_ngram=2).start()
         try:
@@ -309,7 +294,7 @@ def test_quant_pool_500_cycle_churn_accounting_identity():
     mdl, cfg = _tiny("llama")
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, kv_quant=True)
+                       prefill_chunk=8, kv_quant=True)
     rng = random.Random(7)
     for _ in range(500):
         prompt = [rng.randint(1, 127)

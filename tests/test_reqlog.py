@@ -220,7 +220,8 @@ def test_reqlog_e2e_joined_record():
         assert eng["device_time_s"] > 0
         assert eng["ttft_s"] is not None
         assert eng["outcome"] == "ok" and eng["error"] is None
-        assert isinstance(eng["kv_paged"], bool)
+        assert isinstance(eng["kv_quant"], bool)
+        assert "kv_paged" not in eng      # one engine: nothing to say
         assert eng["restarts"] == 0
 
         # Non-streamed: the JSON response path has no SSE frame to
@@ -335,10 +336,8 @@ def test_jitted_steps_are_reqlog_free():
     import inspect
 
     from skypilot_tpu.serve import decode_engine
-    for fn in (decode_engine._engine_step, decode_engine._spec_step,
-               decode_engine._paged_step,
+    for fn in (decode_engine._paged_step,
                decode_engine._paged_spec_step,
-               decode_engine._prefill_chunk,
                decode_engine._paged_prefill_chunk,
                decode_engine._sample, decode_engine._sample_multi):
         assert "reqlog" not in inspect.getsource(fn), fn.__name__
@@ -400,7 +399,7 @@ def test_capture_derive_replay_reproduces_hit_rate(tmp_path):
     # deterministic (exactly one), so the hit-rate comparison isn't
     # noised by concurrent same-prefix admissions racing the trie.
     httpd = serve_llm.serve(cfg, params, 0, ready_event=ready,
-                            engine_slots=1, kv_paged=True)
+                            engine_slots=1)
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     assert ready.wait(timeout=300)
     policy = RoundRobinPolicy()

@@ -183,6 +183,8 @@ def test_cache_specs_tp_sharding_all_families():
     mesh = mesh_lib.make_mesh({"tp": 2}, devices=jax.devices()[:2])
     rules = mesh_lib.DEFAULT_RULES
     for name, mdl, cfg in _families():
+        # One spec per family: the one cache_shardings reads.
+        assert not hasattr(mdl, "paged_cache_specs")
         specs = mdl.cache_specs(cfg)
         assert set(specs) == {"k", "v"}
         shardings = gang_replica.cache_shardings(cfg, mesh, rules)
@@ -203,49 +205,12 @@ def test_cache_specs_tp_sharding_all_families():
         assert "tp" in str(psh["embed"].spec)
 
 
-def test_sharded_engine_donation_preserved():
-    """The KV cache stays donated through the SHARDED jitted decode and
-    prefill entry points: the input buffers are deleted after each
-    call, so the cache never silently doubles in HBM. Pinned per
-    family on the serving path."""
-    mesh = mesh_lib.make_mesh({"tp": 2}, devices=jax.devices()[:2])
-    rules = mesh_lib.DEFAULT_RULES
-    for name, mdl, cfg in _families():
-        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
-        params = gang_replica.shard_params(
-            cfg, mdl.init(cfg, jax.random.key(0)), mesh, rules)
-        cache = mdl.init_cache(cfg, 2, 128)
-        shardings = gang_replica.cache_shardings(cfg, mesh, rules)
-        # shardings also carries k_scale/v_scale for the int8 paged
-        # pool; the dense cache has no such leaves — filter like the
-        # engine does.
-        cache = jax.device_put(cache,
-                               {k: shardings[k] for k in cache})
-        old_k, old_v = cache["k"], cache["v"]
-        buf = jnp.zeros((64,), jnp.int32).at[:4].set(
-            jnp.asarray([1, 2, 3, 4]))
-        block = decode_engine._default_split_kv_block()
-        toks, cache = decode_engine._prefill_chunk(
-            cfg, params, cache, buf, jnp.int32(0), jnp.int32(0),
-            jnp.int32(4), block, jnp.zeros((2,), jnp.int32),
-            jnp.int32(0), jnp.uint32(0), jnp.float32(0.0))
-        assert old_k.is_deleted() and old_v.is_deleted(), \
-            f"{name}: prefill chunk dropped the cache donation"
-        old_k, old_v = cache["k"], cache["v"]
-        _nxt, cache = decode_engine._engine_step(
-            cfg, params, cache, toks,
-            jnp.asarray([4, 0], jnp.int32),
-            jnp.zeros((2,), jnp.float32),
-            jnp.zeros((2,), jnp.uint32), block)
-        assert old_k.is_deleted() and old_v.is_deleted(), \
-            f"{name}: decode step dropped the cache donation"
-
-
-def test_tp_engine_bit_identical_to_single_process():
-    """The tensor-parallel engine (params by param_specs, cache by
-    cache_specs, tp=2 mesh) reproduces the single-process engine's
-    token streams BIT-IDENTICALLY — greedy and seeded sampling — in
-    f32 (bf16 matches only to bf16 rounding, like any resharding)."""
+def test_tp_engine_bit_identical_to_single_process(reference_stream):
+    """The tensor-parallel engine (params by param_specs, pool by
+    cache_specs, tp=2 mesh) reproduces the unsharded row-cache
+    reference's token streams BIT-IDENTICALLY — greedy and seeded
+    sampling — in f32 (bf16 matches only to bf16 rounding, like any
+    resharding)."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
                               dtype=jnp.float32)
     params = llama.init(cfg, jax.random.key(0))
@@ -270,12 +235,12 @@ def test_tp_engine_bit_identical_to_single_process():
             engine.shutdown()
         return out
 
-    ref = run(decode_engine.DecodeEngine(
-        cfg, params, slots=2, max_seq=128).start())
     tp = run(decode_engine.DecodeEngine(
         cfg, sparams, slots=2, max_seq=128, mesh=mesh,
         rules=rules).start())
-    assert tp == ref
+    assert tp == [reference_stream(llama, cfg, params, p, mt,
+                                   temperature=t, seed=s, max_seq=128)
+                  for p, mt, t, s in reqs]
 
 
 # ==================================================== 2-process gang e2e
@@ -452,7 +417,7 @@ def test_topology_info_gauge_in_replica_metrics():
     params = llama.init(cfg, jax.random.key(0))
     ready = threading.Event()
     httpd = serve_llm.serve(
-        cfg, params, 0, ready_event=ready, engine_slots=0,
+        cfg, params, 0, ready_event=ready, engine_slots=1,
         topology=gang_replica.ReplicaTopology(hosts=2,
                                               ici_axes={"tp": 4}))
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
@@ -465,6 +430,7 @@ def test_topology_info_gauge_in_replica_metrics():
                 in text), text[-2000:]
     finally:
         httpd.shutdown()
+        httpd.engine.shutdown()
     del metrics
 
 

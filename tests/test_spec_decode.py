@@ -3,15 +3,14 @@ n-gram drafts with multi-token paged verification.
 
 The contract under test, strongest first:
 
-  * speculative output is BIT-IDENTICAL to non-speculative decode —
-    greedy AND seeded sampling, all three families, dense and paged
-    caches (targets are re-sampled with the engine's own
+  * speculative output is BIT-IDENTICAL to non-speculative decode and
+    to the row-cache reference (``models.<family>.decode``) — greedy
+    AND seeded sampling, all three families (targets are re-sampled with the engine's own
     fold_in(seed, pos) keys, so rejection sampling against the
     deterministic n-gram draft degenerates to exact-match acceptance
     and the stream can never change, only its wall clock);
-  * rejected-suffix rollback is safe: dense rows past the accepted
-    frontier stay masked, the paged path truncates the grown
-    block-table tail back into the pool (reservation returned), and a
+  * rejected-suffix rollback is safe: the grown block-table tail is
+    truncated back into the pool (reservation returned), and a
     verify window clamped near a request's token budget never writes
     where it could corrupt valid rows;
   * the TP-sharded engine drafts/accepts identically to the
@@ -70,19 +69,19 @@ def _mixed_specs(cfg, seed=0, n=3):
 
 # =========================================== bit-identity: all families
 @pytest.mark.parametrize("family", ["llama", "mixtral", "gemma"])
-def test_spec_greedy_bit_identical_dense_and_paged(family):
+def test_spec_greedy_bit_identical_dense_and_paged(family,
+                                                   reference_stream):
     """Greedy speculative streams equal the non-speculative engine's
-    token-for-token (itself pinned against the fixed-path decode by
-    test_decode_engine/test_paged_kv), dense and paged, with real
-    drafting exercised (the repetitive prompt forces verify steps; the
-    ragged ones force rejections)."""
+    AND the row-cache reference's token-for-token, with real drafting
+    exercised (the repetitive prompt forces verify steps; the ragged
+    ones force rejections)."""
     mdl, cfg = _tiny(family)
     params = mdl.init(cfg, jax.random.key(0))
     specs = _mixed_specs(cfg)
 
-    def run(paged, spec_k):
+    def run(spec_k):
         eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=paged,
+                           prefill_chunk=8,
                            spec_k=spec_k, spec_ngram=2).start()
         try:
             reqs = [eng.submit(p, max_tokens=mt) for p, mt in specs]
@@ -91,17 +90,19 @@ def test_spec_greedy_bit_identical_dense_and_paged(family):
         finally:
             eng.shutdown()
 
-    base, zero = run(False, 0)
+    base, zero = run(0)
     assert zero == 0
-    dense, drafted_dense = run(False, 4)
-    paged, drafted_paged = run(True, 4)
-    assert dense == base
-    assert paged == base
-    assert drafted_dense > 0 and drafted_paged > 0
+    spec, drafted = run(4)
+    assert spec == base
+    assert drafted > 0
+    for (p, mt), got in zip(specs, spec):
+        assert got == reference_stream(mdl, cfg, params, p, mt,
+                                       chunk=8), (p, mt)
 
 
-def test_spec_seeded_sampling_parity():
-    """temperature > 0 streams are bit-identical with speculation on:
+def test_spec_seeded_sampling_parity(reference_stream):
+    """temperature > 0 streams are bit-identical with speculation on,
+    and are the row-cache reference's under the same keys:
     the verify targets are sampled with the SAME fold_in(seed, pos)
     keys the 1-token step folds, so acceptance is exact-match and the
     distribution is preserved trivially — the output IS the
@@ -115,9 +116,9 @@ def test_spec_seeded_sampling_parity():
              ([9, 9, 9, 9, 9, 9, 9, 9], 14, 0.3, 4),
              ([1, 2, 3, 4, 5], 8, 1.1, 123)]
 
-    def run(paged, spec_k):
+    def run(spec_k):
         eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                           prefill_chunk=8, paged=paged,
+                           prefill_chunk=8,
                            spec_k=spec_k, spec_ngram=2).start()
         try:
             reqs = [eng.submit(p, max_tokens=mt, temperature=t,
@@ -127,11 +128,13 @@ def test_spec_seeded_sampling_parity():
         finally:
             eng.shutdown()
 
-    base, _ = run(False, 0)
-    dense, d1 = run(False, 4)
-    paged, d2 = run(True, 4)
-    assert dense == base and paged == base
-    assert d1 > 0 and d2 > 0
+    base, _ = run(0)
+    spec, drafted = run(4)
+    assert spec == base
+    assert drafted > 0
+    assert spec == [reference_stream(mdl, cfg, params, p, mt,
+                                     temperature=t, seed=s, chunk=8)
+                    for p, mt, t, s in specs]
 
 
 def test_spec_window_clamped_near_token_budget_and_row_end():
@@ -160,10 +163,11 @@ def test_spec_window_clamped_near_token_budget_and_row_end():
 
 
 # ==================================================== TP + determinism
-def test_spec_tp_paged_engine_bit_identical_to_dense_single():
-    """The TP-sharded speculative paged engine reproduces the
-    single-process non-speculative dense engine bit-identically in
-    f32 — speculation composes with the full sharded serving path."""
+def test_spec_tp_paged_engine_bit_identical_to_dense_single(
+        reference_stream):
+    """The TP-sharded speculative engine reproduces the unsharded
+    row-cache reference bit-identically in f32 — speculation composes
+    with the full sharded serving path."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab_size=128),
                               dtype=jnp.float32)
     params = llama.init(cfg, jax.random.key(0))
@@ -188,11 +192,12 @@ def test_spec_tp_paged_engine_bit_identical_to_dense_single():
             engine.shutdown()
         return out, drafted
 
-    ref, _ = run(DecodeEngine(cfg, params, slots=2,
-                              max_seq=128).start())
+    ref = [reference_stream(llama, cfg, params, p, mt, temperature=t,
+                            seed=s, max_seq=128)
+           for p, mt, t, s in reqs]
     tp_spec, drafted = run(DecodeEngine(
         cfg, sparams, slots=2, max_seq=128, mesh=mesh, rules=rules,
-        paged=True, spec_k=4, spec_ngram=2).start())
+        spec_k=4, spec_ngram=2).start())
     assert tp_spec == ref
     assert drafted > 0
 
@@ -209,7 +214,7 @@ def test_spec_same_admission_sequence_same_tables_and_tokens():
 
     def run():
         eng = DecodeEngine(cfg, params, slots=3, max_seq=64,
-                           prefill_chunk=8, paged=True, spec_k=4,
+                           prefill_chunk=8, spec_k=4,
                            spec_ngram=2)
         reqs = [eng.submit(p, max_tokens=mt) for p, mt in seq]
         tables = []
@@ -307,7 +312,7 @@ def test_spec_cancel_mid_verify_releases_pool_refs():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, spec_k=4,
+                       prefill_chunk=8, spec_k=4,
                        spec_ngram=2)
     shared = [5, 6, 7] * 6                    # 18 tokens: 2 full chunks
     first = eng.submit(shared, max_tokens=1)
@@ -345,7 +350,7 @@ def test_spec_churn_500_cycles_accounting_clean():
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, spec_k=4,
+                       prefill_chunk=8, spec_k=4,
                        spec_ngram=2)
     rng = random.Random(7)
     for _ in range(500):
@@ -383,7 +388,7 @@ def test_spec_injected_verify_fault_rides_restart_ladder():
 
     def factory():
         return DecodeEngine(cfg, params, slots=1, max_seq=64,
-                            prefill_chunk=8, paged=True, spec_k=4,
+                            prefill_chunk=8, spec_k=4,
                             spec_ngram=2)
 
     sup = decode_engine.EngineSupervisor(
@@ -473,41 +478,38 @@ def test_spec_stepstats_and_perf_snapshot_carry_acceptance():
 
 
 def test_spec_env_knobs_registered_and_in_handshake_geometry():
-    """STPU_SPEC_* are registered (stpu-env stays green), the paged
-    default is flipped to 1, and the spec knobs ride the effective
+    """STPU_SPEC_* are registered (stpu-env stays green), and the
+    spec knobs ride the effective
     kv-handshake geometry so a gang member drafting differently fails
     the welcome comparison instead of silently diverging tokens."""
     from skypilot_tpu.utils import env_contract
     assert env_contract.get("STPU_SPEC_K").default == "0"
     assert env_contract.get("STPU_SPEC_NGRAM").default == "3"
     assert env_contract.get("STPU_SPEC_MIN_ACCEPT").default == "0.2"
-    assert env_contract.get("STPU_KV_PAGED").default == "1"
 
     geo = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, prefill_chunk=8, paged=True, spec_k=4,
+        slots=2, max_seq=64, prefill_chunk=8, spec_k=4,
         spec_ngram=2, spec_min_accept=0.25)
     assert geo["spec_k"] == 4 and geo["spec_ngram"] == 2
     assert geo["spec_min_accept"] == 0.25
     other = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, prefill_chunk=8, paged=True, spec_k=0)
+        slots=2, max_seq=64, prefill_chunk=8, spec_k=0)
     assert other != geo                       # mismatch is fatal at join
 
     mdl, cfg = _tiny()
     params = mdl.init(cfg, jax.random.key(0))
     eng = DecodeEngine(cfg, params, slots=2, max_seq=64,
-                       prefill_chunk=8, paged=True, spec_k=4,
+                       prefill_chunk=8, spec_k=4,
                        spec_ngram=2, spec_min_accept=0.25)
     assert eng.kv_config() == geo             # single derivation
 
 
 def test_serve_llm_default_is_paged_with_spec_selectable():
-    """The serving default is the paged pool (STPU_KV_PAGED flipped to
-    1); spec stays opt-in, and a spec-armed replica serves the same
+    """Spec stays opt-in, and a spec-armed replica serves the same
     tokens over HTTP as the models' fixed path."""
     import json
     import urllib.request
     from skypilot_tpu.recipes import serve_llm
-    assert serve_llm.ENGINE_KV_PAGED is True
     assert serve_llm.ENGINE_SPEC_K == 0
 
     mdl, cfg = _tiny()
@@ -518,7 +520,6 @@ def test_serve_llm_default_is_paged_with_spec_selectable():
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     try:
         assert ready.wait(timeout=300)
-        assert httpd.engine.engine._paged    # serving default
         assert httpd.engine.engine._spec_k == 3
         port = httpd.server_address[1]
         prompt = [5, 6, 7] * 6
@@ -529,7 +530,7 @@ def test_serve_llm_default_is_paged_with_spec_selectable():
             headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=120) as resp:
             toks = json.loads(resp.read())["tokens"]
-        # The reference is a dense, non-speculative engine with the
+        # The reference is a non-speculative engine with the
         # server's own geometry (default prefill chunk, the recipe's
         # max_seq): in bf16 a near-tied argmax depends on the prefill
         # tiling, and tiling is not what this test is about.

@@ -285,20 +285,20 @@ def test_donation_rule_fresh_buffer_per_iteration(tmp_path):
 
 
 def test_donation_rule_covers_paged_entry_points():
-    """The analyzer SEES the paged block-table entry points: both
-    _paged_prefill_chunk and _paged_step register as donators with the
-    pool (positional index 2) donated — so a future use-after-donate
-    of the paged pool fails the gate exactly like the dense cache."""
+    """The analyzer SEES the engine's entry points: the three serving
+    programs and the host-tier restore are ALL the donators of
+    decode_engine.py, each with the pool donated — so a future
+    use-after-donate of the pool fails the gate."""
     from skypilot_tpu.analysis import rules_donation
     src = REPO / "skypilot_tpu" / "serve" / "decode_engine.py"
     ctx = analysis.core.FileContext(src, "serve/decode_engine.py")
     donators = {d.name: d
                 for d in rules_donation._collect_donators(ctx)
                 if d.name}
-    for name in ("_paged_prefill_chunk", "_paged_step",
-                 "_prefill_chunk", "_engine_step", "_paged_spec_step"):
-        assert name in donators, f"{name} not seen as a donator"
-        assert "cache" in donators[name].donated_params(), name
+    assert set(donators) == {"_paged_prefill_chunk", "_paged_step",
+                             "_paged_spec_step", "_host_restore_block"}
+    for name, donator in donators.items():
+        assert "cache" in donator.donated_params(), name
 
 
 def test_donation_rule_paged_block_table_fixture(tmp_path):
@@ -803,6 +803,65 @@ def test_env_table_doc_in_sync():
     assert embedded == env_contract.render_markdown_table(), (
         "docs/static-analysis.md env table is stale — regenerate with "
         "`stpu check --env-table`")
+
+
+def test_deleted_serving_switch_is_in_no_registry_and_no_source():
+    """STPU_KV_PAGED selected the row-cache engine, which is gone: the
+    variable is registered nowhere, the generated table (held equal to
+    the registry above) does not carry it, and nothing under
+    skypilot_tpu/ reads or documents it."""
+    assert "STPU_KV_PAGED" not in env_contract.REGISTRY
+    with pytest.raises(KeyError):
+        env_contract.get("STPU_KV_PAGED")
+    assert "STPU_KV_PAGED" not in env_contract.render_markdown_table()
+    doc = (REPO / "docs" / "static-analysis.md").read_text()
+    assert "KV_PAGED" not in doc
+    for path in (REPO / "skypilot_tpu").rglob("*.py"):
+        assert "KV_PAGED" not in path.read_text(), path
+
+
+# ============================================ serving-stack layering
+def _imported_modules(path):
+    """Every ``skypilot_tpu.*`` module a file imports, at module level
+    or inside a function (read with ``ast``; ``from a.b import c`` is
+    counted as both ``a.b`` and ``a.b.c``)."""
+    import ast
+    found = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            found.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:      # relative: resolve against the package
+                pkg = path.relative_to(REPO).with_suffix("").parts
+                base = ".".join(pkg[:len(pkg) - node.level]
+                                + ((base,) if base else ()))
+            found.add(base)
+            found.update(f"{base}.{a.name}" for a in node.names)
+    return {m for m in found if m.startswith("skypilot_tpu")}
+
+
+@pytest.mark.parametrize("sources,forbidden", [
+    ("models", ("serve", "recipes", "benchmark", "tune")),
+    ("serve/kv_pool.py", ("serve.decode_engine",)),
+    ("serve/decode_engine.py", ("recipes",)),
+    ("serve/decode_engine.py", ("benchmark",)),
+], ids=["models", "kv_pool", "engine-recipes", "engine-benchmark"])
+def test_serving_stack_imports_point_downwards(sources, forbidden):
+    """A request goes handler -> DecodeEngine -> kv_pool -> models;
+    imports go the same way and never back up (function-level imports
+    included). decode_engine <-> tune stays a cycle, a named debt
+    (ROADMAP Design 7)."""
+    root = REPO / "skypilot_tpu" / sources
+    files = sorted(root.rglob("*.py")) if root.is_dir() else [root]
+    assert files
+    for path in files:
+        for module in _imported_modules(path):
+            for layer in forbidden:
+                banned = f"skypilot_tpu.{layer}"
+                assert module != banned and \
+                    not module.startswith(banned + "."), \
+                    f"{path.relative_to(REPO)} imports {module}"
 
 
 def test_cli_env_table_matches_registry():

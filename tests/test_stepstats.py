@@ -157,8 +157,9 @@ def test_jitted_steps_are_stepstats_free():
     import inspect
 
     from skypilot_tpu.serve import decode_engine
-    for fn in (decode_engine._engine_step, decode_engine._paged_step,
-               decode_engine._prefill_chunk):
+    for fn in (decode_engine._paged_step,
+               decode_engine._paged_prefill_chunk,
+               decode_engine._paged_spec_step):
         assert "stepstats" not in inspect.getsource(fn)
 
 
@@ -465,6 +466,9 @@ def test_profile_endpoint_capture(armed, monkeypatch):
 
     class _FakeProfiler:
         ProfileOptions = jax.profiler.ProfileOptions
+        # The engine thread names its phases on whatever profiler
+        # there is.
+        TraceAnnotation = jax.profiler.TraceAnnotation
 
         @staticmethod
         def start_trace(path, profiler_options):
@@ -485,9 +489,8 @@ def test_profile_endpoint_capture(armed, monkeypatch):
 
     cfg, params = _tiny_llm()
     port = free_port()
-    # engine_slots=0: the legacy path serves /profile too, and the
-    # test stays light (no engine warmup compile).
-    httpd = serve_llm.serve(cfg, params, port, engine_slots=0)
+    # One slot: /profile does not wait for the engine's warm-up.
+    httpd = serve_llm.serve(cfg, params, port, engine_slots=1)
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     try:
         url = f"http://127.0.0.1:{port}/profile?seconds=0.05"
@@ -513,6 +516,7 @@ def test_profile_endpoint_capture(armed, monkeypatch):
         ei.value.read()
     finally:
         httpd.shutdown()
+        httpd.engine.shutdown()
 
 
 # -------------------------------------------------------------- CLI bits

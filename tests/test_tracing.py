@@ -452,7 +452,7 @@ def test_engine_step_is_tracing_free():
     from skypilot_tpu.serve import decode_engine
     assert "tracing" not in inspect.getsource(
         decode_engine.DecodeEngine._decode_step)
-    assert "tracing" not in inspect.getsource(decode_engine._engine_step)
+    assert "tracing" not in inspect.getsource(decode_engine._paged_step)
 
 
 @pytest.mark.slow

@@ -13,8 +13,9 @@ Four layers, cheapest first:
   manifest die at join);
 * engine-output parity AT tuned constants — the same
   tune.parity.check_parity gate `stpu tune` runs on every winner
-  before persisting, here parametrized over families and paged/dense
-  at a deliberately non-default tile/chunk.
+  before persisting, here parametrized over families, with the window
+  set by a tuned ``block`` and by ``window_blocks``, at a deliberately
+  non-default tile/chunk.
 """
 import json
 import socket
@@ -227,26 +228,26 @@ def test_manifest_fills_only_unset_knobs(manifest_env):
     tag = tune_manifest.entry_for(family="llama", slots=2)[1]
 
     geo = decode_engine.resolve_kv_geometry(slots=2, max_seq=64,
-                                            paged=True, family="llama")
+                                            family="llama")
     assert (geo["block"], geo["chunk"], geo["window"],
             geo["spec_k"]) == (32, 16, 32, 2)
     assert geo["manifest"] == tag
 
     # Explicit knobs win over the manifest; untouched ones still fill.
     geo = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, paged=True, prefill_chunk=8,
+        slots=2, max_seq=64, prefill_chunk=8,
         family="llama")
     assert geo["chunk"] == 8
     assert geo["block"] == 32
-    # kv_block_tokens is the paged alias for chunk — also explicit.
+    # kv_block_tokens is the pool's name for chunk — also explicit.
     geo = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, paged=True, kv_block_tokens=8,
+        slots=2, max_seq=64, kv_block_tokens=8,
         family="llama")
     assert geo["chunk"] == 8
 
     # use_manifest=False (bench legs, parity reference engines).
     geo = decode_engine.resolve_kv_geometry(slots=2, max_seq=64,
-                                            paged=True, family="llama",
+                                            family="llama",
                                             use_manifest=False)
     assert geo["manifest"] == "default"
     assert geo["block"] == 64 and geo["chunk"] == 64
@@ -264,12 +265,12 @@ def test_engine_startup_loads_manifest_constants(manifest_env):
         {"llama|b1-4|tp1|bf16": {"block": 32, "chunk": 16,
                                  "parity": "pass"}}, PROV)
     mdl, cfg, params = _tiny()
-    eng = DecodeEngine(cfg, params, slots=2, max_seq=64, paged=True)
+    eng = DecodeEngine(cfg, params, slots=2, max_seq=64)
     kv = eng.kv_config()
     assert kv["block"] == 32 and kv["chunk"] == 16
     assert kv["manifest"] != "default"
     # Same knobs, manifest off: the handshake dicts must differ.
-    ref = DecodeEngine(cfg, params, slots=2, max_seq=64, paged=True,
+    ref = DecodeEngine(cfg, params, slots=2, max_seq=64,
                        use_manifest=False)
     assert ref.kv_config() != kv
 
@@ -283,7 +284,7 @@ def test_follower_with_drifted_manifest_dies_at_join(manifest_env):
                                  "parity": "pass"}}, PROV)
     topo = gang_replica.ReplicaTopology(hosts=2)
     leader_kv = decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=64, paged=True, family="llama")
+        slots=2, max_seq=64, family="llama")
     assert leader_kv["manifest"] != "default"
     leader = gang_replica.GangLeader(topo, port=0, kv_config=leader_kv)
     try:
@@ -308,7 +309,7 @@ def test_follower_with_drifted_manifest_dies_at_join(manifest_env):
             rc_box.append(gang_replica.follower_serve(
                 _StubEngine, topo, f"127.0.0.1:{leader.port}", rank=1,
                 kv_config=decode_engine.resolve_kv_geometry(
-                    slots=2, max_seq=64, paged=True, family="llama",
+                    slots=2, max_seq=64, family="llama",
                     use_manifest=False)))
 
         t = threading.Thread(target=follower, daemon=True)
@@ -354,14 +355,16 @@ _FAMILIES = ["llama",
 
 
 @pytest.mark.parametrize("family", _FAMILIES)
-def test_parity_at_tuned_constants_dense(family):
-    check_parity(family, block=32, chunk=16, paged=False,
+def test_parity_at_tuned_constants_block(family):
+    """A manifest's ``block`` sets the attention window where no
+    ``window_blocks`` does (two 16-token blocks a tile)."""
+    check_parity(family, block=32, chunk=16,
                  n_requests=2, max_tokens=4)
 
 
 @pytest.mark.parametrize("family", _FAMILIES)
 def test_parity_at_tuned_constants_paged(family):
-    check_parity(family, chunk=16, window_blocks=2, paged=True,
+    check_parity(family, chunk=16, window_blocks=2,
                  n_requests=2, max_tokens=4)
 
 

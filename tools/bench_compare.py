@@ -35,7 +35,6 @@ DEFAULT_METRICS = (
     "detail.long_context.mfu_pct",
     "detail.eight_b_shape.tokens_per_sec_per_chip",
     "detail.serving.*_decode_tok_s_b*",
-    "detail.serving.*_engine_ragged_tok_s",
     "detail.serving.*_engine_paged_tok_s",
     "detail.serving.*_engine_q8_tok_s",
     "detail.serving.*_engine_spec_tok_s",
@@ -45,7 +44,7 @@ DEFAULT_METRICS = (
     # gating the block count here keeps the ratio from eroding
     # round-over-round (e.g. scale-array bloat shrinking the pool).
     "detail.serving.*_kv_pool_capacity_blocks",
-    # Tuned-constants ragged leg (`stpu tune` manifest applied): the
+    # Tuned-constants engine leg (`stpu tune` manifest applied): the
     # autotuner only persists parity-gated winners measured >= the
     # default through this same leg, so a drop here means the manifest
     # went stale for the device this round ran on.

@@ -23,18 +23,17 @@ def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--family", choices=("mixtral", "llama", "gemma"),
                    default="mixtral")
-    p.add_argument("--mode", choices=("fixed", "engine", "paged", "q8",
+    p.add_argument("--mode", choices=("fixed", "paged", "q8",
                                       "spec", "prefix", "ckpt",
                                       "loadgen", "chaos", "tp",
                                       "tuned", "tier"),
                    default="fixed",
                    help="fixed: bucketed batch decode (r01-r05 "
-                        "comparable); engine: continuous-batching "
-                        "decode engine under ragged arrivals; paged: "
-                        "the engine on the paged KV block pool (one "
-                        "device pool + block tables, half the dense "
-                        "HBM budget) under a mixed-length mix — "
-                        "tok/s + pool utilization; q8: the paged "
+                        "comparable); paged: the continuous-batching "
+                        "decode engine (one device KV block pool + "
+                        "block tables, sized to half of slots x "
+                        "max_seq) under a mixed-length mix — "
+                        "tok/s + pool utilization; q8: the "
                         "engine with int8 KV blocks + int8 weights — "
                         "quantized tok/s and the block-capacity "
                         "ratio vs bf16 at the same HBM budget; spec: "
@@ -59,13 +58,13 @@ def main() -> None:
                         "tp: the tensor-parallel sharded engine "
                         "(serve/gang_replica.py) over a --tp-wide "
                         "mesh — needs that many visible devices; "
-                        "tuned: the ragged "
+                        "tuned: the paged "
                         "engine leg at the `stpu tune` manifest's "
                         "constants next to the hand-pinned defaults "
                         "— the tuned >= default acceptance leg "
                         "(STPU_TUNE_MANIFEST selects the manifest; "
                         "with no entry a quick in-process "
-                        "ragged-only sweep supplies the constants); "
+                        "paged-only sweep supplies the constants); "
                         "tier: the host-RAM KV spill tier under a "
                         "prefix working set ~2x the HBM pool — "
                         "warm re-hit TTFT vs cold prefill TTFT, "
@@ -78,7 +77,7 @@ def main() -> None:
     p.add_argument("--slots", type=int, default=8,
                    help="engine mode: concurrent decode slots")
     p.add_argument("--requests", type=int, default=32,
-                   help="engine mode: ragged requests submitted")
+                   help="engine modes: requests submitted")
     p.add_argument("--shared-prefix", type=int, default=256,
                    help="prefix mode: shared system-prompt tokens")
     p.add_argument("--spec-k", type=int, default=4,
@@ -113,11 +112,7 @@ def main() -> None:
     compile_cache.enable()
 
     from skypilot_tpu.benchmark import decode_bench
-    if args.mode == "engine":
-        result = decode_bench.measure_engine_ragged(
-            args.family, slots=args.slots, n_requests=args.requests,
-            **shape_kw)
-    elif args.mode == "paged":
+    if args.mode == "paged":
         result = decode_bench.measure_engine_paged(
             args.family, slots=args.slots, n_requests=args.requests,
             **shape_kw)
@@ -159,31 +154,34 @@ def main() -> None:
         entry, tag = tune_manifest.entry_for(family=args.family,
                                              slots=args.slots)
         if entry is None:
-            # No manifest for this config: a quick ragged-only sweep
+            # No manifest for this config: a quick paged-only sweep
             # supplies (and parity-gates) the constants in-process —
             # the leg then still measures tuned vs default the same
             # way, just without a persisted provenance tag.
             from skypilot_tpu.tune import sweep as tune_sweep
             win = tune_sweep.sweep_one(
-                args.family, "ragged", quick=True, slots=args.slots,
+                args.family, "paged", quick=True, slots=args.slots,
                 shape_kw=shape_kw, log=lambda m: print(m,
                                                        file=sys.stderr))
             entry, tag = (win or {}).get("knobs", {}), "adhoc"
         engine_kw = {k: v for k, v in
                      (("block", entry.get("block", 0)),
-                      ("prefill_chunk", entry.get("chunk", 0))) if v}
-        tuned = decode_bench.measure_engine_ragged(
+                      ("window_blocks",
+                       entry.get("window_blocks", 0))) if v}
+        tuned = decode_bench.measure_engine_paged(
             args.family, slots=args.slots, n_requests=args.requests,
-            engine_kw=engine_kw, **shape_kw)
-        default = decode_bench.measure_engine_ragged(
+            block_tokens=entry.get("chunk", 0), engine_kw=engine_kw,
+            **shape_kw)
+        default = decode_bench.measure_engine_paged(
             args.family, slots=args.slots, n_requests=args.requests,
             **shape_kw)
         result = dict(tuned)
         result["engine_tuned_tok_s"] = result.pop(
-            "engine_ragged_tok_s")
+            "engine_paged_tok_s")
         result["engine_tuned_default_tok_s"] = \
-            default["engine_ragged_tok_s"]
-        result["tuned_constants"] = engine_kw
+            default["engine_paged_tok_s"]
+        result["tuned_constants"] = dict(
+            engine_kw, chunk=entry.get("chunk", 0))
         result["tune_manifest"] = tag
     else:
         result = decode_bench.measure_decode(
