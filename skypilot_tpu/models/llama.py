@@ -243,7 +243,8 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def lora_dense(y: jax.Array, lp: Params, name: str) -> jax.Array:
+def lora_dense(y: jax.Array, lp: Params, name: str,
+               out_dtype=None) -> jax.Array:
     """y @ W, plus the low-rank LoRA path y @ A @ B when the layer params
     carry `<name>_lora_a`/`<name>_lora_b` adapters (recipes/llama_lora.py
     injects them; base checkpoints don't have the keys and skip it).
@@ -251,31 +252,92 @@ def lora_dense(y: jax.Array, lp: Params, name: str) -> jax.Array:
     When the layer carries a `<name>_scale` (quantize_params tree) the
     weight is int8: codes upcast to the activation dtype in-register,
     the matmul runs as usual, and the per-output-channel f32 scale
-    multiplies the result — one extra VPU pass, half the HBM reads."""
+    multiplies the result — one extra VPU pass, half the HBM reads.
+
+    The result is in the activations' dtype, or in ``out_dtype``: f32
+    hands over the MXU's accumulator unrounded (the operands stay what
+    they are)."""
     w = lp[name]
     scale = lp.get(name + "_scale")
+    out_dtype = out_dtype or y.dtype
     if scale is None:
-        out = y @ w
+        out = jnp.matmul(y, w, preferred_element_type=out_dtype)
     else:
         out = ((y @ w.astype(y.dtype)).astype(jnp.float32) *
-               scale).astype(y.dtype)
+               scale).astype(out_dtype)
     a = lp.get(name + "_lora_a")
     if a is not None:
         out = out + (y @ a) @ lp[name + "_lora_b"]
     return out
 
 
-def qkv_proj(cfg, y: jax.Array, lp: Params, positions: jax.Array):
-    """Projection + RoPE shared by the training forward and the KV-cache
-    decode path (they must never diverge). Returns (q, k, v); v unroped.
-    """
+def _qkv(cfg, y: jax.Array, lp: Params, positions: jax.Array, dense):
+    """q, k and v of one layer: ``dense(y, lp, name)`` for the three
+    products, heads, RoPE on q and k. One body for every forward; the
+    two entries below differ in ``dense`` alone."""
     b, t = y.shape[0], y.shape[1]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = lora_dense(y, lp, "wq").reshape(b, t, h, hd)
-    kk = lora_dense(y, lp, "wk").reshape(b, t, kvh, hd)
-    vv = lora_dense(y, lp, "wv").reshape(b, t, kvh, hd)
-    return (rope(q, positions, cfg.rope_theta),
-            rope(kk, positions, cfg.rope_theta), vv)
+    q = dense(y, lp, "wq").reshape(b, t, h, hd)
+    kk = dense(y, lp, "wk").reshape(b, t, kvh, hd)
+    vv = dense(y, lp, "wv").reshape(b, t, kvh, hd)
+    return (rope(q, positions, cfg.rope_theta).astype(y.dtype),
+            rope(kk, positions, cfg.rope_theta).astype(y.dtype),
+            vv.astype(y.dtype))
+
+
+def qkv_proj(cfg, y: jax.Array, lp: Params, positions: jax.Array):
+    """Projection + RoPE of the TRAINING forward
+    (:func:`attention_block`). Returns (q, k, v); v unroped. The
+    compiler may fuse the products into the reshape to heads and the
+    rope that follow: at 8 x 2048 rows what that does to a weight's
+    layout is 0.03 % of a layer, and a finished product would be one
+    more pass over q, k and v. The forwards against a KV cache go
+    through :func:`cached_qkv_proj`."""
+    return _qkv(cfg, y, lp, positions, lora_dense)
+
+
+def _finished_dense(y: jax.Array, lp: Params, name: str) -> jax.Array:
+    """:func:`lora_dense` FINISHED: a (B, T, out) array that exists
+    before anything downstream may be fused into the product — in f32,
+    the accumulator as it is. See :func:`cached_qkv_proj`."""
+    return jax.lax.optimization_barrier(
+        lora_dense(y, lp, name, out_dtype=jnp.float32))
+
+
+def cached_qkv_proj(cfg, y: jax.Array, lp: Params,
+                    positions: jax.Array):
+    """Projection + RoPE of the forwards against a KV cache —
+    :func:`cached_attention_block` (the row-cache reference) and
+    :func:`paged_attention_block` (what serves) both reach q, k and v
+    through here and nowhere else, so they stay the same arithmetic.
+
+    Each product is FINISHED before it is reshaped to heads. Without
+    the barrier the v5e compiler picks the layout of q that the reshape
+    and ``rope``'s half-split want, f32[B,T,H,D]{3,0,2,1}, and re-lays
+    the WEIGHT to suit: in every layer of every step it slices the
+    layer's ``wq`` out of the stack into a buffer of its own
+    (``constant_dynamic-slice_fusion bf16[1,4096,4096]{2,1,0}``),
+    transposes that (``copy bf16[1,4096,4096]{1,2,0}``) and only then
+    multiplies — two more passes over 33.5 MB for a product of half a
+    megabyte, 81 us of a layer at Mistral-7B's widths, and the same for
+    ``wk`` and ``wv``: 142 us a layer where 72 do (PERF.md, PR 32).
+    Finished first, each weight is read once, in the layout it is
+    stored in, by a product that has its ``dynamic-slice`` fused in, as
+    ``wo`` and the MLP are read; what is re-laid is the product.
+    tests/test_tpu_compile.py holds the compiled programs to that. The
+    parameter tree cannot carry the layout instead: the benchmark's
+    plain reference reads ``wq``/``wk``/``wv`` as (dim, heads x
+    head_dim).
+
+    Finished in f32, not in the activations' bf16: that fused program
+    fed the f32 accumulator straight into ``rope`` (XLA drops a
+    bf16 round trip between two fused operations), and q and k were
+    rounded once, after the rotation. A product finished in bf16 rounds
+    them twice, and Mixtral's served tokens then left the float32
+    reference at 4 of 489 positions over the check's margin where the
+    fused program left it at none of 544 (PERF.md, PR 32). The f32 product is
+    a megabyte; ``rope`` computes in f32 either way."""
+    return _qkv(cfg, y, lp, positions, _finished_dense)
 
 
 def _mlp_activation(cfg):
@@ -725,7 +787,7 @@ def cached_attention_block(cfg, x: jax.Array, lp: Params,
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps,
                  getattr(cfg, "norm_offset", 0.0))
-    q, k_new, v_new = qkv_proj(cfg, y, lp, positions)
+    q, k_new, v_new = cached_qkv_proj(cfg, y, lp, positions)
     upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
     ck = jax.vmap(upd)(ck, k_new.astype(ck.dtype), start_pos)
     cv = jax.vmap(upd)(cv, v_new.astype(cv.dtype), start_pos)
@@ -958,7 +1020,7 @@ def paged_attention_block(cfg, x: jax.Array, lp: Params,
     quant = ks is not None
     y = rms_norm(x, lp["attn_norm"], cfg.norm_eps,
                  getattr(cfg, "norm_offset", 0.0))
-    q, k_new, v_new = qkv_proj(cfg, y, lp, positions)
+    q, k_new, v_new = cached_qkv_proj(cfg, y, lp, positions)
     # Every write to the bf16 pool is ONE row scatter
     # pk.at[li, blk, off] with (B, T) targets (the int8 pool re-scales
     # per block).

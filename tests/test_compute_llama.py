@@ -219,3 +219,26 @@ def test_adafactor_optimizer_trains():
     for _ in range(12):
         state, m = step(state, batch)
     assert float(m["loss"]) < float(m0["loss"])
+
+
+def test_training_forward_keeps_the_fused_projection():
+    """PR 32 finishes q, k and v behind an optimization barrier in the
+    forwards against a KV cache (llama.cached_qkv_proj), so that the
+    v5e compiler reads each weight where it lies. The training forward
+    is not one of them: no barrier, and the matmuls it has always had —
+    a layer's q, k, v, scores, values, wo and three of the MLP (the
+    scan's body, once), and the head."""
+    cfg = llama.LlamaConfig.tiny(vocab_size=128)
+    params = jax.eval_shape(lambda: llama.init(cfg, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    # A jaxpr prints the jaxprs inside it (scan and remat bodies) too.
+    text = str(jax.make_jaxpr(
+        lambda p, t: llama.forward(cfg, p, t))(params, tokens))
+    assert "optimization_barrier" not in text
+    assert text.count(" dot_general[") == 10
+    # The control: the same reading finds the barriers where they belong.
+    cache = jax.eval_shape(lambda: llama.init_cache(cfg, 2, 32))
+    cached = str(jax.make_jaxpr(
+        lambda p, t, c: llama.forward_with_cache(cfg, p, t, c, 0))(
+            params, tokens, cache))
+    assert cached.count(" optimization_barrier ") == 3

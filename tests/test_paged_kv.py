@@ -175,6 +175,71 @@ def test_paged_parity_other_families(family):
         assert got == [int(t) for t in ref[0]], (p, mt)
 
 
+def test_cached_and_paged_blocks_share_one_projection(monkeypatch):
+    """The row-cache reference and the paged forward reach q, k and v
+    through ONE function of the module, llama.cached_qkv_proj (the
+    products finished before the reshape to heads, PR 32), and never
+    through the training forward's llama.qkv_proj — so a test that
+    holds the engine to ``decode`` / ``forward_with_cache`` compares
+    two callers of the same arithmetic. The training forward is the
+    other way round."""
+    cfg = llama.LlamaConfig.tiny(vocab_size=128)
+    calls = []
+
+    def recorded(name):
+        real = getattr(llama, name)
+
+        def fn(*args):
+            calls.append(name)
+            return real(*args)
+        return fn
+
+    for name in ("qkv_proj", "cached_qkv_proj"):
+        monkeypatch.setattr(llama, name, recorded(name))
+    params = jax.eval_shape(lambda: llama.init(cfg, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    table = jax.ShapeDtypeStruct((1, 4), jnp.int32)
+    rows = jax.eval_shape(lambda: llama.init_cache(cfg, 1, 32))
+    pool = jax.eval_shape(lambda: llama.init_paged_cache(cfg, 5, 8))
+    # One trace of the scan's body each: one call each.
+    jax.eval_shape(lambda p, t, c: llama.forward_with_cache(
+        cfg, p, t, c, 0), params, tokens, rows)
+    assert calls == ["cached_qkv_proj"]
+    jax.eval_shape(lambda p, t, c, tb: llama.forward_with_paged_cache(
+        cfg, p, t, c, tb, 0, window=32, write_block=jnp.int32(1)),
+        params, tokens, pool, table)
+    assert calls == ["cached_qkv_proj"] * 2
+    jax.eval_shape(lambda p, t: llama.forward(cfg, p, t), params, tokens)
+    assert calls == ["cached_qkv_proj"] * 2 + ["qkv_proj"]
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral", "gemma"])
+def test_paged_forward_logits_equal_the_row_cache_forward(family):
+    """Below the engine: a prompt's chunk and three decode steps through
+    ``forward_with_paged_cache`` give the logits of
+    ``forward_with_cache`` to the bit (one projection, one attention
+    tile, tiles aligned: window == max_seq == 32)."""
+    mdl, cfg = _tiny(family)
+    params = mdl.init(cfg, jax.random.key(0))
+    bt, max_seq = 8, 32
+    prompt = jax.random.randint(jax.random.key(1), (1, bt), 1,
+                                cfg.vocab_size)
+    rows = mdl.init_cache(cfg, 1, max_seq)
+    pool = mdl.init_paged_cache(cfg, 1 + max_seq // bt, bt)
+    table = jnp.arange(1, 1 + max_seq // bt, dtype=jnp.int32)[None]
+    want, rows = mdl.forward_with_cache(cfg, params, prompt, rows, 0)
+    got, pool = mdl.forward_with_paged_cache(
+        cfg, params, prompt, pool, table, 0, window=max_seq,
+        write_block=jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for pos in range(bt, bt + 3):
+        tok = jnp.argmax(want[:, -1:], axis=-1).astype(jnp.int32)
+        want, rows = mdl.forward_with_cache(cfg, params, tok, rows, pos)
+        got, pool = mdl.forward_with_paged_cache(
+            cfg, params, tok, pool, table, pos, window=max_seq)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_paged_seeded_sampling_parity_and_zero_copy_hit(
         reference_stream):
     """A temperature > 0 stream is the row-cache reference's, sampled

@@ -18,6 +18,7 @@ library, and xdist gives a file to one worker), and the kernels'
 interpret choice is steered from here, not by an option of the program.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from skypilot_tpu.models import deepseek, llama
+from skypilot_tpu.models import deepseek, llama, mixtral
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops.pallas import flash_attention as fa
 from skypilot_tpu.parallel import mesh as mesh_lib
@@ -161,6 +162,60 @@ def test_kernel_compiles_inside_the_pipeline(topo, for_the_chip, axes):
     assert after["kernel_replicated"] == before["kernel_replicated"]
 
 
+def _compile_paged(topo, family, entry, *, llama_layers=2,
+                   kv_quant=False, weight_quant=False):
+    """One of the engine's three paged programs compiled for one
+    described chip at a benchmark cell's widths and slots (Mistral-7B
+    at ``llama_layers``, Mixtral-8x7B at the cell's four, DeepSeek-V3's
+    five), 20 blocks of 64 rows a slot. Returns (compiled, params,
+    pool), the last two as shapes."""
+    if family == "deepseek":
+        model, slots = deepseek, 64
+        cfg = deepseek.DeepseekV3Config.v3_5l_ep16()
+    elif family == "mixtral":
+        model, slots = mixtral, 64
+        cfg = dataclasses.replace(mixtral.MixtralConfig.mixtral_8x7b(),
+                                  n_layers=4)
+    else:
+        model, slots = llama, 32
+        cfg = llama.LlamaConfig(vocab_size=32768, dim=4096,
+                                n_layers=llama_layers, n_heads=32,
+                                n_kv_heads=8, mlp_dim=14336,
+                                rope_theta=1e6, max_seq_len=32768)
+    bt, max_seq, window = 64, 1280, 256
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+    def weights():
+        tree = model.init(cfg, jax.random.key(0))
+        return model.quantize_params(cfg, tree) if weight_quant else tree
+
+    params = on_chip(jax.eval_shape(weights))
+    pool = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
+        cfg, slots * max_seq // bt + 1, bt, quantized=kv_quant)))
+    i32, table_len = jnp.int32, max_seq // bt
+    sampling = (arg(jnp.float32, slots), arg(jnp.uint32, slots))
+    args = {
+        "_paged_step": (arg(i32, slots), arg(i32, slots),
+                        arg(i32, slots, table_len), window, *sampling),
+        "_paged_prefill_chunk": (arg(i32, bt), arg(i32, table_len),
+                                 arg(i32), arg(i32), arg(i32), window,
+                                 arg(i32, slots), arg(i32),
+                                 arg(jnp.uint32), arg(jnp.float32)),
+        "_paged_spec_step": (arg(i32, slots), arg(i32, slots, 4),
+                             arg(i32, slots), arg(i32, slots),
+                             arg(i32, slots, table_len), window,
+                             *sampling),
+    }[entry]
+    compiled = getattr(decode_engine, entry).lower(
+        cfg, params, pool, *args).compile()
+    return compiled, params, pool
+
+
 @pytest.mark.parametrize("family,entry,quantized", [
     ("llama", "_paged_step", False),
     ("llama", "_paged_prefill_chunk", False),
@@ -189,42 +244,8 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
     whole pool back for the attention's gather in every layer. Written
     as a row scatter, like the decode step's, the pool keeps the
     layout it arrived in."""
-    import re
-    if family == "deepseek":
-        model, slots = deepseek, 64
-        cfg = deepseek.DeepseekV3Config.v3_5l_ep16()
-    else:
-        model, slots = llama, 32
-        cfg = llama.LlamaConfig(vocab_size=32768, dim=4096, n_layers=2,
-                                n_heads=32, n_kv_heads=8, mlp_dim=14336,
-                                rope_theta=1e6, max_seq_len=32768)
-    bt, max_seq, window = 64, 1280, 256
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=one_chip), tree)
-    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=one_chip)
-    params = on_chip(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.key(0))))
-    pool = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
-        cfg, slots * max_seq // bt + 1, bt, quantized=quantized)))
-    i32, table_len = jnp.int32, max_seq // bt
-    sampling = (arg(jnp.float32, slots), arg(jnp.uint32, slots))
-    args = {
-        "_paged_step": (arg(i32, slots), arg(i32, slots),
-                        arg(i32, slots, table_len), window, *sampling),
-        "_paged_prefill_chunk": (arg(i32, bt), arg(i32, table_len),
-                                 arg(i32), arg(i32), arg(i32), window,
-                                 arg(i32, slots), arg(i32),
-                                 arg(jnp.uint32), arg(jnp.float32)),
-        "_paged_spec_step": (arg(i32, slots), arg(i32, slots, 4),
-                             arg(i32, slots), arg(i32, slots),
-                             arg(i32, slots, table_len), window,
-                             *sampling),
-    }[entry]
-    compiled = getattr(decode_engine, entry).lower(
-        cfg, params, pool, *args).compile()
+    compiled, _, pool = _compile_paged(topo, family, entry,
+                                       kv_quant=quantized)
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pool))
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -236,3 +257,80 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
             rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(",
             compiled.as_text(), re.M)
         assert not copies, copies
+
+
+def _weight_shaped(text, layers):
+    """The instructions of a compiled program whose result has the
+    shape of ONE layer of a stacked weight matrix, ``[1, *leaf.shape[1:]]``
+    in any layout, and that are either a ``copy`` (anywhere) or stand
+    outside every fused computation: a layer's weight cut out of its
+    stack into a buffer of its own, or re-laid. A slice that is fused
+    into the product that reads it appears only inside that product's
+    ``fused_computation``."""
+    shapes = {",".join(map(str, (1,) + leaf.shape[1:]))
+              for leaf in jax.tree.leaves(layers) if leaf.ndim >= 3}
+    found, fused = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace():    # a computation's head, or }
+            fused = "fused_computation" in line.split("(")[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(2) in shapes and (m.group(3) == "copy"
+                                           or not fused):
+            found.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
+    return found
+
+
+def test_weight_shaped_finds_a_cut_out_and_a_re_laid_weight():
+    """The reader above on four lines of the parent's compiled step
+    (PR 30) and one fused slice: the text it must find, and the text it
+    must let pass."""
+    layers = {"wq": jax.ShapeDtypeStruct((2, 4096, 4096), jnp.bfloat16),
+              "attn_norm": jax.ShapeDtypeStruct((2, 4096), jnp.bfloat16)}
+    text = """\
+%fused_computation.98 (param_0.1: bf16[2,4096,4096], param_1.2: s32[]) -> bf16[1,4096,4096] {
+  ROOT %dynamic-slice.9 = bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)} dynamic-slice(%param_0.1, %param_1.2)
+}
+%body (p: (bf16[32,1,4096], bf16[2,4096,4096])) -> (bf16[32,1,4096]) {
+  %slice.1 = bf16[1,4096]{1,0} dynamic-slice(%norm, %i)
+  %constant_dynamic-slice_fusion.8 = bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)} fusion(%w, %i), kind=kLoop
+  %copy.57 = bf16[1,4096,4096]{1,2,0:T(8,128)(2,1)S(1)} copy(%constant_dynamic-slice_fusion.8)
+}
+"""
+    assert _weight_shaped(text, layers) == [
+        "%constant_dynamic-slice_fusion.8 fusion [1,4096,4096]",
+        "%copy.57 copy [1,4096,4096]"]
+
+
+@pytest.mark.parametrize("family,entry,weight_quant", [
+    ("llama", "_paged_step", False),
+    ("llama", "_paged_prefill_chunk", False),
+    ("llama", "_paged_spec_step", False),
+    ("mixtral", "_paged_step", False),
+    ("llama", "_paged_step", True),
+])
+def test_paged_programs_read_each_weight_in_place_on_v5e(
+        topo, for_the_chip, family, entry, weight_quant):
+    """No program of the engine cuts a layer's weight out of its stack
+    into a buffer of its own, or transposes one: every matrix of
+    ``params["layers"]`` is read once a layer, where it lies, by the
+    product it feeds. Until PR 32 the v5e compiler gave q the layout
+    that the reshape to heads and rope's half-split want and re-laid
+    the WEIGHTS to match — ``constant_dynamic-slice_fusion
+    bf16[1,4096,4096]{2,1,0}`` and ``copy bf16[1,4096,4096]{1,2,0}`` for
+    ``wq``, the same at ``[1,4096,1024]`` for ``wk`` and ``wv``, in
+    every layer of every step, chunk and verify step, in Mixtral's
+    step too and as ``s8`` under int8 weights (8.7 % of the device's
+    time for ``wq`` alone in ``mistral7b-chat-steady``; ledger, PR 30).
+    ``llama.cached_qkv_proj`` finishes the three products first; this
+    is its contract, whatever it is written with. The CPU's compiler
+    never re-laid them: only this compile shows it. Mistral-7B at the
+    cell's 16 layers: at two the compiler fetches a whole 64 MB stack
+    ahead of the loop in per-layer ``slice-start``s, which is neither
+    the fault nor what a deployment's depth compiles to."""
+    compiled, params, _ = _compile_paged(topo, family, entry,
+                                         llama_layers=16,
+                                         weight_quant=weight_quant)
+    found = _weight_shaped(compiled.as_text(), params["layers"])
+    assert not found, found
