@@ -1,7 +1,8 @@
 """Model families: llama / mixtral / gemma share one attention,
 KV-cache, and serving-decode stack (models/llama.py); deepseek brings
-its own attention and latent paged pool (models/deepseek.py) to the
-same engine."""
+its own attention and latent paged pool (models/deepseek.py) and brumby
+its power retention and a pool of whole-sequence states
+(models/brumby.py) to the same engine."""
 from __future__ import annotations
 
 
@@ -9,14 +10,17 @@ def model_api(cfg):
     """Config-type -> model module (init/forward/decode/cache fns).
 
     Static dispatch on the (static-argnum) config dataclass, shared by
-    the serving recipe, the decode engine, and the benches so a fourth
+    the serving recipe, the decode engine, and the benches so a fifth
     family plugs in at exactly one place.
     """
-    from skypilot_tpu.models import deepseek, gemma, llama, mixtral
+    from skypilot_tpu.models import (brumby, deepseek, gemma, llama,
+                                     mixtral)
     if isinstance(cfg, mixtral.MixtralConfig):
         return mixtral
     if isinstance(cfg, deepseek.DeepseekV3Config):
         return deepseek
+    if isinstance(cfg, brumby.BrumbyConfig):
+        return brumby
     if isinstance(cfg, gemma.GemmaConfig):
         return gemma
     return llama
@@ -24,7 +28,7 @@ def model_api(cfg):
 
 def family_name(cfg) -> str:
     """Config-type -> family string ("llama" / "mixtral" / "gemma" /
-    "deepseek").
+    "deepseek" / "brumby").
 
     The stable identifier the tuning manifest keys engine constants
     by (skypilot_tpu/tune/) — the same dispatch as model_api, reduced

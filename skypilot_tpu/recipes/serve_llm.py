@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.agent import constants as agent_constants
-from skypilot_tpu.models import (deepseek, family_name, gemma, llama,
-                                 mixtral, model_api)
+from skypilot_tpu.models import (brumby, deepseek, family_name, gemma,
+                                 llama, mixtral, model_api)
 from skypilot_tpu.observability import metrics
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
@@ -47,6 +47,7 @@ from skypilot_tpu.observability import tracing
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.serve import decode_engine
 from skypilot_tpu.serve import gang_replica
+from skypilot_tpu.serve import kv_pool
 from skypilot_tpu.serve import load_balancing_policies
 from skypilot_tpu.train import distributed
 from skypilot_tpu.utils import compile_cache
@@ -809,6 +810,8 @@ def model_config(model: str, dtype: str = None):
         "gemma-7b": gemma.GemmaConfig.gemma_7b,
         "deepseek-tiny": deepseek.DeepseekV3Config.tiny,
         "deepseek-v3-5l-ep16": deepseek.DeepseekV3Config.v3_5l_ep16,
+        "brumby-tiny": brumby.BrumbyConfig.tiny,
+        "brumby-14b-6l": brumby.BrumbyConfig.b14_6l,
     }[model]()
     if dtype:
         cfg = dataclasses.replace(
@@ -874,7 +877,8 @@ def main(argv=None):
     p.add_argument("--model",
                    choices=["tiny", "8b", "mixtral-tiny", "mixtral-8x7b",
                             "gemma-tiny", "gemma-2b", "gemma-7b",
-                            "deepseek-tiny", "deepseek-v3-5l-ep16"],
+                            "deepseek-tiny", "deepseek-v3-5l-ep16",
+                            "brumby-tiny", "brumby-14b-6l"],
                    default="tiny")
     p.add_argument("--port", type=int, default=8080)
     p.add_argument("--seed", type=int, default=0)
@@ -1009,7 +1013,8 @@ def main(argv=None):
         spec_min_accept=kv["spec_min_accept"],
         host_cache_mb=kv["prefix_cache_mb"],
         family=family_name(cfg),
-        tp=(mesh.devices.size if mesh is not None else 1))
+        tp=(mesh.devices.size if mesh is not None else 1),
+        seq_blocks=kv_pool.blocks_per_sequence(cfg))
     if topology.hosts > 1 and rank > 0:
         # Non-zero hosts never front HTTP: they run the lockstep
         # follower loop against the leader's gang channel, mirroring

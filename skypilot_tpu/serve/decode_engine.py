@@ -28,6 +28,12 @@ and runs one jitted decode step over all slots every iteration:
     refcount transfer. At least one trailing prompt token is always
     prefilled so the first token is sampled from real logits.
     ``prefix_cache_mb`` sizes the host-RAM spill tier under the trie;
+  * a family whose block is a sequence's whole recurrent STATE
+    (kv_pool.blocks_per_sequence, models/brumby.py) changes the
+    accounting and nothing else: a slot holds a fixed number of blocks
+    that every step rewrites, admission reserves that, nothing grows,
+    and a prefix hit restores from a SNAPSHOT, copy-on-write
+    (:meth:`DecodeEngine._chunk_target`);
   * the pool is DONATED through all three jitted programs (prefill
     chunk, decode step, verify step), so the engine keeps one buffer
     from step to step. Donation names the buffer the result lands in,
@@ -171,6 +177,18 @@ _WEIGHT_QUANT_ENABLED = metrics.gauge(
     "stpu_engine_weight_quant_enabled",
     "1 while the engine serves int8 quantized params "
     "(STPU_WEIGHT_QUANT), else 0 — info gauge.")
+_STATE_SNAPSHOTS = metrics.counter(
+    "stpu_engine_state_snapshots_total",
+    "State snapshots of a family whose pool block is a sequence's whole "
+    "state: taken = a chunk boundary's state became a prefix-trie node "
+    "(the block a later chunk read and did not write), restored = an "
+    "admission started from one, evicted = LRU took one back.",
+    ("event",))
+_STATE_BLOCKS = metrics.gauge(
+    "stpu_engine_state_blocks",
+    "State pool blocks by holder (state families only): slot = a live "
+    "sequence's own state, snapshot = a prefix-trie node, free.",
+    ("kind",))
 _ZERO_COPY_HITS = metrics.counter(
     "stpu_engine_prefix_zero_copy_hits_total",
     "Prefix-cache hits served by aliasing pool blocks into the "
@@ -687,7 +705,8 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
                         block: int = 0, window_blocks: int = 0,
                         host_cache_mb: float = 0.0,
                         family: Optional[str] = None, tp: int = 1,
-                        use_manifest: bool = True
+                        use_manifest: bool = True,
+                        seq_blocks: int = 0
                         ) -> Dict[str, Any]:
     """EFFECTIVE KV-cache geometry for an engine config — the single
     derivation DecodeEngine.__init__, kv_config() and the gang
@@ -718,7 +737,22 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     dict, so gang members that resolved geometry from DIFFERENT
     manifests fail the welcome handshake even if the constants
     happen to coincide — tuned geometry drifts are join-fatal exactly
-    like kv/quant drifts."""
+    like kv/quant drifts.
+
+    What the keys mean. ``chunk`` is the prefill chunk and the prefix
+    trie's key unit, in tokens, for every family. For a family that
+    caches per token (``seq_blocks`` 0) it is also a block's size,
+    ``pool_blocks`` auto-sizes to ``slots * max_seq / chunk + 1``
+    (every slot at full length, plus scratch) and ``table_len`` is how
+    many blocks one slot's table can name. For a family whose block is
+    a sequence's whole state (``seq_blocks`` = kv_pool.
+    blocks_per_sequence(cfg) > 0) a block holds no tokens at all:
+    ``pool_blocks`` auto-sizes to ``slots * seq_blocks +
+    snapshot_blocks + 1`` — every slot's own state, ``snapshot_blocks``
+    = max(2, 3 * slots // 4) blocks for prefix snapshots and for the
+    fresh block a chunk writes while the one it read becomes a
+    snapshot, and scratch — ``table_len`` is ``seq_blocks``, and a
+    slot's token limit is ``max_seq``, not its table's span."""
     max_seq = int(max_seq)
     manifest_tag = "default"
     if use_manifest and family:
@@ -751,8 +785,11 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     # Auto sizing: slots * max_seq tokens of bf16 KV plus the scratch
     # block. An int8 block (codes + one f32 scale per layer/head) is
     # ~half the bytes, so the same budget holds 2x the blocks.
+    seq_blocks = int(seq_blocks)
+    snapshot_blocks = max(2, 3 * int(slots) // 4) if seq_blocks else 0
+    per_slot = seq_blocks or (2 if kv_quant else 1) * (max_seq // chunk)
     total = int(kv_pool_blocks) or (
-        (2 if kv_quant else 1) * int(slots) * (max_seq // chunk) + 1)
+        int(slots) * per_slot + snapshot_blocks + 1)
     if window_blocks:
         window = max(min(int(window_blocks) * chunk,
                          max_seq // chunk * chunk), chunk)
@@ -776,7 +813,8 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
         "spec_k": int(spec_k), "spec_ngram": int(spec_ngram),
         "spec_min_accept": float(spec_min_accept),
         "pool_blocks": total, "window": window,
-        "table_len": -(-(total - 1) // nbw) * nbw,
+        "table_len": seq_blocks or -(-(total - 1) // nbw) * nbw,
+        "seq_blocks": seq_blocks, "snapshot_blocks": snapshot_blocks,
         "host_mb": float(host_cache_mb)}
 
 
@@ -820,6 +858,14 @@ class DecodeEngine:
             raise ValueError("spec_ngram must be >= 1")
         self._cfg = cfg
         self._api = model_api(cfg)
+        # The one question about the pool's accounting, asked once: 0 =
+        # blocks of tokens, appended and aliased; n = a sequence holds n
+        # blocks that every step rewrites (kv_pool.blocks_per_sequence).
+        self._seq_blocks = kv_pool.blocks_per_sequence(cfg)
+        if self._seq_blocks > 1:
+            raise NotImplementedError(
+                "a sequence state of more than one pool block has no "
+                "program yet (table[b, 0] names the one)")
         # int8 weight serving: quantize here (idempotent — params may
         # arrive pre-quantized from a checkpoint) and, under a mesh,
         # re-place by the QUANTIZED spec tree so codes shard like the
@@ -871,7 +917,7 @@ class DecodeEngine:
             host_cache_mb=host_mb,
             family=family_name(cfg),
             tp=(mesh.devices.size if mesh is not None else 1),
-            use_manifest=use_manifest)
+            use_manifest=use_manifest, seq_blocks=self._seq_blocks)
         self._kv_geometry = geo
         chunk = geo["chunk"]
         self._chunk = chunk
@@ -879,6 +925,10 @@ class DecodeEngine:
         # passed the 0 sentinel — read the EFFECTIVE value back from
         # the geometry, the same dict the handshake compares.
         self._spec_k = geo["spec_k"]
+        refuse_options = getattr(self._api, "refuse_engine_options", None)
+        if refuse_options is not None:
+            refuse_options(cfg, spec_k=self._spec_k,
+                           host_cache_mb=geo["host_mb"])
         self._max_queue = int(max_queue)
         # Host-RAM spill tier state (host_mb > 0 only, but the
         # attributes always exist — shutdown and introspection touch
@@ -892,7 +942,7 @@ class DecodeEngine:
         # cache (serve/kv_pool.py), sized and tiled by
         # resolve_kv_geometry.
         total = geo["pool_blocks"]
-        self._pool = kv_pool.BlockPool(total, chunk)
+        self._pool = kv_pool.BlockPool(total, chunk, self._seq_blocks)
         self._window = geo["window"]    # attention tile, whole blocks
         # Per-slot LOGICAL capacity is the pool, not a row: the table
         # can address every usable block (rounded up so the last
@@ -958,8 +1008,10 @@ class DecodeEngine:
         # Requests between the two halves of their end: slot retired,
         # last token still unread (in_flight() counts them).
         self._retiring = 0
-        # A slot whose next write would be the last position ends.
-        self._limit = self._table_len * chunk
+        # A slot whose next write would be the last position ends: its
+        # table's span, or (a state holds no positions) max_seq.
+        self._limit = (int(max_seq) if self._seq_blocks
+                       else self._table_len * chunk)
         _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
             cfg, chunk, quantized=self._kv_quant))
         _KV_QUANT_ENABLED.set(int(self._kv_quant))
@@ -1019,6 +1071,12 @@ class DecodeEngine:
                 f"({req.max_tokens}) exceeds the KV pool "
                 f"({self._pool.usable_blocks} blocks x "
                 f"{self._chunk} tokens)")
+        if self._seq_blocks and \
+                len(req.prompt) + req.max_tokens > self._limit:
+            raise EngineError(
+                f"prompt ({len(req.prompt)}) + max_tokens "
+                f"({req.max_tokens}) exceeds the engine's max_seq "
+                f"({self._limit})")
         with self._cond:
             if self._failed:
                 raise EngineError(f"engine failed: {self._failed}")
@@ -1063,7 +1121,11 @@ class DecodeEngine:
         gang leader and its followers must agree on byte-for-byte or
         admission/backpressure (and draft/accept) decisions diverge
         across hosts. serve_llm derives the same dict via
-        resolve_kv_geometry for the welcome handshake."""
+        resolve_kv_geometry for the welcome handshake, and that
+        function's docstring says what ``pool_blocks``, ``table_len``
+        and ``chunk`` mean for a family whose block is a sequence's
+        state (``seq_blocks`` > 0; ``snapshot_blocks`` is the rule's
+        share for prefix snapshots)."""
         return dict(self._kv_geometry)
 
     def cache_bytes_per_device(self) -> Dict[int, int]:
@@ -1190,6 +1252,11 @@ class DecodeEngine:
         a gather. Zero device work, zero host copies. The final
         partial prompt block (prompt tail + generated tokens share it)
         is never published."""
+        if self._seq_blocks:
+            # A state block holds the state after everything the slot
+            # has seen, not after a prompt chunk: its snapshots were
+            # taken as it prefilled (_chunk_target).
+            return
         slot = self._slots[i]
         self.prefix_cache.publish(
             slot.request.prompt, slot.prefilled,
@@ -1216,8 +1283,8 @@ class DecodeEngine:
             slot.held = []
         for j in range(aliased, slot.blocks):
             self._pool.release(int(self._table[i, j]))
-        if slot.blocks:
-            self._table[i, :slot.blocks] = 0
+        if slot.blocks or aliased:
+            self._table[i, :max(slot.blocks, aliased)] = 0
         slot.blocks = 0
         if slot.reserved:
             self._pool.unreserve(slot.reserved)
@@ -1336,6 +1403,8 @@ class DecodeEngine:
         never lose a block, so nothing decoding is ever rolled back).
         """
         nodes = self.prefix_cache.match(req.prompt)
+        if self._seq_blocks:
+            return self._admit_state(i, req, nodes)
         # Split the match by residency: a device-resident prefix (the
         # zero-copy alias) followed by a host-resident suffix to
         # re-admit H2D. Payloads are fetched NOW — holding the host
@@ -1386,19 +1455,110 @@ class DecodeEngine:
         slot.generated = 0
         req.cached_prompt_tokens = slot.cached
         self.prefix_cache.note_result(len(dev_nodes) + len(pending))
-        if dev_nodes or pending:
+        if dev_nodes:
+            _ZERO_COPY_HITS.inc()
+        self._count_admission(
+            req, len(dev_nodes) + len(pending),
+            "host" if pending else "hbm" if dev_nodes else "miss")
+        return True
+
+    def _count_admission(self, req: Request, chunks: int,
+                         tier: str) -> None:
+        """A successful admission on the prefix counters: a hit that
+        saved ``chunks`` chunks of prefill from ``tier``, or a miss."""
+        if chunks:
             _PREFIX_HITS.inc()
-            if dev_nodes:
-                _ZERO_COPY_HITS.inc()
-            _PREFIX_SAVED.inc(
-                (len(dev_nodes) + len(pending)) * self._chunk)
+            _PREFIX_SAVED.inc(chunks * self._chunk)
         else:
             _PREFIX_MISSES.inc()
-        tier = "host" if pending else "hbm" if dev_nodes else "miss"
         _KV_TIER_HITS.labels(tier=tier).inc()
         if reqlog.ENABLED:
             req.kv_tier = tier
+
+    def _admit_state(self, i: int, req: Request, nodes: List[Any]
+                     ) -> bool:
+        """:meth:`_try_admit_paged` for a family whose block is a
+        sequence's whole state: the reservation is the fixed
+        ``seq_blocks`` whatever the request's length, and a hit pins
+        the DEEPEST matching node alone — its block holds the state
+        after all the matched chunks, which the slot's first own chunk
+        reads (``table[i, 0]``) and never writes: it writes the slot's
+        own block (:meth:`_chunk_target`). The nodes above it are kept
+        by the trie's leaf-only eviction, as any interior node is."""
+        held = nodes[-1:]
+        self.prefix_cache.pin(held)
+        while self._pool.available() < self._seq_blocks:
+            if not self._evict_snapshot():
+                self.prefix_cache.unpin(held)
+                return False
+        self._pool.reserve(self._seq_blocks)
+        slot = self._slots[i]
+        slot.request = req
+        slot.held = held
+        slot.pending = []
+        slot.blocks = 0
+        self._table[i, 0] = held[0].block if held else 0
+        slot.reserved = self._seq_blocks
+        slot.cached = len(nodes) * self._chunk
+        slot.prefilled = slot.pos = slot.cached
+        slot.generated = 0
+        req.cached_prompt_tokens = slot.cached
+        self.prefix_cache.note_result(len(nodes), zero_copy=False)
+        if nodes:
+            _STATE_SNAPSHOTS.labels(event="restored").inc()
+        self._count_admission(req, len(nodes),
+                              "hbm" if nodes else "miss")
         return True
+
+    def _evict_snapshot(self) -> bool:
+        """LRU-evict one unpinned snapshot leaf (state families)."""
+        if not self.prefix_cache.evict_one():
+            return False
+        _STATE_SNAPSHOTS.labels(event="evicted").inc()
+        return True
+
+    def _chunk_target(self, i: int, start: int) -> int:
+        """The block a state family's prefill chunk writes, the chunk
+        at ``start`` of slot ``i``'s prompt; ``table[i, 0]`` names the
+        block it reads. Copy-on-write, one chunk at a time:
+
+        * the slot's FIRST chunk reads what admission put there — a
+          pinned snapshot, or the scratch block's zero state — and
+          writes the slot's own block, drawn from its reservation; the
+          snapshot is unpinned and never written;
+        * a later chunk reads the slot's own block, which holds the
+          state after the ``start // chunk`` full chunks before it. If
+          a spare block is free (or an unpinned snapshot leaf can be
+          evicted for one), the chunk writes THAT and the block it
+          read becomes the trie's node for those chunks — a snapshot
+          taken with no copy — or frees if the node is there already.
+          With no spare block the chunk rewrites the slot's block in
+          place and no snapshot is taken: a full pool costs prefix
+          reuse, never a request.
+
+        The caller uploads the table row (the block to READ) before it
+        points ``table[i, 0]`` at the block returned here. Programs run
+        in dispatch order, so a block is rewritten only after whatever
+        read it last."""
+        slot = self._slots[i]
+        if not slot.blocks:
+            block = self._pool.alloc()
+            slot.reserved -= 1
+            slot.blocks = 1
+            if slot.held:
+                self.prefix_cache.unpin(slot.held)
+                slot.held = []
+            return block
+        read = int(self._table[i, 0])
+        while self._pool.available() < 1:
+            if not self._evict_snapshot():
+                return read
+        block = self._pool.alloc(reserved=False)
+        if self.prefix_cache.publish_snapshot(
+                slot.request.prompt, start // self._chunk, read):
+            _STATE_SNAPSHOTS.labels(event="taken").inc()
+        self._pool.release(read)
+        return block
 
     def _admit(self) -> None:
         # Traced-phase stamps taken under the lock, RECORDED after it:
@@ -1448,12 +1608,18 @@ class DecodeEngine:
                                 attrs=attrs)
 
     def _update_pool_gauges(self) -> None:
-        _KV_POOL_FREE.set(self._pool.free_blocks())
+        free = self._pool.free_blocks()
+        _KV_POOL_FREE.set(free)
         pinned = set()
         for i, s in enumerate(self._slots):
             if s.request is not None:
                 pinned.update(int(b) for b in self._table[i, :s.blocks])
         _KV_POOL_PINNED.set(len(pinned))
+        if self._seq_blocks:
+            _STATE_BLOCKS.labels(kind="slot").set(len(pinned))
+            _STATE_BLOCKS.labels(kind="snapshot").set(
+                self.prefix_cache.stats()["chunks"])
+            _STATE_BLOCKS.labels(kind="free").set(free)
 
     def _table_upload(self, i=slice(None)):
         """The block table (or slot ``i``'s row) as a program's input:
@@ -1463,10 +1629,28 @@ class DecodeEngine:
         a block — before it waits for anything."""
         return jnp.asarray(self._table[i].copy())
 
+    def _step_table(self, live: List[int]):
+        """The block table a decode step runs with. A family that
+        pages by the token takes it whole: rows of slots that do not
+        decode write a row nobody attends. A state family's step
+        REWRITES the block each row names, so only the decoding slots'
+        rows go in and every other row names the scratch block, which
+        the program skips (a slot in the middle of its prefill keeps
+        its state; free slots cost a step nothing)."""
+        if not self._seq_blocks:
+            return self._table_upload()
+        table = np.zeros_like(self._table)
+        table[live] = self._table[live]
+        return jnp.asarray(table)
+
     def _ensure_block(self, i: int, j: int) -> int:
         """Back slot ``i``'s logical block ``j``, allocating from the
         slot's admission reservation on first touch (lazy growth —
-        blocks are claimed as prefill/decode actually reaches them)."""
+        blocks are claimed as prefill/decode actually reaches them).
+        A state family's slot holds its fixed blocks from its first
+        chunk on and never grows."""
+        if self._seq_blocks:
+            return int(self._table[i, 0])
         slot = self._slots[i]
         if j < slot.blocks:
             return int(self._table[i, j])
@@ -1537,11 +1721,14 @@ class DecodeEngine:
                 fault_injection.fire("engine.prefill", slot=i,
                                      start=start)
             _STEP_KIND["prefill"].inc()
-            wb = self._ensure_block(i, start // self._chunk)
+            wb = (self._chunk_target(i, start) if self._seq_blocks
+                  else self._ensure_block(i, start // self._chunk))
             self._toks, self._cache = _paged_prefill_chunk(
                 self._cfg, self._params, self._cache, buf,
                 self._table_upload(i), jnp.int32(start),
                 jnp.int32(valid), jnp.int32(wb), self._window, *first)
+            if self._seq_blocks:
+                self._table[i, 0] = wb     # the slot's state, from now
             req.prefill_chunks += 1
             slot.prefilled = valid
             slot.pos = valid
@@ -1922,7 +2109,7 @@ class DecodeEngine:
             self._ensure_block(i, self._slots[i].pos // self._chunk)
         nxt, self._cache = _paged_step(
             self._cfg, self._params, self._cache, self._toks, pos,
-            self._table_upload(), self._window, temps, seeds)
+            self._step_table(live), self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
         self._toks, chosen = nxt if isinstance(nxt, tuple) \

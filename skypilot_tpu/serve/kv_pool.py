@@ -31,6 +31,18 @@ Block 0 is a reserved SCRATCH block, never allocated: free slots ride
 along in the batched decode step with ``pos 0`` and their (ignored)
 K/V writes land there instead of clobbering a live slot's block.
 
+A family may instead keep a FIXED-SIZE state a sequence
+(:func:`blocks_per_sequence`, models/brumby.py): a block is then one
+sequence's whole state, a sequence holds the same number of blocks
+whatever its length, and every step REWRITES them, so a live slot's
+block is never the trie's. There the trie's node for a chunk holds a
+SNAPSHOT of the state after that chunk — the block a later chunk of the
+same prompt read from and did not write (:meth:`PagedPrefixCache.
+publish_snapshot`) — a hit pins the deepest matching node alone, the
+slot's first chunk reads it and writes the slot's own block
+(copy-on-write), and the scratch block, which no program ever changes,
+is the zero state a cold prompt starts from.
+
 Below the device pool sits an optional second tier
 (:class:`HostBlockPool`): on LRU eviction a leaf's block is SPILLED
 D2H into a bounded host-RAM pool instead of destroyed — the trie node
@@ -98,6 +110,22 @@ def block_bytes_for(cfg, block_tokens: int, *,
     return sum(a.size * a.dtype.itemsize for a in pool.values())
 
 
+def blocks_per_sequence(cfg) -> int:
+    """The ONE question the engine asks a family about its pool's
+    accounting: how many blocks a sequence holds whatever its length —
+    0 for a family that caches something per token (blocks are appended
+    as the sequence grows and never rewritten, so the trie may alias
+    them), the family's ``state_blocks_per_sequence(cfg)`` for one
+    whose block is a sequence's recurrent state (rewritten by every
+    step, so restored from snapshots, never aliased). Admission, the
+    pool's auto size, the table's length and a slot's token limit all
+    follow from it (:class:`BlockPool`, decode_engine.
+    resolve_kv_geometry)."""
+    from skypilot_tpu.models import model_api
+    ask = getattr(model_api(cfg), "state_blocks_per_sequence", None)
+    return int(ask(cfg)) if ask is not None else 0
+
+
 def blocks_for_budget(budget_bytes: int, block_tokens: int,
                       n_layers: int, n_kv_heads: int, head_dim: int, *,
                       quantized: bool = False,
@@ -121,7 +149,8 @@ class BlockPool:
     a deque — deterministic, so seeded runs replay exactly.
     """
 
-    def __init__(self, num_blocks: int, block_tokens: int):
+    def __init__(self, num_blocks: int, block_tokens: int,
+                 seq_blocks: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"kv pool needs >= 2 blocks (1 scratch + 1 usable); "
@@ -131,6 +160,9 @@ class BlockPool:
                              f"{block_tokens}")
         self.num_blocks = int(num_blocks)
         self.block_tokens = int(block_tokens)
+        # :func:`blocks_per_sequence`'s answer: > 0 where a block is a
+        # sequence's whole state.
+        self.seq_blocks = int(seq_blocks)
         self._free: "collections.deque[int]" = collections.deque(
             range(1, self.num_blocks))
         self._refs: Dict[int, int] = {}
@@ -144,7 +176,9 @@ class BlockPool:
         return self.num_blocks - 1
 
     def blocks_for(self, tokens: int) -> int:
-        return -(-int(tokens) // self.block_tokens)
+        """Blocks a sequence of ``tokens`` tokens holds: one a
+        ``block_tokens`` of them, or the family's fixed count."""
+        return self.seq_blocks or -(-int(tokens) // self.block_tokens)
 
     # -------------------------------------------------------- accounting
     def free_blocks(self) -> int:
@@ -310,6 +344,11 @@ class HostBlockPool:
                     "lru_dropped": self.lru_dropped,
                     "rehits": self.rehits,
                     "inflight": len(self._inflight)}
+
+
+# Subtracted from the LRU clock for a snapshot nobody has restored
+# from: more than the clock will ever count.
+_NEVER_HIT = 1 << 62
 
 
 class _BlockNode:
@@ -482,12 +521,14 @@ class PagedPrefixCache:
             self._host_chunks -= 1
             self.promotions += 1
 
-    def note_result(self, matched_chunks: int) -> None:
-        """Count a successful admission's hit/miss + tokens saved."""
+    def note_result(self, matched_chunks: int,
+                    zero_copy: bool = True) -> None:
+        """Count a successful admission's hit/miss + tokens saved (a
+        hit restored from a snapshot is no zero-copy alias)."""
         with self._lock:
             if matched_chunks:
                 self.hits += 1
-                self.zero_copy_hits += 1
+                self.zero_copy_hits += bool(zero_copy)
                 self.tokens_saved += matched_chunks * self.chunk
             else:
                 self.misses += 1
@@ -530,6 +571,44 @@ class PagedPrefixCache:
                 child.tick = self._tick
                 node = child
         return adopted
+
+    def publish_snapshot(self, prompt: List[int], n_chunks: int,
+                         block: int) -> bool:
+        """Adopt ``block`` as the node of ``prompt``'s first
+        ``n_chunks`` chunks: the state AFTER them, which a later chunk
+        read and no program will write again. True when the trie took
+        it (pool.retain: the caller drops its own reference right
+        after, as in :meth:`publish`); False when the node is there
+        already or its parent is not (evicted meanwhile): the block
+        then simply frees."""
+        with self._lock:
+            self._tick += 1
+            node = self._root
+            for j in range(n_chunks - 1):
+                node = node.children.get(
+                    tuple(prompt[j * self.chunk:(j + 1) * self.chunk]))
+                if node is None or node.block < 0:
+                    return False
+            j = n_chunks - 1
+            key = tuple(prompt[j * self.chunk:(j + 1) * self.chunk])
+            child = node.children.get(key)
+            if child is not None:
+                child.tick = self._tick
+                return False
+            child = _BlockNode(key, node, block)
+            node.children[key] = child
+            self.pool.retain(child.block)
+            # Never restored from yet: older than every node that has
+            # been (a pin stamps the clock's positive tick), oldest
+            # taken first among its like. Most snapshots are of a
+            # prompt's OWN chunks and are never asked for again; at one
+            # LRU clock for both, they pushed the shared prefixes'
+            # nodes out between two of their hits (a quarter of the
+            # admissions of brumby14b-reason-steady missed; PERF.md,
+            # PR 33).
+            child.tick = self._tick - _NEVER_HIT
+            self._chunks += 1
+            return True
 
     # ----------------------------------------------------------- evict
     def evict_one(self):

@@ -26,7 +26,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from skypilot_tpu.models import deepseek, llama, mixtral
+from skypilot_tpu.models import brumby, deepseek, llama, mixtral
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops.pallas import flash_attention as fa
 from skypilot_tpu.parallel import mesh as mesh_lib
@@ -334,3 +334,57 @@ def test_paged_programs_read_each_weight_in_place_on_v5e(
                                          weight_quant=weight_quant)
     found = _weight_shaped(compiled.as_text(), params["layers"])
     assert not found, found
+
+
+@pytest.mark.parametrize("entry", ["_paged_step", "_paged_prefill_chunk"])
+def test_state_pool_programs_stay_in_place_on_v5e(topo, for_the_chip,
+                                                  entry):
+    """Brumby-14B at the cell's six layers, 16 slots and 29 state
+    blocks of 228 MB: the pool (6.62 GB beside 7.08 GB of weights; a
+    second one does not fit the chip) is one buffer in both programs —
+    temporaries under ONE block, no pool-shaped copy — no weight is cut
+    out of its stack or re-laid, and the decode step's state update is
+    the Mosaic kernel, which interpret mode never compiles (a row of
+    ``phi(q)`` sliced at a lane offset did not broadcast over sublanes
+    until it was read 128 lanes at a time; PERF.md, PR 33). The chunk's
+    whole-block write of the new state is one the TPU compiles in
+    place: its operand comes out of a product with ``D`` minor, the
+    layout the pool has."""
+    cfg, slots, blocks = brumby.BrumbyConfig.b14_6l(), 16, 29
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: brumby.init(cfg, jax.random.key(0))))
+    pool = on_chip(jax.eval_shape(
+        lambda: brumby.init_paged_cache(cfg, blocks, 64)))
+    i32 = jnp.int32
+    args = {
+        "_paged_step": (arg(i32, slots), arg(i32, slots),
+                        arg(i32, slots, 1), 64, arg(jnp.float32, slots),
+                        arg(jnp.uint32, slots)),
+        "_paged_prefill_chunk": (arg(i32, 64), arg(i32, 1), arg(i32),
+                                 arg(i32), arg(i32), 64, arg(i32, slots),
+                                 arg(i32), arg(jnp.uint32),
+                                 arg(jnp.float32)),
+    }[entry]
+    compiled = getattr(decode_engine, entry).lower(
+        cfg, params, pool, *args).compile()
+    text = compiled.as_text()
+    block = sum(a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(pool)) // blocks
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < block, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= blocks * block
+    for leaf in pool.values():
+        dims = ",".join(map(str, leaf.shape))
+        copies = re.findall(
+            rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(", text,
+            re.M)
+        assert not copies, copies
+    found = _weight_shaped(text, params["layers"])
+    assert not found, found
+    assert ("stpu_retention_step" in text) == (entry == "_paged_step")
