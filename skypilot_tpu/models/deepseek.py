@@ -335,15 +335,46 @@ def route(cfg: DeepseekV3Config, logits: jax.Array, bias: jax.Array
     return w * cfg.routed_scaling_factor, chosen
 
 
-def moe_block(cfg: DeepseekV3Config, x: jax.Array, lp: Params
+_EXPERTS = ("we_gate", "we_up", "we_down")
+
+
+def experts_asked(chosen: jax.Array, counts: Optional[jax.Array]
+                  ) -> jax.Array:
+    """(held,) bool: the held experts that a row which COUNTS chose
+    (``chosen`` (B, T, held), ``counts`` (B, T) bool; None: every
+    row). What :func:`moe_block` computes, and what the engine counts
+    as computed."""
+    if counts is not None:
+        chosen = chosen & counts[..., None]
+    return jnp.any(chosen, axis=(0, 1))
+
+
+def moe_block(cfg: DeepseekV3Config, x: jax.Array, lp: Params,
+              counts: Optional[jax.Array] = None,
+              layer: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
     """Pre-norm sparse residual block: (x + held routed part + shared
-    expert, chosen-and-held (B, T, held) bool). Every held expert is
-    computed for every token and weighted (zero where it was not
-    chosen): a token's result does not depend on its batch, so
-    incremental decode equals a full pass."""
+    expert, chosen-and-held (B, T, held) bool).
+
+    A held expert is computed only if a row that counts chose it
+    (:func:`experts_asked`), and no byte of its three matrices is read
+    otherwise: one pass of a loop for each such expert, taken in order,
+    does its products for all rows (zero weight where a row did not
+    choose it) and adds into a float32 sum that is rounded once. An
+    expert left out would have added exact zeros to every counted row,
+    so such a row's result depends on its batch by the order of that
+    sum at most, and incremental decode equals a full pass; a row that
+    does not count comes out without the experts only it chose, and
+    nobody reads it. With ``layer``, ``lp``'s three expert leaves are
+    the whole (layers, held, ...) stacks and the loop reads
+    ``[layer, e]`` where it lies: a layer's experts cut out ahead of
+    the loop would be a copy of all of them."""
     lo = cfg.ep_rank * cfg.n_experts_held
     hi = lo + cfg.n_experts_held
+    we_gate, we_up, we_down = (
+        lp[name] if layer is not None else lp[name][None]
+        for name in _EXPERTS)
+    layer = jnp.int32(0) if layer is None else layer
     with jax.named_scope("stpu.moe"):
         y = llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         # The choice is discrete: its logits are worth six bf16 passes
@@ -352,14 +383,25 @@ def moe_block(cfg: DeepseekV3Config, x: jax.Array, lp: Params
                             lp["router"],
                             precision=jax.lax.Precision.HIGHEST)
         w, chosen = route(cfg, logits, lp["router_bias"])
-        w = w[..., lo:hi].astype(y.dtype)
-        gate = jax.nn.silu(jnp.einsum("btd,edm->btem", y, lp["we_gate"]))
-        up = jnp.einsum("btd,edm->btem", y, lp["we_up"])
-        routed = jnp.einsum("btem,emd->btd", gate * up * w[..., None],
-                            lp["we_down"])
+        w, chosen = w[..., lo:hi].astype(y.dtype), chosen[..., lo:hi]
+        asked = experts_asked(chosen, counts)
+        order = jnp.argsort(~asked, stable=True)
+
+        def one_expert(i, routed):
+            e = order[i]
+            gate = jax.nn.silu(y @ we_gate[layer, e])
+            up = y @ we_up[layer, e]
+            w_e = jax.lax.dynamic_index_in_dim(w, e, axis=2)
+            return routed + jnp.einsum(
+                "btm,md->btd", gate * up * w_e, we_down[layer, e],
+                preferred_element_type=jnp.float32)
+
+        routed = jax.lax.fori_loop(
+            0, jnp.sum(asked, dtype=jnp.int32), one_expert,
+            jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
         shared = (jax.nn.silu(y @ lp["ws_gate"]) * (y @ lp["ws_up"])
                   ) @ lp["ws_down"]
-        return x + routed + shared, chosen[..., lo:hi]
+        return x + routed + shared, chosen
 
 
 # ------------------------------------------------------------- attention
@@ -563,22 +605,37 @@ def paged_attention_block(cfg: DeepseekV3Config, x: jax.Array,
 
 
 # ------------------------------------------------------- forward passes
-def _dense_block(cfg, x, lp):
+def _dense_block(cfg, x, lp, counts, layer):
     return llama.mlp_block(cfg, x, lp), None
+
+
+def _scanned(stack: Params):
+    """A stack of layers as (what a scan slices a layer at a time, the
+    sparse layers' expert matrices, which stay whole: moe_block reads
+    them by [layer, expert])."""
+    return ({k: v for k, v in stack.items() if k not in _EXPERTS},
+            {k: stack[k] for k in _EXPERTS if k in stack})
 
 
 def forward(cfg: DeepseekV3Config, params: Params, tokens: jax.Array,
             positions: Optional[jax.Array] = None) -> jax.Array:
-    """Token ids (B, S) -> float32 logits (B, S, vocab), no cache."""
+    """Token ids (B, S) -> float32 logits (B, S, vocab), no cache.
+    Every row counts."""
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = llama._decode_embed(cfg, params, tokens)
     for stack, mlp in ((params["dense_layers"], _dense_block),
                        (params["moe_layers"], moe_block)):
+        sliced, whole = _scanned(stack)
+
+        def layer_fn(x, scanned):
+            lp, i = scanned
+            x = attention_block(cfg, x, lp, positions)
+            return mlp(cfg, x, {**lp, **whole}, None, i)[0], None
         x, _ = jax.lax.scan(
-            lambda x, lp: (mlp(cfg, attention_block(
-                cfg, x, lp, positions), lp)[0], None), x, stack)
+            layer_fn, x,
+            (sliced, jnp.arange(stack["attn_norm"].shape[0])))
     return llama.lm_head(cfg, params, x, lambda a, _spec: a)
 
 
@@ -592,35 +649,49 @@ def forward_with_paged_cache(cfg: DeepseekV3Config, params: Params,
                              write_block: Optional[jax.Array] = None,
                              write_pos: Optional[jax.Array] = None):
     """llama.forward_with_paged_cache's contract over the latent pool,
-    with a third result: (logits, pool, chosen), ``chosen`` (B, T,
-    sparse layers, held) bool — which held experts each token chose,
-    which the engine counts per decode step. Two scans, dense layers
-    then sparse ones, both carrying the pool."""
+    with a third result: (logits, pool, (chosen, computed)), ``chosen``
+    (B, T, sparse layers, held) bool — which held experts each token
+    chose — and ``computed`` (sparse layers, held) bool — which of them
+    the program computed; the engine counts both per decode step. Two
+    scans, dense layers then sparse ones, both carrying the pool.
+
+    The rows that COUNT for the expert layer: the positions under
+    ``valid_len`` (a chunk's padded tail does not), and in a decode
+    step or a verify window only the rows whose table names a block of
+    their own. The engine hands such a step a table in which every row
+    that does not decode names block 0, the scratch block
+    (decode_engine._step_table), so the step learns its decoding rows
+    from an argument it already has."""
     b, t = tokens.shape
     start_pos, valid_len, positions = llama.slot_positions(
         b, t, start_pos, valid_len)
+    counts = positions < valid_len[:, None]
+    if t == 1 or write_pos is not None:
+        counts &= table[:, :1] != 0
     x = llama._decode_embed(cfg, params, tokens)
 
     def stack_scan(x, pool, stack, first, mlp):
+        sliced, whole = _scanned(stack)
+
         def layer_fn(carry, scanned):
             x, pool = carry
-            lp, li = scanned
+            lp, i = scanned
             x, pool = paged_attention_block(
-                cfg, x, lp, li, pool, table, positions, start_pos,
-                valid_len, window, write_block, write_pos)
-            x, chosen = mlp(cfg, x, lp)
+                cfg, x, lp, first + i, pool, table, positions,
+                start_pos, valid_len, window, write_block, write_pos)
+            x, chosen = mlp(cfg, x, {**lp, **whole}, counts, i)
             return (x, pool), chosen
         n = stack["attn_norm"].shape[0]
-        return jax.lax.scan(layer_fn, (x, pool),
-                            (stack, first + jnp.arange(n)))
+        return jax.lax.scan(layer_fn, (x, pool), (sliced, jnp.arange(n)))
 
     (x, pool), _ = stack_scan(x, dict(cache), params["dense_layers"], 0,
                               _dense_block)
     (x, pool), chosen = stack_scan(x, pool, params["moe_layers"],
                                    cfg.n_dense_layers, moe_block)
+    computed = jax.vmap(experts_asked, (0, None))(chosen, counts)
     logits = llama.lm_head(cfg, params, llama.read_out(x, logits_at),
                            lambda a, _spec: a)
-    return logits, pool, chosen.transpose(1, 2, 0, 3)
+    return logits, pool, (chosen.transpose(1, 2, 0, 3), computed)
 
 
 def verify_step_paged(cfg: DeepseekV3Config, params: Params,

@@ -266,6 +266,12 @@ _MOE_HIT = metrics.counter(
     "stpu_moe_experts_hit_total",
     "Held experts, summed over sparse layers, that at least one live "
     "slot's token of a decode step chose.")
+_MOE_COMPUTED = metrics.counter(
+    "stpu_moe_experts_computed_total",
+    "Held experts, summed over sparse layers, that a decode step's "
+    "program computed: what its expert loop ran over, read back "
+    "beside the tokens. Equals stpu_moe_experts_hit_total where the "
+    "program's decoding rows are the scheduler's.")
 _STEPS = metrics.counter(
     "stpu_engine_steps_total",
     "Device programs the engine loop dispatched, by kind: decode "
@@ -490,14 +496,15 @@ class _Unread:
     request, outcome or None) in emission order — row ``i`` of the
     vector is the request's next token, an outcome says that it is
     its last (the slot was retired when the program was dispatched),
-    and no index means no token, only the end (a cancel). ``chosen``
-    is a step's held-expert array beside the tokens (deepseek), ``t0``
-    a decode step's dispatch instant (None for a prefill chunk)."""
+    and no index means no token, only the end (a cancel). ``routing``
+    is a step's two held-expert arrays beside the tokens (deepseek:
+    chosen by each row, computed by the program), ``t0`` a decode
+    step's dispatch instant (None for a prefill chunk)."""
 
-    __slots__ = ("toks", "chosen", "rows", "t0")
+    __slots__ = ("toks", "routing", "rows", "t0")
 
-    def __init__(self, toks, rows, chosen=None, t0=None):
-        self.toks, self.chosen, self.t0 = toks, chosen, t0
+    def __init__(self, toks, rows, routing=None, t0=None):
+        self.toks, self.routing, self.t0 = toks, routing, t0
         self.rows = collections.deque(rows)
 
 
@@ -576,20 +583,23 @@ def _paged_step(cfg, params, cache, toks, pos, table, window, temps,
     """One decode step over ALL slots through their block tables: each
     slot's new K/V row scatters into block ``table[b, pos//bt]``, and
     attention gathers every slot's valid prefix through its table.
-    Free slots ride along with table row 0 (the scratch block) and are
-    ignored host-side. The pool is donated (in-place update).
+    Slots that do not decode ride along with a table row of zeros (the
+    scratch block; :meth:`DecodeEngine._step_table`) and are ignored
+    host-side. The pool is donated (in-place update).
 
     A family whose forward reports which held experts each token chose
-    (deepseek: a third result, (B, T, sparse layers, held) bool) gets
-    them back beside the tokens, ``((nxt, chosen), pool)``, so that the
-    step's one blocking fetch brings both."""
+    and which of them it computed (deepseek: a third result, (B, T,
+    sparse layers, held) and (sparse layers, held) bool) gets them
+    back beside the tokens, ``((nxt, (chosen, computed)), pool)``, so
+    that the step's one blocking fetch brings all three."""
     api = model_api(cfg)
-    logits, cache, *chosen = api.forward_with_paged_cache(
+    logits, cache, *routing = api.forward_with_paged_cache(
         cfg, params, toks[:, None], cache, table, pos, window=window)
     logits = logits[:, -1]
     nxt = _sample(logits, seeds, pos + 1, temps)
-    if chosen:
-        nxt = (nxt, chosen[0][:, 0])
+    if routing:
+        chosen, computed = routing[0]
+        nxt = (nxt, (chosen[:, 0], computed))
     return nxt, cache
 
 
@@ -1621,8 +1631,8 @@ class DecodeEngine:
                 self.prefix_cache.stats()["chunks"])
             _STATE_BLOCKS.labels(kind="free").set(free)
 
-    def _table_upload(self, i=slice(None)):
-        """The block table (or slot ``i``'s row) as a program's input:
+    def _table_upload(self, i: int):
+        """Slot ``i``'s row of the block table as a program's input:
         a COPY, because the upload may read the host's memory after
         the call returns (on the CPU the device array IS that memory)
         and the engine writes the table again — retires a slot, grows
@@ -1630,16 +1640,17 @@ class DecodeEngine:
         return jnp.asarray(self._table[i].copy())
 
     def _step_table(self, live: List[int]):
-        """The block table a decode step runs with. A family that
-        pages by the token takes it whole: rows of slots that do not
-        decode write a row nobody attends. A state family's step
-        REWRITES the block each row names, so only the decoding slots'
-        rows go in and every other row names the scratch block, which
-        the program skips (a slot in the middle of its prefill keeps
-        its state; free slots cost a step nothing)."""
-        if not self._seq_blocks:
-            return self._table_upload()
-        table = np.zeros_like(self._table)
+        """The block table a decode or verify step runs with: the
+        decoding slots' rows, and the scratch block (0) in every
+        other row. That is how a step's program knows which rows
+        decode, from an argument it already has. A state family's
+        step REWRITES the block each row names and skips the scratch
+        block (a slot in the middle of its prefill keeps its state;
+        free slots cost a step nothing); a family that pages by the
+        token writes a non-decoding row's unattended cache row into
+        the scratch block, not the slot's own, and deepseek's expert
+        layer computes no expert for it."""
+        table = np.zeros_like(self._table)    # a copy, as above
         table[live] = self._table[live]
         return jnp.asarray(table)
 
@@ -1822,16 +1833,18 @@ class DecodeEngine:
         if not due:
             return
         self._phase.enter("fetch")
-        fetched = jax.device_get([(e.toks, e.chosen) for e in due])
+        fetched = jax.device_get([(e.toks, e.routing) for e in due])
         now = self._phase.enter("emit")
-        for entry, (toks, chosen) in zip(due, fetched):
+        for entry, (toks, routing) in zip(due, fetched):
             rows = entry.rows
             if entry.t0 is not None:
                 live = [i for i, _, _ in rows if i is not None]
-                if chosen is not None:
+                if routing is not None:
+                    chosen, computed = routing
                     chosen = chosen[live]      # (live, layers, held)
                     _MOE_ROUTED.inc(int(chosen.sum()))
                     _MOE_HIT.inc(int(chosen.any(axis=0).sum()))
+                    _MOE_COMPUTED.inc(int(computed.sum()))
                 # A step's time is the interval between two reads:
                 # with the loop a step ahead, dispatch to read spans
                 # two.
@@ -1975,7 +1988,7 @@ class DecodeEngine:
         targets, accepts, self._toks, self._cache = _paged_spec_step(
             self._cfg, self._params, self._cache, self._toks,
             jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
-            self._table_upload(), self._window, temps, seeds)
+            self._step_table(live), self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, accepts)
         self._phase.enter("fetch")
@@ -2112,7 +2125,7 @@ class DecodeEngine:
             self._step_table(live), self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
-        self._toks, chosen = nxt if isinstance(nxt, tuple) \
+        self._toks, routing = nxt if isinstance(nxt, tuple) \
             else (nxt, None)
         rows = []
         for i in live:
@@ -2120,7 +2133,7 @@ class DecodeEngine:
             slot.pos += 1
             slot.generated += 1
             rows.append((i, slot.request, self._maybe_retire(i)))
-        self._fresh.append(_Unread(self._toks, rows, chosen, t0))
+        self._fresh.append(_Unread(self._toks, rows, routing, t0))
         self._land()
         self._behind, self._fresh = self._fresh, []
         return len(live)

@@ -3,8 +3,10 @@
 random weights: the full pass, chunked prefill and paged decode through
 the engine's own programs (which pins absorbed = expanded attention),
 the routing against a plain loop, the expert shares against the uncut
-layer, YaRN against its closed forms, what the latent pool costs, the
-routing counters, and what the family refuses."""
+layer, the expert loop (only the held experts a counted row chose)
+against the all-held-experts form, YaRN against its closed forms, what
+the latent pool costs, the routing counters, and what the family
+refuses."""
 import dataclasses
 import math
 
@@ -260,6 +262,144 @@ def test_shares_of_all_ranks_add_up_to_the_uncut_layer():
     assert float(jnp.abs(got - want).max()) < 1e-4
 
 
+# ------------------------------------------- the expert loop (PR 34)
+def _all_held(cfg, x, lp):
+    """moe_block as it was until PR 34: every held expert computed for
+    every token and weighted, zero where it was not chosen; one
+    product over (expert, column), rounded once."""
+    lo = cfg.ep_rank * cfg.n_experts_held
+    hi = lo + cfg.n_experts_held
+    y = deepseek.llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    logits = jnp.einsum("btd,de->bte", y.astype(jnp.float32),
+                        lp["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    w, chosen = deepseek.route(cfg, logits, lp["router_bias"])
+    w = w[..., lo:hi].astype(y.dtype)
+    gate = jax.nn.silu(jnp.einsum("btd,edm->btem", y, lp["we_gate"]))
+    up = jnp.einsum("btd,edm->btem", y, lp["we_up"])
+    routed = jnp.einsum("btem,emd->btd", gate * up * w[..., None],
+                        lp["we_down"])
+    shared = (jax.nn.silu(y @ lp["ws_gate"]) * (y @ lp["ws_up"])
+              ) @ lp["ws_down"]
+    return x + routed + shared, x + shared, chosen[..., lo:hi]
+
+
+def _sparse_layer(cfg, key=3):
+    stack = deepseek.init(cfg, jax.random.key(key))["moe_layers"]
+    return {k: v[1] for k, v in stack.items()}
+
+
+def _poisoned(lp, keep):
+    """``lp`` (one sparse layer, or the stack) with NaN in the three
+    matrices of every held expert that ``keep`` ((held,) or (layers,
+    held) bool) leaves out: a product that reads one of them shows
+    (NaN times a zero weight is NaN)."""
+    keep = jnp.asarray(keep)
+    out = dict(lp)
+    for name in deepseek._EXPERTS:
+        mask = keep.reshape(keep.shape + (1,) * (lp[name].ndim - keep.ndim))
+        out[name] = jnp.where(mask, lp[name], jnp.nan)
+    return out
+
+
+# 64 routed experts in 16 ranks of 4, top-8 of 4 groups of 8: a row
+# chooses a given held expert one time in eight, as the cell's 8 of 256
+# does one time in 32.
+_EP16 = dict(n_routed_experts=64, n_experts_held=4, ep_size=16,
+             n_group=8, topk_group=4, top_k=8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rank", [0, 15])
+@pytest.mark.parametrize("rows", ["all", "one", "none"])
+@pytest.mark.parametrize("shape", [(12, 1), (1, 64)])
+def test_expert_loop_equals_the_all_held_form_on_counted_rows(
+        dtype, rank, rows, shape):
+    """A decode step's twelve rows and a chunk's 64 with a padded tail
+    (40 real), every row counting, one, and none: each counted row's
+    result is the all-held-experts form's. In float32 to 1e-6 of the
+    largest value: the same products, summed over the experts in
+    another order. In bf16 to ONE bf16 step of the larger of the
+    routed part and the result (2^-7 of it), and to the bit in 99
+    values of 100 (on this CPU: in every value): both forms sum the
+    experts' float32 products in float32 and round once, so the sums
+    differ in their last float32 bits at most, which can move a value
+    that sits on a rounding boundary by one step. The
+    experts that no counted row chose are NaN: the loop never reads
+    them. With no row counted no expert runs: the routed part is zero
+    and the shared expert is still added."""
+    cfg = _tiny(dtype=dtype, ep_rank=rank, **_EP16)
+    lp = _sparse_layer(cfg)
+    b, t = shape
+    x = jax.random.normal(jax.random.key(5), (b, t, cfg.dim)
+                          ).astype(dtype)
+    with jax.default_matmul_precision("highest"):
+        want, unrouted, chosen = _all_held(cfg, x, lp)
+        real = jnp.arange(t)[None, :] < (40 if t > 1 else 1)
+        if rows == "all":
+            counts = jnp.broadcast_to(real, (b, t))   # not the padded tail
+        elif rows == "one":                # the first that asks for any
+            first = jnp.argmax((chosen.any(-1) & real).reshape(-1))
+            counts = jnp.zeros((b * t,), bool).at[first].set(True
+                                                             ).reshape(b, t)
+        else:
+            counts = jnp.zeros((b, t), bool)
+        asked = np.asarray(deepseek.experts_asked(chosen, counts))
+        got, got_chosen = jax.jit(
+            lambda x, lp, counts: deepseek.moe_block(cfg, x, lp, counts)
+        )(x, _poisoned(lp, asked), counts)
+    np.testing.assert_array_equal(got_chosen, chosen)
+    assert asked.any() == (rows != "none")
+    keep = np.asarray(counts)
+    got, want, unrouted = (np.asarray(a, np.float32)
+                           for a in (got, want, unrouted))
+    assert np.isfinite(got).all()
+    if rows == "none":
+        np.testing.assert_array_equal(got, unrouted)
+        return
+    got, want = got[keep], want[keep]
+    if dtype == jnp.float32:
+        assert np.abs(got - want).max() < 1e-6 * np.abs(want).max()
+        return
+    size = np.maximum(np.abs(want), np.abs(want - unrouted[keep]))
+    assert (np.abs(got - want) <= 2.0 ** -7 * size).all()
+    assert (got == want).mean() > 0.99
+
+
+def test_a_row_that_does_not_count_never_makes_an_expert_computed():
+    """Two rows of a decode step, each choosing held experts the other
+    does not; only row 0 counts (row 1's table names the scratch
+    block). The program computes exactly what row 0 chose, in every
+    sparse layer, row 0's logits are those of the step in which both
+    rows count, and the experts only row 1 asked for are NaN
+    throughout: none was read."""
+    cfg = _tiny()
+    params = deepseek.init(cfg, jax.random.key(0))
+    step = jax.jit(lambda params, toks, pool, table, pos:
+                   deepseek.forward_with_paged_cache(
+                       cfg, params, toks, pool, table, pos, window=16))
+    pool = deepseek.init_paged_cache(cfg, 8, 8)
+    both = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos = jnp.asarray([3, 5], jnp.int32)
+    for seed in range(40):
+        toks = jnp.asarray(_tokens(2, seed=seed))[:, None]
+        want, _, (chosen, computed) = step(params, toks, pool, both, pos)
+        chosen = np.asarray(chosen)[:, 0]             # (row, layer, held)
+        np.testing.assert_array_equal(computed, chosen.any(axis=0))
+        if (chosen[1] & ~chosen[0]).any() and chosen[0].any():
+            break
+    else:
+        raise AssertionError("no seed gave row 1 an expert of its own")
+    only_row0 = both.at[1].set(0)
+    poisoned = {**params, "moe_layers": _poisoned(params["moe_layers"],
+                                                  chosen[0])}
+    got, _, (got_chosen, computed) = step(poisoned, toks, pool, only_row0,
+                                          pos)
+    np.testing.assert_array_equal(np.asarray(got_chosen)[0, 0], chosen[0])
+    np.testing.assert_array_equal(computed, chosen[0])
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+
+
 # ----------------------------------------------------------------- YaRN
 def test_yarn_frequencies_and_softmax_scale_closed_forms():
     cfg = deepseek.DeepseekV3Config()
@@ -353,7 +493,13 @@ def test_routing_counters_move_by_what_the_batch_chose():
     """Three slots decode one step: the counters grow by the held
     experts the LIVE slots' tokens chose (pairs, and distinct experts a
     layer), as moe_block says for the same tokens; the free slot's ride
-    is not counted."""
+    is not counted, by the host (``chosen[live]``) nor by the program
+    (its table row names the scratch block):
+    ``stpu_moe_experts_computed_total``, what the program's expert loop
+    ran over, grows as ``stpu_moe_experts_hit_total`` does — in this
+    step, and over the rest of a served run in which a third request
+    prefills in four chunks beside the decoding two and slots retire
+    at different steps."""
     cfg = _tiny()
     params = deepseek.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=3, max_seq=64,
@@ -361,7 +507,7 @@ def test_routing_counters_move_by_what_the_batch_chose():
     try:
         for n in (5, 7):
             engine.submit(list(map(int, _tokens(n, seed=n))),
-                          max_tokens=4)
+                          max_tokens=4 + n)
         engine._admit()
         while any(s.request is not None and
                   s.prefilled < len(s.request.prompt)
@@ -374,13 +520,16 @@ def test_routing_counters_move_by_what_the_batch_chose():
         for i in live:
             engine._ensure_block(i, engine._slots[i].pos // 8)
         # What this step's forward chooses, on a copy of the pool.
-        _, _, chosen = deepseek.forward_with_paged_cache(
+        _, _, (chosen, computed) = deepseek.forward_with_paged_cache(
             cfg, params, toks[:, None],
             jax.tree.map(jnp.copy, engine._cache),
-            jnp.asarray(engine._table), pos, window=engine._window)
+            engine._step_table(live), pos, window=engine._window)
         chosen = np.asarray(chosen)[live, 0]      # (live, layers, held)
-        routed0 = _counter("stpu_moe_tokens_routed_total")
-        hit0 = _counter("stpu_moe_experts_hit_total")
+        np.testing.assert_array_equal(computed, chosen.any(axis=0))
+        names = ("stpu_moe_tokens_routed_total",
+                 "stpu_moe_experts_hit_total",
+                 "stpu_moe_experts_computed_total")
+        routed0, hit0, computed0 = map(_counter, names)
         assert engine._decode_step() == 2
         # The step's tokens and routing are read one iteration later,
         # and the step dispatched then is not in the counters yet.
@@ -390,7 +539,18 @@ def test_routing_counters_move_by_what_the_batch_chose():
             chosen.sum()
         assert _counter("stpu_moe_experts_hit_total") - hit0 == \
             chosen.any(axis=0).sum()
+        assert _counter("stpu_moe_experts_computed_total") - computed0 \
+            == chosen.any(axis=0).sum()
         assert chosen.sum() > 0
+        engine.submit(list(map(int, _tokens(30, seed=30))), max_tokens=6)
+        for _ in range(60):
+            engine._admit()
+            did = engine._prefill_one()
+            if not (engine._decode_step() or did):
+                break
+        assert not engine._behind and not engine._fresh
+        routed, hit, computed = map(_counter, names)
+        assert computed - computed0 == hit - hit0 > chosen.any(0).sum()
     finally:
         engine.shutdown()
 

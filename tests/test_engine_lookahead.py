@@ -14,16 +14,19 @@ when that step's tokens are READ, an iteration later. Held here:
   * ``stpu_engine_lookahead_steps_total`` says how often the loop ran
     ahead, and is 0 while a slot drafts;
   * shutdown, drain and a crash with results unread end every request
-    with its own outcome and leak no block.
+    with its own outcome and leak no block;
+  * a step's block table names the decoding slots' blocks and the
+    scratch block in every other row, for every family (PR 34).
 """
 import random
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from skypilot_tpu.models import deepseek, gemma, llama, mixtral
+from skypilot_tpu.models import brumby, deepseek, gemma, llama, mixtral
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.serve import decode_engine
 from skypilot_tpu.serve.decode_engine import DecodeEngine, EngineError
@@ -37,6 +40,8 @@ def _tiny(family="llama"):
         return gemma, gemma.GemmaConfig.tiny(vocab_size=128)
     if family == "deepseek":
         return deepseek, deepseek.DeepseekV3Config.tiny(vocab_size=128)
+    if family == "brumby":
+        return brumby, brumby.BrumbyConfig.tiny(vocab_size=128)
     return llama, llama.LlamaConfig.tiny(vocab_size=128)
 
 
@@ -499,3 +504,62 @@ def test_warmup_reaches_every_program_the_loop_dispatches():
             assert built() == before, int8
         finally:
             engine.shutdown()
+
+
+# ============================================== (f) the step's table
+@pytest.mark.parametrize("family", ["llama", "mixtral", "gemma",
+                                    "deepseek", "brumby"])
+def test_a_steps_table_names_only_the_decoding_slots(family,
+                                                     monkeypatch):
+    """Three slots: one decodes, one prefills a four-chunk prompt
+    beside it, one stays free. Every decode step's table holds the
+    decoding slots' own rows and names block 0, the scratch block, in
+    every other row — which is how a step's program knows the rows
+    that decode (deepseek's expert layer computes no expert for the
+    others; brumby's step leaves their state alone) — and the
+    prefilling slot's blocks hold after the step what they held
+    before it, leaf for leaf."""
+    _, cfg, _, engine = _engine(family, slots=3)
+    seen = []
+    step = decode_engine._paged_step
+
+    def spy(cfg, params, cache, toks, pos, table, *rest):
+        seen.append((np.asarray(table), engine._table.copy()))
+        return step(cfg, params, cache, toks, pos, table, *rest)
+
+    monkeypatch.setattr(decode_engine, "_paged_step", spy)
+    rng = random.Random(5)
+    draw = lambda n: [rng.randint(1, cfg.vocab_size - 1)
+                      for _ in range(n)]
+    reqs = [engine.submit(draw(5), max_tokens=12),
+            engine.submit(draw(30), max_tokens=3)]
+    beside_a_prefill = beside_a_free_slot = 0
+    for _ in range(100):
+        engine._admit()
+        did = engine._prefill_one()
+        slots = engine._slots
+        decoding = [i for i, s in enumerate(slots) if s.request
+                    and s.prefilled >= len(s.request.prompt)]
+        prefilling = [i for i, s in enumerate(slots) if s.request
+                      and 0 < s.prefilled < len(s.request.prompt)]
+        blocks = sorted({int(b) for i in prefilling
+                         for b in engine._table[i, :slots[i].blocks]})
+        before = jax.tree.map(lambda leaf: np.asarray(leaf[:, blocks]),
+                              engine._cache)
+        steps = len(seen)
+        did = engine._decode_step() or did
+        if len(seen) > steps:
+            table, own = seen[-1]      # own: grown for this step
+            others = [i for i in range(3) if i not in decoding]
+            np.testing.assert_array_equal(table[decoding], own[decoding])
+            assert (table[decoding, 0] != 0).all()
+            assert not table[others].any()
+            after = jax.tree.map(
+                lambda leaf: np.asarray(leaf[:, blocks]), engine._cache)
+            jax.tree.map(np.testing.assert_array_equal, after, before)
+            beside_a_prefill += bool(blocks)
+            beside_a_free_slot += any(s.request is None for s in slots)
+        if not did and not engine._waiting:
+            break
+    assert [len(r.result(timeout=5.0)) for r in reqs] == [12, 3]
+    assert beside_a_prefill >= 3 and beside_a_free_slot >= 3

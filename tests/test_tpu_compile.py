@@ -259,16 +259,13 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
         assert not copies, copies
 
 
-def _weight_shaped(text, layers):
-    """The instructions of a compiled program whose result has the
-    shape of ONE layer of a stacked weight matrix, ``[1, *leaf.shape[1:]]``
-    in any layout, and that are either a ``copy`` (anywhere) or stand
-    outside every fused computation: a layer's weight cut out of its
-    stack into a buffer of its own, or re-laid. A slice that is fused
-    into the product that reads it appears only inside that product's
+def _standing_alone(text, shapes):
+    """The instructions of a compiled program whose result has one of
+    ``shapes`` ("1,4096,4096", in any layout) and that are either a
+    ``copy`` (anywhere) or stand outside every fused computation: a
+    buffer of that shape of its own. A slice that is fused into the
+    product that reads it appears only inside that product's
     ``fused_computation``."""
-    shapes = {",".join(map(str, (1,) + leaf.shape[1:]))
-              for leaf in jax.tree.leaves(layers) if leaf.ndim >= 3}
     found, fused = [], False
     for line in text.splitlines():
         if line and not line[0].isspace():    # a computation's head, or }
@@ -280,6 +277,15 @@ def _weight_shaped(text, layers):
                                            or not fused):
             found.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
     return found
+
+
+def _weight_shaped(text, layers):
+    """:func:`_standing_alone` for ONE layer of each stacked weight
+    matrix, ``[1, *leaf.shape[1:]]``: a layer's weight cut out of its
+    stack into a buffer of its own, or re-laid."""
+    return _standing_alone(
+        text, {",".join(map(str, (1,) + leaf.shape[1:]))
+               for leaf in jax.tree.leaves(layers) if leaf.ndim >= 3})
 
 
 def test_weight_shaped_finds_a_cut_out_and_a_re_laid_weight():
@@ -334,6 +340,49 @@ def test_paged_programs_read_each_weight_in_place_on_v5e(
                                          weight_quant=weight_quant)
     found = _weight_shaped(compiled.as_text(), params["layers"])
     assert not found, found
+
+
+@pytest.mark.parametrize("entry", ["_paged_step", "_paged_prefill_chunk"])
+def test_expert_loop_reads_each_held_expert_in_place_on_v5e(
+        topo, for_the_chip, entry):
+    """DeepSeek-V3's step and chunk at the cell's shapes (64 slots,
+    four sparse layers of 16 held experts): the expert layer's loop
+    (PERF.md, PR 34) reads ``we_gate[layer, e]``, ``we_up[layer, e]``
+    and ``we_down[layer, e]`` where they lie in their (4, 16, ...)
+    stacks. No buffer has the shape of one expert's matrix, of a
+    layer's sixteen or of a whole stack outside the product that reads
+    it (cut out ahead of the loop, a layer's experts would be a copy
+    of 0.7 GB in every layer of every step), none is re-laid, every
+    fused computation that takes a stack is called from inside the
+    loop's body, the three products with it, and the latent pool is
+    still one buffer aliased in place."""
+    compiled, params, pool = _compile_paged(topo, "deepseek", entry)
+    text = compiled.as_text()
+    experts = {name: params["moe_layers"][name]
+               for name in deepseek._EXPERTS}
+    shapes = {",".join(map(str, shape))
+              for leaf in experts.values()
+              for shape in (leaf.shape, (1,) + leaf.shape[1:],
+                            leaf.shape[1:], (1, 1) + leaf.shape[2:],
+                            (1,) + leaf.shape[2:], leaf.shape[2:])}
+    found = [line for line in _standing_alone(text, shapes)
+             if " parameter " not in line
+             and " get-tuple-element " not in line]
+    assert not found, found
+    stacks = "|".join(re.escape(",".join(map(str, leaf.shape)))
+                      for leaf in experts.values())
+    readers = re.findall(
+        rf"^%(\S+) \([^)]*: bf16\[(?:{stacks})\]", text, re.M)
+    assert len(readers) >= 3, readers
+    for name in readers:
+        calls = [line for line in text.splitlines()
+                 if f"calls=%{name}," in line or f"calls=%{name} " in line]
+        assert calls and all("stpu.moe/while/body" in line
+                             for line in calls), (name, calls)
+    assert len(re.findall(r"stpu\.moe/while/body/dot_general", text)) >= 3
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
 
 @pytest.mark.parametrize("entry", ["_paged_step", "_paged_prefill_chunk"])
