@@ -30,8 +30,8 @@ What differs from llama.py, and where it lives:
     D)`` and ``z (layers, blocks, kv_heads, D)``, float32, ``D`` minor so
     both leaves lie in the TPU's natural layout), ``table[b, 0]`` names
     slot ``b``'s block, and the engine learns from
-    :func:`state_blocks_per_sequence` that a sequence costs a fixed
-    number of blocks which every step REWRITES;
+    :func:`pool_layout` that a sequence costs a fixed number of blocks
+    which every step REWRITES;
   * two forms pinned to one reference (benchmarks/reference/
     brumby_arch.py, the attention form): a prefill chunk runs the chunk
     form (quadratic inside its 64 tokens, the state across chunks;
@@ -299,13 +299,14 @@ def _chunk(cfg: BrumbyConfig, q, k, v, log_gate, keep, state, norm):
 
 
 # ------------------------------------------------------- the state pool
-def state_blocks_per_sequence(cfg: BrumbyConfig) -> int:
+def pool_layout(cfg: BrumbyConfig) -> Dict[str, Any]:
     """What the engine asks a family once (serve/kv_pool.py:
-    ``blocks_per_sequence``): a sequence holds this many pool blocks
-    whatever its length, every step rewrites them, so none is ever
-    shared between a live slot and the prefix trie. A family without
-    this function pages by the token."""
-    return 1
+    ``pool_layout``, the fields of its ``PoolLayout``): a sequence holds
+    one state block whatever its length and no blocks of tokens; every
+    step rewrites the block, so none is ever shared between a live slot
+    and the prefix trie. A family without this function pages by the
+    token."""
+    return {"tokens": False, "state_blocks": 1}
 
 
 def refuse_engine_options(cfg: BrumbyConfig, *, spec_k: int,

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from skypilot_tpu.agent import constants as agent_constants
 from skypilot_tpu.models import (brumby, deepseek, family_name, gemma,
-                                 llama, mixtral, model_api)
+                                 llama, mixtral, model_api, phi4flash)
 from skypilot_tpu.observability import metrics
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
@@ -812,6 +812,8 @@ def model_config(model: str, dtype: str = None):
         "deepseek-v3-5l-ep16": deepseek.DeepseekV3Config.v3_5l_ep16,
         "brumby-tiny": brumby.BrumbyConfig.tiny,
         "brumby-14b-6l": brumby.BrumbyConfig.b14_6l,
+        "phi4flash-tiny": phi4flash.Phi4FlashConfig.tiny,
+        "phi-4-mini-flash": phi4flash.Phi4FlashConfig.mini_flash,
     }[model]()
     if dtype:
         cfg = dataclasses.replace(
@@ -878,7 +880,8 @@ def main(argv=None):
                    choices=["tiny", "8b", "mixtral-tiny", "mixtral-8x7b",
                             "gemma-tiny", "gemma-2b", "gemma-7b",
                             "deepseek-tiny", "deepseek-v3-5l-ep16",
-                            "brumby-tiny", "brumby-14b-6l"],
+                            "brumby-tiny", "brumby-14b-6l",
+                            "phi4flash-tiny", "phi-4-mini-flash"],
                    default="tiny")
     p.add_argument("--port", type=int, default=8080)
     p.add_argument("--seed", type=int, default=0)
@@ -1014,7 +1017,7 @@ def main(argv=None):
         host_cache_mb=kv["prefix_cache_mb"],
         family=family_name(cfg),
         tp=(mesh.devices.size if mesh is not None else 1),
-        seq_blocks=kv_pool.blocks_per_sequence(cfg))
+        layout=kv_pool.pool_layout(cfg))
     if topology.hosts > 1 and rank > 0:
         # Non-zero hosts never front HTTP: they run the lockstep
         # follower loop against the leader's gang channel, mirroring

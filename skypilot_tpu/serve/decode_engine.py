@@ -28,12 +28,16 @@ and runs one jitted decode step over all slots every iteration:
     refcount transfer. At least one trailing prompt token is always
     prefilled so the first token is sampled from real logits.
     ``prefix_cache_mb`` sizes the host-RAM spill tier under the trie;
-  * a family whose block is a sequence's whole recurrent STATE
-    (kv_pool.blocks_per_sequence, models/brumby.py) changes the
-    accounting and nothing else: a slot holds a fixed number of blocks
-    that every step rewrites, admission reserves that, nothing grows,
-    and a prefix hit restores from a SNAPSHOT, copy-on-write
-    (:meth:`DecodeEngine._chunk_target`);
+  * what a slot's memory is made of is the family's to say
+    (kv_pool.pool_layout): blocks of tokens as above; blocks of tokens
+    of which only the newest window's are kept, released BEHIND the
+    sequence (:meth:`DecodeEngine._release_behind`); and a block that
+    is a sequence's whole recurrent STATE, which every step rewrites,
+    so that admission reserves a fixed number, nothing grows, and a
+    prefix hit restores from a SNAPSHOT, copy-on-write
+    (:meth:`DecodeEngine._chunk_target`). A slot may hold all three at
+    once (models/phi4flash.py), each kind from a pool of its own, all
+    of them named by the slot's one table row;
   * the pool is DONATED through all three jitted programs (prefill
     chunk, decode step, verify step), so the engine keeps one buffer
     from step to step. Donation names the buffer the result lands in,
@@ -189,6 +193,18 @@ _STATE_BLOCKS = metrics.gauge(
     "State pool blocks by holder (state families only): slot = a live "
     "sequence's own state, snapshot = a prefix-trie node, free.",
     ("kind",))
+_CACHE_BLOCKS = metrics.gauge(
+    "stpu_engine_cache_blocks",
+    "Pool blocks in use by kind: global / window = distinct blocks of "
+    "tokens that live slots' tables name (the full layers', the window "
+    "layers'), state = live sequences' own state blocks, snapshot = "
+    "prefix-trie nodes of a family with a state (each holds one block "
+    "of every kind).", ("kind",))
+_WINDOW_RELEASED = metrics.counter(
+    "stpu_engine_window_blocks_released_total",
+    "Window-layer blocks a live sequence gave back because it had "
+    "moved past them (a refcount drop: the prefix trie may still hold "
+    "the block).")
 _ZERO_COPY_HITS = metrics.counter(
     "stpu_engine_prefix_zero_copy_hits_total",
     "Prefix-cache hits served by aliasing pool blocks into the "
@@ -462,6 +478,8 @@ class _Slot:
 
     __slots__ = ("request", "pos", "generated", "prefilled",
                  "held", "cached", "blocks", "reserved", "pending",
+                 "win_lo", "win_own", "win_reserved", "own_state",
+                 "state_reserved",
                  "history", "ngram_index", "drafted", "accepted",
                  "spec_off")
 
@@ -472,8 +490,20 @@ class _Slot:
         self.prefilled = 0    # prompt tokens already prefilled
         self.held: List[Any] = []           # pinned prefix-pool nodes
         self.cached = 0       # prompt tokens restored from the pool
-        self.blocks = 0       # valid block-table entries
-        self.reserved = 0     # blocks still promised, unclaimed
+        self.blocks = 0       # valid token-block table entries
+        self.reserved = 0     # token blocks still promised, unclaimed
+        # Window kind: the lowest chunk index whose block is still held
+        # (held: [win_lo, blocks)), which of those count against the
+        # slot's own budget (not aliased, not adopted by the trie), and
+        # the budget still unclaimed.
+        self.win_lo = 0
+        self.win_own: set = set()
+        self.win_reserved = 0
+        # State kind: whether table column 0 names a block of the
+        # slot's own (else a snapshot or the scratch block), and the
+        # reservation for it.
+        self.own_state = False
+        self.state_reserved = 0
         # Host-tier re-admits this slot still owes: (logical chunk
         # index, trie node, fetched host payload) in chunk order,
         # consumed one per engine iteration by _restore_one.
@@ -716,7 +746,7 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
                         host_cache_mb: float = 0.0,
                         family: Optional[str] = None, tp: int = 1,
                         use_manifest: bool = True,
-                        seq_blocks: int = 0
+                        layout: Optional[kv_pool.PoolLayout] = None
                         ) -> Dict[str, Any]:
     """EFFECTIVE KV-cache geometry for an engine config — the single
     derivation DecodeEngine.__init__, kv_config() and the gang
@@ -750,19 +780,23 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     like kv/quant drifts.
 
     What the keys mean. ``chunk`` is the prefill chunk and the prefix
-    trie's key unit, in tokens, for every family. For a family that
-    caches per token (``seq_blocks`` 0) it is also a block's size,
-    ``pool_blocks`` auto-sizes to ``slots * max_seq / chunk + 1``
-    (every slot at full length, plus scratch) and ``table_len`` is how
-    many blocks one slot's table can name. For a family whose block is
-    a sequence's whole state (``seq_blocks`` = kv_pool.
-    blocks_per_sequence(cfg) > 0) a block holds no tokens at all:
-    ``pool_blocks`` auto-sizes to ``slots * seq_blocks +
-    snapshot_blocks + 1`` — every slot's own state, ``snapshot_blocks``
-    = max(2, 3 * slots // 4) blocks for prefix snapshots and for the
-    fresh block a chunk writes while the one it read becomes a
-    snapshot, and scratch — ``table_len`` is ``seq_blocks``, and a
-    slot's token limit is ``max_seq``, not its table's span."""
+    trie's key unit, in tokens, for every family. ``layout`` is the
+    family's kv_pool.PoolLayout (default: blocks of tokens alone) and
+    ``pools`` the blocks of each kind it names, scratch included;
+    ``pool_blocks`` is the first kind's. For blocks of tokens
+    (``global``) ``chunk`` is also a block's size and the kind
+    auto-sizes to ``slots * max_seq / chunk + 1`` (every slot at full
+    length, plus scratch); where that is the only kind ``table_len`` is
+    how many blocks one slot's table can name. A ``window`` kind
+    auto-sizes to ``slots * layout.window_blocks(chunk)``. A ``state``
+    kind (``seq_blocks`` = ``layout.state_blocks`` > 0) holds no tokens
+    at all and auto-sizes to ``slots * seq_blocks``; every kind of a
+    family with a state gains ``snapshot_blocks`` = max(2, 3 * slots //
+    4) blocks for the prefix trie's nodes (and for the fresh block a
+    chunk writes while the one it read becomes a snapshot), and a
+    slot's token limit is then ``max_seq``, not its table's span.
+    ``table_len`` counts the state's column, then ``max_seq / chunk``
+    columns for each kind of token block the family has beside it."""
     max_seq = int(max_seq)
     manifest_tag = "default"
     if use_manifest and family:
@@ -795,11 +829,17 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
     # Auto sizing: slots * max_seq tokens of bf16 KV plus the scratch
     # block. An int8 block (codes + one f32 scale per layer/head) is
     # ~half the bytes, so the same budget holds 2x the blocks.
-    seq_blocks = int(seq_blocks)
+    layout = layout or kv_pool.PoolLayout()
+    seq_blocks = layout.state_blocks
     snapshot_blocks = max(2, 3 * int(slots) // 4) if seq_blocks else 0
-    per_slot = seq_blocks or (2 if kv_quant else 1) * (max_seq // chunk)
-    total = int(kv_pool_blocks) or (
-        int(slots) * per_slot + snapshot_blocks + 1)
+    span = max_seq // chunk
+    per_slot = {"global": (2 if kv_quant else 1) * span,
+                "window": min(span, layout.window_blocks(chunk)),
+                "state": seq_blocks}
+    pools = {kind: int(slots) * per_slot[kind] + snapshot_blocks + 1
+             for kind in layout.kinds()}
+    first = layout.kinds()[0]
+    total = pools[first] = int(kv_pool_blocks) or pools[first]
     if window_blocks:
         window = max(min(int(window_blocks) * chunk,
                          max_seq // chunk * chunk), chunk)
@@ -823,9 +863,10 @@ def resolve_kv_geometry(*, slots: int, max_seq: int,
         "spec_k": int(spec_k), "spec_ngram": int(spec_ngram),
         "spec_min_accept": float(spec_min_accept),
         "pool_blocks": total, "window": window,
-        "table_len": seq_blocks or -(-(total - 1) // nbw) * nbw,
+        "table_len": (seq_blocks + (len(pools) - 1) * span if seq_blocks
+                      else -(-(total - 1) // nbw) * nbw),
         "seq_blocks": seq_blocks, "snapshot_blocks": snapshot_blocks,
-        "host_mb": float(host_cache_mb)}
+        "pools": pools, "host_mb": float(host_cache_mb)}
 
 
 class DecodeEngine:
@@ -868,14 +909,9 @@ class DecodeEngine:
             raise ValueError("spec_ngram must be >= 1")
         self._cfg = cfg
         self._api = model_api(cfg)
-        # The one question about the pool's accounting, asked once: 0 =
-        # blocks of tokens, appended and aliased; n = a sequence holds n
-        # blocks that every step rewrites (kv_pool.blocks_per_sequence).
-        self._seq_blocks = kv_pool.blocks_per_sequence(cfg)
-        if self._seq_blocks > 1:
-            raise NotImplementedError(
-                "a sequence state of more than one pool block has no "
-                "program yet (table[b, 0] names the one)")
+        # The one question about the pool's accounting, asked once:
+        # which kinds of block a slot holds (kv_pool.PoolLayout).
+        self._layout = kv_pool.pool_layout(cfg)
         # int8 weight serving: quantize here (idempotent — params may
         # arrive pre-quantized from a checkpoint) and, under a mesh,
         # re-place by the QUANTIZED spec tree so codes shard like the
@@ -927,7 +963,7 @@ class DecodeEngine:
             host_cache_mb=host_mb,
             family=family_name(cfg),
             tp=(mesh.devices.size if mesh is not None else 1),
-            use_manifest=use_manifest, seq_blocks=self._seq_blocks)
+            use_manifest=use_manifest, layout=self._layout)
         self._kv_geometry = geo
         chunk = geo["chunk"]
         self._chunk = chunk
@@ -951,17 +987,35 @@ class DecodeEngine:
         # ONE device-resident pool for slot growth AND the prefix
         # cache (serve/kv_pool.py), sized and tiled by
         # resolve_kv_geometry.
-        total = geo["pool_blocks"]
-        self._pool = kv_pool.BlockPool(total, chunk, self._seq_blocks)
+        # ... one BlockPool a kind of block the layout names, each
+        # with block ids of its own. ``_pool`` is the first kind's: the
+        # one the prefix trie's ``node.block`` lives in.
+        layout = self._layout
+        self._win_blocks = layout.window_blocks(chunk)
+        self._pools = {
+            kind: kv_pool.BlockPool(
+                n, chunk,
+                seq_blocks=layout.state_blocks if kind == "state" else 0)
+            for kind, n in geo["pools"].items()}
+        kinds = layout.kinds()
+        self._pool = self._pools[kinds[0]]
+        self._window_pool = self._pools.get("window")
+        self._state_pool = self._pools.get("state")
         self._window = geo["window"]    # attention tile, whole blocks
         # Per-slot LOGICAL capacity is the pool, not a row: the table
         # can address every usable block (rounded up so the last
-        # attention tile's table slice stays in bounds).
+        # attention tile's table slice stays in bounds). One row names
+        # all of a slot's blocks: the state's in column 0 where there
+        # is one, then the token blocks by chunk index from ``_tok0``,
+        # then the window's from ``_win0``.
         self._table_len = geo["table_len"]
         self._table = np.zeros((slots, self._table_len), np.int32)
+        self._tok0 = layout.state_blocks
+        self._win0 = self._tok0 + int(max_seq) // chunk
         make_cache = functools.partial(
-            self._api.init_paged_cache, cfg, total, chunk,
-            quantized=self._kv_quant)
+            self._api.init_paged_cache, cfg,
+            geo["pool_blocks"] if len(kinds) == 1 else geo["pools"],
+            chunk, quantized=self._kv_quant)
         # Host-RAM spill tier under the trie: evictions demote blocks
         # D2H through a bounded queue drained off the compute thread;
         # warm matches re-admit H2D during the prefill phase
@@ -980,7 +1034,8 @@ class DecodeEngine:
         self.prefix_cache = kv_pool.PagedPrefixCache(
             self._pool, chunk, host_pool=self._host_pool,
             spill=(self._spill_block
-                   if self._host_pool is not None else None))
+                   if self._host_pool is not None else None),
+            extra_pools={k: self._pools[k] for k in kinds[1:]})
         _KV_POOL_TOTAL.set(self._pool.usable_blocks)
         _KV_POOL_FREE.set(self._pool.free_blocks())
         if mesh is None:
@@ -1020,7 +1075,7 @@ class DecodeEngine:
         self._retiring = 0
         # A slot whose next write would be the last position ends: its
         # table's span, or (a state holds no positions) max_seq.
-        self._limit = (int(max_seq) if self._seq_blocks
+        self._limit = (int(max_seq) if layout.state_blocks
                        else self._table_len * chunk)
         _KV_POOL_BLOCK_BYTES.set(kv_pool.block_bytes_for(
             cfg, chunk, quantized=self._kv_quant))
@@ -1081,7 +1136,7 @@ class DecodeEngine:
                 f"({req.max_tokens}) exceeds the KV pool "
                 f"({self._pool.usable_blocks} blocks x "
                 f"{self._chunk} tokens)")
-        if self._seq_blocks and \
+        if self._layout.state_blocks and \
                 len(req.prompt) + req.max_tokens > self._limit:
             raise EngineError(
                 f"prompt ({len(req.prompt)}) + max_tokens "
@@ -1133,9 +1188,10 @@ class DecodeEngine:
         across hosts. serve_llm derives the same dict via
         resolve_kv_geometry for the welcome handshake, and that
         function's docstring says what ``pool_blocks``, ``table_len``
-        and ``chunk`` mean for a family whose block is a sequence's
-        state (``seq_blocks`` > 0; ``snapshot_blocks`` is the rule's
-        share for prefix snapshots)."""
+        and ``chunk`` mean for a family whose pool has a sequence's
+        state in it (``seq_blocks`` > 0; ``snapshot_blocks`` is the
+        rule's share for prefix snapshots) or more than one kind of
+        block (``pools``)."""
         return dict(self._kv_geometry)
 
     def cache_bytes_per_device(self) -> Dict[int, int]:
@@ -1262,10 +1318,11 @@ class DecodeEngine:
         a gather. Zero device work, zero host copies. The final
         partial prompt block (prompt tail + generated tokens share it)
         is never published."""
-        if self._seq_blocks:
+        if self._layout.state_blocks:
             # A state block holds the state after everything the slot
             # has seen, not after a prompt chunk: its snapshots were
-            # taken as it prefilled (_chunk_target).
+            # taken as it prefilled, with the token blocks that belong
+            # to them (_chunk_target).
             return
         slot = self._slots[i]
         self.prefix_cache.publish(
@@ -1273,13 +1330,16 @@ class DecodeEngine:
             lambda j: int(self._table[i, j]))
 
     def _release_paged(self, i: int) -> None:
-        """Return every pool reference the slot holds: unpin aliased
-        prefix blocks (table[0:len(held)]), release fresh blocks
-        (table[len(held):blocks]), hand back unused reservation.
+        """Return every pool reference the slot holds, of every kind:
+        unpin aliased prefix nodes (their token blocks are the table's
+        first len(held)), release fresh token blocks (the table's
+        [len(held), blocks)), the window blocks still held and the
+        slot's own state block, hand back unused reservations.
         Idempotent at the slot level — held/blocks/reserved are
         cleared, so a second call is a no-op instead of a
         double-decrement."""
         slot = self._slots[i]
+        lay, tok0 = self._layout, self._tok0
         if slot.pending:
             # Pending re-admits never took pool references — drop the
             # trie pins only (cancel / error before their restore ran;
@@ -1287,14 +1347,28 @@ class DecodeEngine:
             self.prefix_cache.unpin_pending(
                 [n for _, n, _ in slot.pending])
             slot.pending = []
-        aliased = len(slot.held)
+        # A state alone pins only the snapshot it starts from.
+        aliased = len(slot.held) if lay.tokens else 0
         if slot.held:
             self.prefix_cache.unpin(slot.held)
             slot.held = []
         for j in range(aliased, slot.blocks):
-            self._pool.release(int(self._table[i, j]))
-        if slot.blocks or aliased:
-            self._table[i, :max(slot.blocks, aliased)] = 0
+            self._pool.release(int(self._table[i, tok0 + j]))
+        if lay.window:
+            # Its own and the aliased alike: admission retained those.
+            for j in range(slot.win_lo, slot.blocks):
+                self._window_pool.release(
+                    int(self._table[i, self._win0 + j]))
+            self._window_pool.unreserve(slot.win_reserved)
+            slot.win_lo = slot.win_reserved = 0
+            slot.win_own = set()
+        if lay.state_blocks:
+            if slot.own_state:
+                self._state_pool.release(int(self._table[i, 0]))
+                slot.own_state = False
+            self._state_pool.unreserve(slot.state_reserved)
+            slot.state_reserved = 0
+        self._table[i] = 0
         slot.blocks = 0
         if slot.reserved:
             self._pool.unreserve(slot.reserved)
@@ -1405,16 +1479,25 @@ class DecodeEngine:
     def _try_admit_paged(self, i: int, req: Request) -> bool:
         """Reservation-based paged admission (compute thread): alias
         the longest cached prefix into the slot's block table (pin —
-        the zero-copy hit), then reserve every block the request can
-        ever need, evicting LRU unpinned trie leaves to make room.
-        False = head-of-line backpressure: the request stays at the
-        queue head until slot frees / evictions make it fit —
-        deterministic and preemption-free (an admitted request can
-        never lose a block, so nothing decoding is ever rolled back).
-        """
+        the zero-copy hit), then reserve every block of every kind the
+        request can ever need, evicting LRU unpinned trie leaves to
+        make room. False = head-of-line backpressure: the request
+        stays at the queue head until slot frees / evictions make it
+        fit — deterministic and preemption-free (an admitted request
+        can never lose a block, so nothing decoding is ever rolled
+        back).
+
+        By kind (kv_pool.PoolLayout): token blocks of the ``k``
+        matched chunks are aliased and the rest, to the request's
+        longest, reserved; of a window kind the last ``window_blocks -
+        1`` matched are aliased (what the next token's window still
+        reaches) and as many reserved as the slot can hold of its own
+        at once; a state reserves its fixed count and the slot's first
+        chunk READS the deepest matched node's snapshot
+        (:meth:`_chunk_target`). A node holds a block of every kind,
+        so a hit restores all of them to one chunk boundary."""
+        lay = self._layout
         nodes = self.prefix_cache.match(req.prompt)
-        if self._seq_blocks:
-            return self._admit_state(i, req, nodes)
         # Split the match by residency: a device-resident prefix (the
         # zero-copy alias) followed by a host-resident suffix to
         # re-admit H2D. Payloads are fetched NOW — holding the host
@@ -1432,31 +1515,56 @@ class DecodeEngine:
                 pending.append((node, payload))
             else:
                 break
-        self.prefix_cache.pin(dev_nodes)
+        # With no token blocks to alias, the DEEPEST node alone is
+        # pinned, until its snapshot is read; the nodes above it are
+        # kept by the trie's leaf-only eviction, as any interior node.
+        held = dev_nodes if lay.tokens else dev_nodes[-1:]
+        self.prefix_cache.pin(held)
         pend_nodes = [n for n, _ in pending]
         self.prefix_cache.pin_pending(pend_nodes)
-        total = self._pool.blocks_for(len(req.prompt) + req.max_tokens)
         # Host re-admits draw FRESH blocks, budgeted like any other
         # un-cached chunk (same worst-case reservation); the restore
         # itself runs off the hot path in the prefill-phase interleave.
-        needed = total - len(dev_nodes)
-        while self._pool.available() < needed:
-            evicted = self.prefix_cache.evict_one()
-            if not evicted:
-                self.prefix_cache.unpin(dev_nodes)
+        tokens = len(req.prompt) + req.max_tokens
+        hit = len(dev_nodes)
+        fresh = self._pool.blocks_for(tokens) - hit if lay.tokens else 0
+        needed = {"global": fresh, "window": min(fresh, self._win_blocks),
+                  "state": lay.state_blocks}
+        while any(pool.available() < needed[kind]
+                  for kind, pool in self._pools.items()):
+            if not self._evict_leaf():
+                self.prefix_cache.unpin(held)
                 self.prefix_cache.unpin_pending(pend_nodes)
                 return False
-        self._pool.reserve(needed)
+        for kind, pool in self._pools.items():
+            pool.reserve(needed[kind])
         slot = self._slots[i]
         slot.request = req
-        slot.held = dev_nodes
-        slot.pending = [(len(dev_nodes) + j, node, payload)
+        slot.held = held
+        slot.pending = [(hit + j, node, payload)
                         for j, (node, payload) in enumerate(pending)]
-        for j, node in enumerate(dev_nodes):
-            self._table[i, j] = node.block
-        slot.blocks = len(dev_nodes)
-        slot.reserved = needed
-        slot.cached = len(dev_nodes) * self._chunk
+        slot.blocks = 0
+        if lay.tokens:
+            for j, node in enumerate(dev_nodes):
+                self._table[i, self._tok0 + j] = node.block
+            slot.blocks = hit
+            slot.reserved = needed["global"]
+        if lay.window:
+            slot.win_lo = max(0, hit - (self._win_blocks - 1))
+            for j in range(slot.win_lo, hit):
+                block = dev_nodes[j].extra["window"]
+                self._window_pool.retain(block)
+                self._table[i, self._win0 + j] = block
+            slot.win_own = set()
+            slot.win_reserved = needed["window"]
+        if lay.state_blocks:
+            slot.own_state = False
+            slot.state_reserved = needed["state"]
+            # The deepest node's snapshot: its block of the state kind,
+            # which is the node's one block where a state is all.
+            self._table[i, 0] = dev_nodes[-1].extra.get(
+                "state", dev_nodes[-1].block) if hit else 0
+        slot.cached = hit * self._chunk
         # The device-resident "restore" is already done: the aliased
         # blocks ARE the prefilled prefix. Host-resident chunks join
         # the frontier one _restore_one at a time; prefill resumes
@@ -1464,11 +1572,15 @@ class DecodeEngine:
         slot.prefilled = slot.pos = slot.cached
         slot.generated = 0
         req.cached_prompt_tokens = slot.cached
-        self.prefix_cache.note_result(len(dev_nodes) + len(pending))
-        if dev_nodes:
+        # A hit restored from a snapshot alone aliases nothing.
+        self.prefix_cache.note_result(hit + len(pending),
+                                      zero_copy=lay.tokens)
+        if dev_nodes and lay.tokens:
             _ZERO_COPY_HITS.inc()
+        if dev_nodes and lay.state_blocks:
+            _STATE_SNAPSHOTS.labels(event="restored").inc()
         self._count_admission(
-            req, len(dev_nodes) + len(pending),
+            req, hit + len(pending),
             "host" if pending else "hbm" if dev_nodes else "miss")
         return True
 
@@ -1485,90 +1597,104 @@ class DecodeEngine:
         if reqlog.ENABLED:
             req.kv_tier = tier
 
-    def _admit_state(self, i: int, req: Request, nodes: List[Any]
-                     ) -> bool:
-        """:meth:`_try_admit_paged` for a family whose block is a
-        sequence's whole state: the reservation is the fixed
-        ``seq_blocks`` whatever the request's length, and a hit pins
-        the DEEPEST matching node alone — its block holds the state
-        after all the matched chunks, which the slot's first own chunk
-        reads (``table[i, 0]``) and never writes: it writes the slot's
-        own block (:meth:`_chunk_target`). The nodes above it are kept
-        by the trie's leaf-only eviction, as any interior node is."""
-        held = nodes[-1:]
-        self.prefix_cache.pin(held)
-        while self._pool.available() < self._seq_blocks:
-            if not self._evict_snapshot():
-                self.prefix_cache.unpin(held)
-                return False
-        self._pool.reserve(self._seq_blocks)
-        slot = self._slots[i]
-        slot.request = req
-        slot.held = held
-        slot.pending = []
-        slot.blocks = 0
-        self._table[i, 0] = held[0].block if held else 0
-        slot.reserved = self._seq_blocks
-        slot.cached = len(nodes) * self._chunk
-        slot.prefilled = slot.pos = slot.cached
-        slot.generated = 0
-        req.cached_prompt_tokens = slot.cached
-        self.prefix_cache.note_result(len(nodes), zero_copy=False)
-        if nodes:
-            _STATE_SNAPSHOTS.labels(event="restored").inc()
-        self._count_admission(req, len(nodes),
-                              "hbm" if nodes else "miss")
-        return True
-
-    def _evict_snapshot(self) -> bool:
-        """LRU-evict one unpinned snapshot leaf (state families)."""
-        if not self.prefix_cache.evict_one():
-            return False
-        _STATE_SNAPSHOTS.labels(event="evicted").inc()
-        return True
+    def _evict_leaf(self):
+        """LRU-evict one unpinned trie leaf; what ``evict_one`` says."""
+        evicted = self.prefix_cache.evict_one()
+        if evicted and self._layout.state_blocks:
+            _STATE_SNAPSHOTS.labels(event="evicted").inc()
+        return evicted
 
     def _chunk_target(self, i: int, start: int) -> int:
-        """The block a state family's prefill chunk writes, the chunk
-        at ``start`` of slot ``i``'s prompt; ``table[i, 0]`` names the
+        """The STATE block a prefill chunk writes, the chunk at
+        ``start`` of slot ``i``'s prompt; ``table[i, 0]`` names the
         block it reads. Copy-on-write, one chunk at a time:
 
         * the slot's FIRST chunk reads what admission put there — a
-          pinned snapshot, or the scratch block's zero state — and
-          writes the slot's own block, drawn from its reservation; the
-          snapshot is unpinned and never written;
+          matched node's snapshot, or the scratch block's zero state —
+          and writes the slot's own block, drawn from its reservation;
+          the snapshot is never written (a family with no token blocks
+          pinned its node for this read alone and unpins it here);
         * a later chunk reads the slot's own block, which holds the
           state after the ``start // chunk`` full chunks before it. If
-          a spare block is free (or an unpinned snapshot leaf can be
+          a spare block is free (or an unpinned trie leaf can be
           evicted for one), the chunk writes THAT and the block it
           read becomes the trie's node for those chunks — a snapshot
-          taken with no copy — or frees if the node is there already.
-          With no spare block the chunk rewrites the slot's block in
-          place and no snapshot is taken: a full pool costs prefix
-          reuse, never a request.
+          taken with no copy, together with the last of those chunks'
+          token blocks where the family has them — or frees if the
+          node is there already. With no spare block the chunk
+          rewrites the slot's block in place and no snapshot is taken:
+          a full pool costs prefix reuse, never a request.
+
+        A window block the trie adopts stops counting against the
+        slot's budget (the slot will give it back before its end and
+        the trie will not), so a snapshot also needs one window block
+        free, to promise the slot in its place.
 
         The caller uploads the table row (the block to READ) before it
         points ``table[i, 0]`` at the block returned here. Programs run
         in dispatch order, so a block is rewritten only after whatever
         read it last."""
-        slot = self._slots[i]
-        if not slot.blocks:
-            block = self._pool.alloc()
-            slot.reserved -= 1
-            slot.blocks = 1
-            if slot.held:
+        slot, lay, pool = self._slots[i], self._layout, self._state_pool
+        if not slot.own_state:
+            block = pool.alloc()
+            slot.state_reserved -= 1
+            slot.own_state = True
+            if slot.held and not lay.tokens:
                 self.prefix_cache.unpin(slot.held)
                 slot.held = []
             return block
         read = int(self._table[i, 0])
-        while self._pool.available() < 1:
-            if not self._evict_snapshot():
+        spare = [pool] + ([self._window_pool] if lay.window else [])
+        while any(p.available() < 1 for p in spare):
+            if not self._evict_leaf():
                 return read
-        block = self._pool.alloc(reserved=False)
-        if self.prefix_cache.publish_snapshot(
-                slot.request.prompt, start // self._chunk, read):
+        block = pool.alloc(reserved=False)
+        done = start // self._chunk
+        if not lay.tokens:
+            taken = self.prefix_cache.publish_snapshot(
+                slot.request.prompt, done, read)
+        else:
+            extra = {"state": read}
+            if lay.window:
+                extra["window"] = int(self._table[i, self._win0 + done - 1])
+            taken = self.prefix_cache.publish_snapshot(
+                slot.request.prompt, done,
+                int(self._table[i, self._tok0 + done - 1]), extra)
+            if taken:
+                self._window_budget_back(slot, done - 1)
+        if taken:
             _STATE_SNAPSHOTS.labels(event="taken").inc()
-        self._pool.release(read)
+        pool.release(read)
         return block
+
+    def _release_behind(self, i: int) -> None:
+        """Give back the window blocks slot ``i`` has moved past: a
+        block whose last row is older than the window of the NEXT token
+        (``slot.pos``) is read by no program dispatched from here on.
+        Called where a retired slot's blocks are returned, right after
+        a dispatch: programs run in dispatch order, so a step that is
+        dispatched and not yet read still finds the block as it was,
+        and whoever is handed the block writes it only afterwards. A
+        refcount drop: the trie may still hold the block for a shared
+        prefix. One of the slot's own goes back to its budget."""
+        slot = self._slots[i]
+        first = max(slot.pos - self._layout.window + 1, 0) // self._chunk
+        while slot.win_lo < min(first, slot.blocks):
+            j = slot.win_lo
+            self._window_pool.release(int(self._table[i, self._win0 + j]))
+            self._table[i, self._win0 + j] = 0
+            self._window_budget_back(slot, j)
+            slot.win_lo = j + 1
+            _WINDOW_RELEASED.inc()
+
+    def _window_budget_back(self, slot: "_Slot", j: int) -> None:
+        """Window block ``j`` stops counting against the slot's budget
+        (released behind it, or adopted by the trie): promise the slot
+        another in its place. The callers know one is free."""
+        if j in slot.win_own:
+            slot.win_own.discard(j)
+            self._window_pool.reserve(1)
+            slot.win_reserved += 1
 
     def _admit(self) -> None:
         # Traced-phase stamps taken under the lock, RECORDED after it:
@@ -1618,18 +1744,39 @@ class DecodeEngine:
                                 attrs=attrs)
 
     def _update_pool_gauges(self) -> None:
-        free = self._pool.free_blocks()
-        _KV_POOL_FREE.set(free)
-        pinned = set()
+        lay = self._layout
+        _KV_POOL_FREE.set(self._pool.free_blocks())
+        # Distinct blocks that live slots' tables name, by kind.
+        named = {kind: set() for kind in self._pools}
         for i, s in enumerate(self._slots):
             if s.request is not None:
-                pinned.update(int(b) for b in self._table[i, :s.blocks])
-        _KV_POOL_PINNED.set(len(pinned))
-        if self._seq_blocks:
-            _STATE_BLOCKS.labels(kind="slot").set(len(pinned))
-            _STATE_BLOCKS.labels(kind="snapshot").set(
-                self.prefix_cache.stats()["chunks"])
-            _STATE_BLOCKS.labels(kind="free").set(free)
+                for kind, blocks in self._slot_blocks(i).items():
+                    named[kind].update(blocks)
+        _KV_POOL_PINNED.set(len(named[lay.kinds()[0]]))
+        for kind, blocks in named.items():
+            _CACHE_BLOCKS.labels(kind=kind).set(len(blocks))
+        if lay.state_blocks:
+            snapshots = self.prefix_cache.stats()["chunks"]
+            _CACHE_BLOCKS.labels(kind="snapshot").set(snapshots)
+            _STATE_BLOCKS.labels(kind="slot").set(len(named["state"]))
+            _STATE_BLOCKS.labels(kind="snapshot").set(snapshots)
+            _STATE_BLOCKS.labels(kind="free").set(
+                self._state_pool.free_blocks())
+
+    def _slot_blocks(self, i: int) -> Dict[str, List[int]]:
+        """The blocks slot ``i``'s table row names, by kind: its token
+        blocks (aliased and own), the window blocks it still holds, its
+        own state block."""
+        slot, row, out = self._slots[i], self._table[i], {}
+        if self._layout.tokens:
+            out["global"] = row[self._tok0:
+                                self._tok0 + slot.blocks].tolist()
+        if self._layout.window:
+            out["window"] = row[self._win0 + slot.win_lo:
+                                self._win0 + slot.blocks].tolist()
+        if slot.own_state:
+            out["state"] = [int(row[0])]
+        return out
 
     def _table_upload(self, i: int):
         """Slot ``i``'s row of the block table as a program's input:
@@ -1643,28 +1790,29 @@ class DecodeEngine:
         """The block table a decode or verify step runs with: the
         decoding slots' rows, and the scratch block (0) in every
         other row. That is how a step's program knows which rows
-        decode, from an argument it already has. A state family's
-        step REWRITES the block each row names and skips the scratch
-        block (a slot in the middle of its prefill keeps its state;
-        free slots cost a step nothing); a family that pages by the
-        token writes a non-decoding row's unattended cache row into
-        the scratch block, not the slot's own, and deepseek's expert
-        layer computes no expert for it."""
+        decode, from an argument it already has. A step REWRITES the
+        state block a row names and skips the scratch block (a slot in
+        the middle of its prefill keeps its state; free slots cost the
+        state's layers nothing); blocks of tokens get a non-decoding
+        row's unattended cache row in the scratch block, not the
+        slot's own, and deepseek's expert layer computes no expert for
+        it."""
         table = np.zeros_like(self._table)    # a copy, as above
         table[live] = self._table[live]
         return jnp.asarray(table)
 
     def _ensure_block(self, i: int, j: int) -> int:
-        """Back slot ``i``'s logical block ``j``, allocating from the
-        slot's admission reservation on first touch (lazy growth —
-        blocks are claimed as prefill/decode actually reaches them).
-        A state family's slot holds its fixed blocks from its first
-        chunk on and never grows."""
-        if self._seq_blocks:
-            return int(self._table[i, 0])
+        """Back slot ``i``'s logical block ``j`` of tokens, of each
+        kind of token block, allocating from the slot's admission
+        reservation on first touch (lazy growth — blocks are claimed as
+        prefill/decode actually reaches them). A state holds its fixed
+        blocks from the slot's first chunk on and never grows: nothing
+        to do where that is all there is."""
+        if not self._layout.tokens:
+            return 0
         slot = self._slots[i]
         if j < slot.blocks:
-            return int(self._table[i, j])
+            return int(self._table[i, self._tok0 + j])
         if j != slot.blocks:
             raise EngineError(
                 f"non-contiguous block growth: slot {i} has "
@@ -1675,7 +1823,11 @@ class DecodeEngine:
                 "under-reserved (worst-case block math is wrong)")
         block = self._pool.alloc()
         slot.reserved -= 1
-        self._table[i, j] = block
+        self._table[i, self._tok0 + j] = block
+        if self._layout.window:
+            self._table[i, self._win0 + j] = self._window_pool.alloc()
+            slot.win_reserved -= 1
+            slot.win_own.add(j)
         slot.blocks = j + 1
         return block
 
@@ -1732,17 +1884,23 @@ class DecodeEngine:
                 fault_injection.fire("engine.prefill", slot=i,
                                      start=start)
             _STEP_KIND["prefill"].inc()
-            wb = (self._chunk_target(i, start) if self._seq_blocks
-                  else self._ensure_block(i, start // self._chunk))
+            # The block the chunk writes: its block of tokens, or
+            # where there is a state the state's (the token blocks are
+            # then the ones the table names at ``start``).
+            wb = self._ensure_block(i, start // self._chunk)
+            if self._layout.state_blocks:
+                wb = self._chunk_target(i, start)
             self._toks, self._cache = _paged_prefill_chunk(
                 self._cfg, self._params, self._cache, buf,
                 self._table_upload(i), jnp.int32(start),
                 jnp.int32(valid), jnp.int32(wb), self._window, *first)
-            if self._seq_blocks:
+            if self._layout.state_blocks:
                 self._table[i, 0] = wb     # the slot's state, from now
             req.prefill_chunks += 1
             slot.prefilled = valid
             slot.pos = valid
+            if self._layout.window:
+                self._release_behind(i)
             if final:
                 slot.generated = 1
                 self._fresh.append(_Unread(
@@ -2132,6 +2290,8 @@ class DecodeEngine:
             slot = self._slots[i]
             slot.pos += 1
             slot.generated += 1
+            if self._layout.window:
+                self._release_behind(i)
             rows.append((i, slot.request, self._maybe_retire(i)))
         self._fresh.append(_Unread(self._toks, rows, routing, t0))
         self._land()

@@ -31,17 +31,29 @@ Block 0 is a reserved SCRATCH block, never allocated: free slots ride
 along in the batched decode step with ``pos 0`` and their (ignored)
 K/V writes land there instead of clobbering a live slot's block.
 
-A family may instead keep a FIXED-SIZE state a sequence
-(:func:`blocks_per_sequence`, models/brumby.py): a block is then one
-sequence's whole state, a sequence holds the same number of blocks
-whatever its length, and every step REWRITES them, so a live slot's
-block is never the trie's. There the trie's node for a chunk holds a
-SNAPSHOT of the state after that chunk — the block a later chunk of the
-same prompt read from and did not write (:meth:`PagedPrefixCache.
-publish_snapshot`) — a hit pins the deepest matching node alone, the
-slot's first chunk reads it and writes the slot's own block
-(copy-on-write), and the scratch block, which no program ever changes,
-is the zero state a cold prompt starts from.
+What a slot's memory is MADE OF is the family's to say
+(:func:`pool_layout`, :class:`PoolLayout`), and a slot may hold several
+kinds at once, each kind a :class:`BlockPool` with block ids of its own:
+
+  * blocks of tokens that grow with the sequence (the default, above);
+  * blocks of tokens of which a sequence keeps only the newest
+    ``window`` tokens' (window-attention layers, models/phi4flash.py):
+    the engine releases a block BEHIND the sequence, so a sequence
+    never holds more than :meth:`PoolLayout.window_blocks`;
+  * a FIXED-SIZE state a sequence (models/brumby.py, the Mamba layers
+    of models/phi4flash.py): a block is one sequence's whole state, a
+    sequence holds the same number whatever its length, and every step
+    REWRITES it, so a live slot's block is never the trie's. There the
+    trie's node for a chunk holds a SNAPSHOT of the state after that
+    chunk — the block a later chunk of the same prompt read from and
+    did not write (:meth:`PagedPrefixCache.publish_snapshot`) — the
+    slot's first chunk reads it and writes the slot's own block
+    (copy-on-write), and the scratch block, which no program ever
+    changes, is the zero state a cold prompt starts from.
+
+A trie node holds one block of EVERY kind the family has (``block``,
+the first kind's, and ``extra``), so a hit restores all kinds to the
+same chunk boundary and an eviction returns all of them.
 
 Below the device pool sits an optional second tier
 (:class:`HostBlockPool`): on LRU eviction a leaf's block is SPILLED
@@ -67,6 +79,7 @@ arithmetic of who holds which block).
 from __future__ import annotations
 
 import collections
+import dataclasses
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -96,34 +109,81 @@ def block_bytes(block_tokens: int, n_layers: int, n_kv_heads: int,
     return rows * per_elem + scales
 
 
-def block_bytes_for(cfg, block_tokens: int, *,
-                    quantized: bool = False) -> int:
-    """Device bytes ONE pool block of a model configuration costs
-    across all layers. What a cached token costs is the family's to
-    say, and its ``init_paged_cache`` says it (shapes only): keys and
-    values for every KV head (:func:`block_bytes`), or one latent row
-    shared by all heads (deepseek)."""
+@dataclasses.dataclass(frozen=True)
+class PoolLayout:
+    """What a slot's memory is made of: the answer to the ONE question
+    the engine asks a family about its pool (:func:`pool_layout`).
+
+    ``tokens``: blocks of ``chunk`` tokens, appended as the sequence
+    grows and never rewritten, so the trie may alias them (kind
+    ``"global"``). ``window`` > 0 (with ``tokens``): a second kind of
+    token block (``"window"``) whose layers read only the newest
+    ``window`` tokens, released behind the sequence. ``state_blocks``:
+    blocks that hold a sequence's whole recurrent state (``"state"``),
+    rewritten by every step, restored from snapshots, never aliased.
+    Admission, the pools' auto sizes, the table's columns and a slot's
+    token limit all follow from it (decode_engine.resolve_kv_geometry).
+    """
+    tokens: bool = True
+    window: int = 0
+    state_blocks: int = 0
+
+    def __post_init__(self):
+        if not self.tokens and (self.window or not self.state_blocks):
+            raise ValueError(f"a pool of no kind, or a window with no "
+                             f"token blocks: {self}")
+        if self.state_blocks > 1:
+            raise NotImplementedError(
+                "a sequence state of more than one pool block has no "
+                "program yet (one table column names the one)")
+
+    def kinds(self) -> Tuple[str, ...]:
+        """The kinds a slot holds, the trie's first kind first."""
+        return tuple(k for k, has in (
+            ("global", self.tokens), ("window", self.window),
+            ("state", self.state_blocks)) if has)
+
+    def window_blocks(self, chunk: int) -> int:
+        """The most window blocks a sequence holds: ``window`` keys end
+        in the newest token's block and start at most ``window - 1``
+        rows before it."""
+        return -(-self.window // chunk) + 1 if self.window else 0
+
+
+def pool_layout(cfg) -> PoolLayout:
+    """A family's :class:`PoolLayout`, from the fields its
+    ``pool_layout(cfg)`` gives (models import nothing of serve/), or
+    blocks of tokens alone for a family without that function."""
+    from skypilot_tpu.models import model_api
+    ask = getattr(model_api(cfg), "pool_layout", None)
+    return PoolLayout(**ask(cfg)) if ask is not None else PoolLayout()
+
+
+def block_bytes_by_kind(cfg, block_tokens: int, *,
+                        quantized: bool = False) -> Dict[str, int]:
+    """Device bytes ONE pool block of each kind costs across all the
+    layers that keep it. What a block holds is the family's to say, and
+    its ``init_paged_cache`` says it (shapes only): keys and values for
+    every KV head (:func:`block_bytes`), one latent row shared by all
+    heads (deepseek), a sequence's state (brumby). A family of several
+    kinds leads each leaf's name with its kind (``window_k``)."""
     import jax
     from skypilot_tpu.models import model_api
+    kinds = pool_layout(cfg).kinds()
     pool = jax.eval_shape(lambda: model_api(cfg).init_paged_cache(
         cfg, 1, block_tokens, quantized=quantized))
-    return sum(a.size * a.dtype.itemsize for a in pool.values())
+    return {kind: sum(a.size * a.dtype.itemsize
+                      for name, a in pool.items()
+                      if len(kinds) == 1 or name.startswith(kind + "_"))
+            for kind in kinds}
 
 
-def blocks_per_sequence(cfg) -> int:
-    """The ONE question the engine asks a family about its pool's
-    accounting: how many blocks a sequence holds whatever its length —
-    0 for a family that caches something per token (blocks are appended
-    as the sequence grows and never rewritten, so the trie may alias
-    them), the family's ``state_blocks_per_sequence(cfg)`` for one
-    whose block is a sequence's recurrent state (rewritten by every
-    step, so restored from snapshots, never aliased). Admission, the
-    pool's auto size, the table's length and a slot's token limit all
-    follow from it (:class:`BlockPool`, decode_engine.
-    resolve_kv_geometry)."""
-    from skypilot_tpu.models import model_api
-    ask = getattr(model_api(cfg), "state_blocks_per_sequence", None)
-    return int(ask(cfg)) if ask is not None else 0
+def block_bytes_for(cfg, block_tokens: int, *,
+                    quantized: bool = False) -> int:
+    """Device bytes one block of EVERY kind of a model configuration
+    costs together (one kind: that kind's block)."""
+    return sum(block_bytes_by_kind(cfg, block_tokens,
+                                   quantized=quantized).values())
 
 
 def blocks_for_budget(budget_bytes: int, block_tokens: int,
@@ -160,7 +220,7 @@ class BlockPool:
                              f"{block_tokens}")
         self.num_blocks = int(num_blocks)
         self.block_tokens = int(block_tokens)
-        # :func:`blocks_per_sequence`'s answer: > 0 where a block is a
+        # From the family's :class:`PoolLayout`: > 0 where a block is a
         # sequence's whole state.
         self.seq_blocks = int(seq_blocks)
         self._free: "collections.deque[int]" = collections.deque(
@@ -177,7 +237,9 @@ class BlockPool:
 
     def blocks_for(self, tokens: int) -> int:
         """Blocks a sequence of ``tokens`` tokens holds: one a
-        ``block_tokens`` of them, or the family's fixed count."""
+        ``block_tokens`` of them, or the fixed count of a state. (What
+        a WINDOW's sequence holds is bounded by :meth:`PoolLayout.
+        window_blocks`, which admission applies itself.)"""
         return self.seq_blocks or -(-int(tokens) // self.block_tokens)
 
     # -------------------------------------------------------- accounting
@@ -356,16 +418,20 @@ class _BlockNode:
     one pool block. ``refs`` counts live slots whose admission aliased
     this node (pins — never evicted while > 0). ``block == -1`` is the
     HOST residency state: the device block was spilled to the host
-    tier, keyed by ``path`` (the full chunk-key chain from the root)."""
+    tier, keyed by ``path`` (the full chunk-key chain from the root).
+    ``extra`` names the node's block of every further kind the family's
+    pool has (kind -> block id in that kind's pool)."""
 
-    __slots__ = ("key", "parent", "children", "block", "refs", "tick",
-                 "path")
+    __slots__ = ("key", "parent", "children", "block", "extra", "refs",
+                 "tick", "path")
 
-    def __init__(self, key, parent: Optional["_BlockNode"], block: int):
+    def __init__(self, key, parent: Optional["_BlockNode"], block: int,
+                 extra: Optional[Dict[str, int]] = None):
         self.key = key
         self.parent = parent
         self.children: Dict[tuple, "_BlockNode"] = {}
         self.block = int(block)
+        self.extra: Dict[str, int] = dict(extra or {})
         self.refs = 0
         self.tick = 0
         self.path: Tuple = (() if parent is None
@@ -394,8 +460,17 @@ class PagedPrefixCache:
 
     def __init__(self, pool: BlockPool, chunk: int, *,
                  host_pool: Optional[HostBlockPool] = None,
-                 spill: Optional[Callable[["_BlockNode"], bool]] = None):
+                 spill: Optional[Callable[["_BlockNode"], bool]] = None,
+                 extra_pools: Optional[Dict[str, BlockPool]] = None):
         self.pool = pool
+        # The pools of a node's ``extra`` blocks, by kind. The spill
+        # path moves ``node.block`` alone to the host, so a node with
+        # further blocks cannot be demoted.
+        self.extra_pools: Dict[str, BlockPool] = dict(extra_pools or {})
+        if self.extra_pools and host_pool is not None:
+            raise NotImplementedError(
+                "the host spill tier moves one block a node: a trie "
+                "over several kinds of block cannot spill")
         self.chunk = int(chunk)
         self.host_pool = host_pool
         self._spill = spill if host_pool is not None else None
@@ -573,14 +648,17 @@ class PagedPrefixCache:
         return adopted
 
     def publish_snapshot(self, prompt: List[int], n_chunks: int,
-                         block: int) -> bool:
-        """Adopt ``block`` as the node of ``prompt``'s first
-        ``n_chunks`` chunks: the state AFTER them, which a later chunk
-        read and no program will write again. True when the trie took
-        it (pool.retain: the caller drops its own reference right
-        after, as in :meth:`publish`); False when the node is there
-        already or its parent is not (evicted meanwhile): the block
-        then simply frees."""
+                         block: int,
+                         extra: Optional[Dict[str, int]] = None) -> bool:
+        """Adopt ``block`` (and ``extra``: one block of every further
+        kind) as the node of ``prompt``'s first ``n_chunks`` chunks:
+        the state AFTER them, which a later chunk read and no program
+        will write again, and the token blocks of the last of them.
+        True when the trie took them (retain in each kind's pool: the
+        caller drops or keeps its own references as it would have, as
+        in :meth:`publish`); False when the node is there already or
+        its parent is not (evicted meanwhile): a state block then
+        simply frees."""
         with self._lock:
             self._tick += 1
             node = self._root
@@ -595,9 +673,11 @@ class PagedPrefixCache:
             if child is not None:
                 child.tick = self._tick
                 return False
-            child = _BlockNode(key, node, block)
+            child = _BlockNode(key, node, block, extra)
             node.children[key] = child
             self.pool.retain(child.block)
+            for kind, held in child.extra.items():
+                self.extra_pools[kind].retain(held)
             # Never restored from yet: older than every node that has
             # been (a pin stamps the clock's positive tick), oldest
             # taken first among its like. Most snapshots are of a
@@ -657,6 +737,8 @@ class PagedPrefixCache:
                 stack.extend(n.children.values())
             del victim.parent.children[victim.key]
             self.pool.release(victim.block)
+            for kind, held in victim.extra.items():
+                self.extra_pools[kind].release(held)
             for n in doomed:
                 self._chunks -= 1
                 if n.block < 0:

@@ -126,11 +126,14 @@ def _counter(name, **labels):
 def test_model_api_dispatch_and_the_one_question():
     cfg = brumby.BrumbyConfig.tiny()
     assert model_api(cfg) is brumby and family_name(cfg) == "brumby"
-    assert kv_pool.blocks_per_sequence(cfg) == 1
+    assert kv_pool.pool_layout(cfg) == kv_pool.PoolLayout(
+        tokens=False, state_blocks=1)
+    assert kv_pool.pool_layout(cfg).kinds() == ("state",)
     for other in (llama.LlamaConfig.tiny(), mixtral.MixtralConfig.tiny(),
                   gemma.GemmaConfig.tiny(),
                   deepseek.DeepseekV3Config.tiny()):
-        assert kv_pool.blocks_per_sequence(other) == 0
+        assert kv_pool.pool_layout(other) == kv_pool.PoolLayout()
+        assert kv_pool.pool_layout(other).kinds() == ("global",)
 
 
 @pytest.mark.parametrize("hd,d", [(16, 256), (32, 768), (128, 9216)])
@@ -396,13 +399,16 @@ def test_dead_and_prefilling_slots_never_change_a_live_slots_logits(
 
 def test_pool_accounting_and_gauges(params):
     geo = decode_engine.resolve_kv_geometry(
-        slots=16, max_seq=1280, seq_blocks=1, use_manifest=False)
+        slots=16, max_seq=1280, use_manifest=False,
+        layout=kv_pool.PoolLayout(tokens=False, state_blocks=1))
     assert (geo["pool_blocks"], geo["snapshot_blocks"], geo["table_len"],
             geo["chunk"], geo["seq_blocks"]) == (29, 12, 1, 64, 1)
+    assert geo["pools"] == {"state": 29}
     paged = decode_engine.resolve_kv_geometry(
         slots=16, max_seq=1280, use_manifest=False)
     assert (paged["pool_blocks"], paged["seq_blocks"],
             paged["snapshot_blocks"]) == (16 * 20 + 1, 0, 0)
+    assert paged["pools"] == {"global": 16 * 20 + 1}
     pool = kv_pool.BlockPool(5, 64, seq_blocks=1)
     assert pool.blocks_for(1) == pool.blocks_for(100000) == 1
     assert kv_pool.BlockPool(5, 64).blocks_for(130) == 3
@@ -413,7 +419,8 @@ def test_pool_accounting_and_gauges(params):
     engine = DecodeEngine(cfg, params, slots=2, max_seq=256,
                           use_manifest=False)
     assert engine.kv_config() == decode_engine.resolve_kv_geometry(
-        slots=2, max_seq=256, seq_blocks=1, use_manifest=False)
+        slots=2, max_seq=256, use_manifest=False,
+        layout=kv_pool.pool_layout(cfg))
     assert _counter("stpu_engine_kv_pool_block_bytes") == block
     assert sum(engine.cache_bytes_per_device().values()) == 5 * block
     with pytest.raises(decode_engine.EngineError, match="max_seq"):

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from skypilot_tpu.models import brumby, deepseek, gemma, llama, mixtral
+from skypilot_tpu.models import (brumby, deepseek, gemma, llama, mixtral,
+                                 phi4flash)
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.serve import decode_engine
 from skypilot_tpu.serve.decode_engine import DecodeEngine, EngineError
@@ -42,6 +43,8 @@ def _tiny(family="llama"):
         return deepseek, deepseek.DeepseekV3Config.tiny(vocab_size=128)
     if family == "brumby":
         return brumby, brumby.BrumbyConfig.tiny(vocab_size=128)
+    if family == "phi4flash":
+        return phi4flash, phi4flash.Phi4FlashConfig.tiny(vocab_size=128)
     return llama, llama.LlamaConfig.tiny(vocab_size=128)
 
 
@@ -507,8 +510,22 @@ def test_warmup_reaches_every_program_the_loop_dispatches():
 
 
 # ============================================== (f) the step's table
+def _blocks_by_leaf(engine, slots_held):
+    """For every pool leaf, the sorted block ids (of the leaf's kind)
+    that the table rows of ``slots_held`` name
+    (``DecodeEngine._slot_blocks``)."""
+    held = {kind: set() for kind in engine._pools}
+    for i in slots_held:
+        for kind, blocks in engine._slot_blocks(i).items():
+            held[kind].update(blocks)
+    one = len(held) == 1
+    return {leaf: sorted(next(iter(held.values())) if one
+                         else held[leaf.split("_")[0]])
+            for leaf in engine._cache}
+
+
 @pytest.mark.parametrize("family", ["llama", "mixtral", "gemma",
-                                    "deepseek", "brumby"])
+                                    "deepseek", "brumby", "phi4flash"])
 def test_a_steps_table_names_only_the_decoding_slots(family,
                                                      monkeypatch):
     """Three slots: one decodes, one prefills a four-chunk prompt
@@ -516,9 +533,9 @@ def test_a_steps_table_names_only_the_decoding_slots(family,
     decoding slots' own rows and names block 0, the scratch block, in
     every other row — which is how a step's program knows the rows
     that decode (deepseek's expert layer computes no expert for the
-    others; brumby's step leaves their state alone) — and the
-    prefilling slot's blocks hold after the step what they held
-    before it, leaf for leaf."""
+    others; brumby's and phi4flash's steps leave their state alone) —
+    and the prefilling slot's blocks, of every kind, hold after the
+    step what they held before it, leaf for leaf."""
     _, cfg, _, engine = _engine(family, slots=3)
     seen = []
     step = decode_engine._paged_step
@@ -542,10 +559,9 @@ def test_a_steps_table_names_only_the_decoding_slots(family,
                     and s.prefilled >= len(s.request.prompt)]
         prefilling = [i for i, s in enumerate(slots) if s.request
                       and 0 < s.prefilled < len(s.request.prompt)]
-        blocks = sorted({int(b) for i in prefilling
-                         for b in engine._table[i, :slots[i].blocks]})
-        before = jax.tree.map(lambda leaf: np.asarray(leaf[:, blocks]),
-                              engine._cache)
+        blocks = _blocks_by_leaf(engine, prefilling)
+        held = {leaf: np.asarray(a[:, blocks[leaf]])
+                for leaf, a in engine._cache.items()}
         steps = len(seen)
         did = engine._decode_step() or did
         if len(seen) > steps:
@@ -554,10 +570,10 @@ def test_a_steps_table_names_only_the_decoding_slots(family,
             np.testing.assert_array_equal(table[decoding], own[decoding])
             assert (table[decoding, 0] != 0).all()
             assert not table[others].any()
-            after = jax.tree.map(
-                lambda leaf: np.asarray(leaf[:, blocks]), engine._cache)
-            jax.tree.map(np.testing.assert_array_equal, after, before)
-            beside_a_prefill += bool(blocks)
+            for leaf, a in engine._cache.items():
+                np.testing.assert_array_equal(
+                    np.asarray(a[:, blocks[leaf]]), held[leaf])
+            beside_a_prefill += any(blocks.values())
             beside_a_free_slot += any(s.request is None for s in slots)
         if not did and not engine._waiting:
             break

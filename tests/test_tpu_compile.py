@@ -26,12 +26,13 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from skypilot_tpu.models import brumby, deepseek, llama, mixtral
+from skypilot_tpu.models import (brumby, deepseek, llama, mixtral,
+                                 phi4flash)
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops.pallas import flash_attention as fa
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.parallel import mesh_attention
-from skypilot_tpu.serve import decode_engine
+from skypilot_tpu.serve import decode_engine, kv_pool
 
 
 @pytest.fixture(scope="module")
@@ -437,3 +438,80 @@ def test_state_pool_programs_stay_in_place_on_v5e(topo, for_the_chip,
     found = _weight_shaped(text, params["layers"])
     assert not found, found
     assert ("stpu_retention_step" in text) == (entry == "_paged_step")
+
+
+@pytest.mark.parametrize("entry", ["_paged_step", "_paged_prefill_chunk"])
+def test_three_kinds_of_pool_stay_in_place_on_v5e(topo, for_the_chip,
+                                                  entry):
+    """Phi-4-mini-flash-reasoning whole (32 layers, every width, 7.71 GB
+    of weights), 64 slots and the engine's own pools for them: 1,329
+    blocks of the full layer, 625 of the eight window layers, 113
+    states, 2.44 GB. Every pool leaf is ONE buffer in both programs,
+    aliased from entry to exit, with no copy of its shape; what the
+    programs keep beside them (rehearsal 3, PR 36): the step 0.63 GB,
+    which is the keys and values it gathers for 64 slots (layer 17's
+    once for its eight readers, 0.42 GB, and a window layer's 0.19 GB),
+    the chunk 0.01 GB. Two things this compile refused before any chip
+    call, both the TPU's choice of layouts: with a key/value pair's 10
+    pairs next to the lanes (no multiple of 8 sublanes) every pool was
+    converted to (rows, lanes) tiles and back around each program (3.7
+    GB of temporaries); and with the pairs outside a block's rows but
+    the new rows scattered a (pairs, lanes) slab at a time, the carried
+    pool took the slab's layout and was converted for the gather, 3.8
+    GB again. One 128-lane row a (token, pair) keeps the layout the
+    pool arrives in. No layer's weight is cut out of a scanned stack."""
+    cfg, slots = phi4flash.Phi4FlashConfig(), 64
+    geo = decode_engine.resolve_kv_geometry(
+        slots=slots, max_seq=1280, use_manifest=False,
+        layout=kv_pool.pool_layout(cfg))
+    assert geo["pools"] == {"global": 1329, "window": 625, "state": 113}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: phi4flash.init(cfg, jax.random.key(0))))
+    pool = on_chip(jax.eval_shape(
+        lambda: phi4flash.init_paged_cache(cfg, geo["pools"], 64)))
+    i32, table_len = jnp.int32, geo["table_len"]
+    args = {
+        "_paged_step": (arg(i32, slots), arg(i32, slots),
+                        arg(i32, slots, table_len), 256,
+                        arg(jnp.float32, slots), arg(jnp.uint32, slots)),
+        "_paged_prefill_chunk": (arg(i32, 64), arg(i32, table_len),
+                                 arg(i32), arg(i32), arg(i32), 256,
+                                 arg(i32, slots), arg(i32),
+                                 arg(jnp.uint32), arg(jnp.float32)),
+    }[entry]
+    compiled = getattr(decode_engine, entry).lower(
+        cfg, params, pool, *args).compile()
+    text = compiled.as_text()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert pool_bytes == 2_438_379_520
+    memory = compiled.memory_analysis()
+    bound = {"_paged_step": 0.70e9, "_paged_prefill_chunk": 0.02e9}[entry]
+    assert memory.temp_size_in_bytes < bound, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= pool_bytes
+    for name, leaf in pool.items():
+        dims = ",".join(map(str, leaf.shape))
+        copies = re.findall(
+            rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(", text,
+            re.M)
+        assert not copies, (name, copies)
+        # One layout from entry to exit: the one the leaf arrives in.
+        layouts = set(re.findall(rf"\w+\[{dims}\](\{{[\d,]*)", text))
+        minor_to_major = "{" + ",".join(
+            str(i) for i in reversed(range(leaf.ndim)))
+        assert layouts == {minor_to_major}, (name, layouts)
+    # The scanned stacks (8 and 7 layers): no layer cut out or re-laid.
+    # The middle layers' matrices are parameters of their own, one
+    # layer each, which the compiler may fetch ahead (``copy-start``).
+    scanned = {g: params[g] for g in ("front", "back")}
+    found = [f for f in _weight_shaped(text, scanned)
+             if f.split()[1] not in ("parameter", "get-tuple-element",
+                                     "bitcast", "copy-start", "copy-done")
+             and "[1,16,5120]" not in f]      # exp(a_log): 0.3 MB
+    assert not found, found
