@@ -33,7 +33,9 @@ row of the engine's table names all of a slot's blocks (:func:`_tables`):
   * ``global_k/v (1, blocks, pairs, block_tokens, 2 * head_dim)``: the
     full layer's keys and values, appended with the sequence, aliased by
     the prefix trie, written by ONE layer and read by it and by every
-    cross layer: gathered once a program. The pairs lie OUTSIDE a
+    cross layer (a chunk gathers them once; a decode step reads the
+    decoding slots' blocks where they lie, eight times:
+    ops/pallas/paged_attention.py). The pairs lie OUTSIDE a
     block's rows: 10 pairs are no multiple of the TPU's 8 sublanes, and
     with them next to the lanes the compiler re-laid every pool to
     (tokens, lanes) tiles on the way in and out of each program
@@ -63,13 +65,14 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.ops.pallas import paged_attention
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -464,17 +467,19 @@ def _diff_out(cfg: Phi4FlashConfig, x, o, lp: Params, lam_init):
     return x + jnp.matmul(o.reshape(b, t, -1), lp["wo"]) + lp["bo"]
 
 
-def cross_block(cfg: Phi4FlashConfig, x, lp: Params, lam_init, k, v,
-                mask) -> jax.Array:
-    """Pre-norm cross attention on the full layer's keys and values
-    (as :func:`_attend` takes them): a query and an output projection
-    of its own, no key/value projection."""
+def cross_block(cfg: Phi4FlashConfig, x, lp: Params, lam_init,
+                attend) -> jax.Array:
+    """Pre-norm cross attention on the full layer's keys and values: a
+    query and an output projection of its own, no key/value
+    projection. ``attend`` takes the padded queries to the four
+    read-outs a key/value pair, however the caller keeps those keys
+    and values."""
     with jax.named_scope("stpu.cross_attn"):
         y = layer_norm(x, lp["norm1_w"], lp["norm1_b"], cfg.norm_eps)
         q = (llama._finished_dense(y, lp, "wq")
              + lp["bq"].astype(F32)).astype(y.dtype)
-        o = _attend(cfg, _pad_queries(cfg, q), k, v, mask)
-        return _diff_out(cfg, x, o, lp, lam_init)
+        return _diff_out(cfg, x, attend(_pad_queries(cfg, q)), lp,
+                         lam_init)
 
 
 def _qkv(cfg: Phi4FlashConfig, y: jax.Array, lp: Params):
@@ -544,7 +549,8 @@ def forward(cfg: Phi4FlashConfig, params: Params, tokens: jax.Array,
     def back(x, scanned):
         gp, cp, lam_init = scanned
         x = mlp_block(cfg, gmu_block(cfg, x, gp, m), gp)
-        x = cross_block(cfg, x, cp, lam_init, k, v, mask)
+        x = cross_block(cfg, x, cp, lam_init,
+                        lambda q: _attend(cfg, q, k, v, mask))
         return mlp_block(cfg, x, cp), None
 
     x, _ = jax.lax.scan(back, x, (
@@ -715,15 +721,23 @@ def forward_with_paged_cache(cfg: Phi4FlashConfig, params: Params,
                              logits_at: Optional[jax.Array] = None, *,
                              window: int,
                              write_block: Optional[jax.Array] = None,
-                             write_pos: Optional[jax.Array] = None
-                             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                             write_pos: Optional[jax.Array] = None):
     """llama.forward_with_paged_cache's contract over the three pools.
     ``table`` (B, 1 + 2 span) names a slot's blocks of every kind
     (:func:`_tables`); ``write_block`` is the STATE block a chunk
     writes (it reads the one at ``table[0, 0]``), its key/value rows go
     to the blocks the table names at its first position; ``window``
     (the engine's attention tile) tiles nothing here. Three scans, each
-    scanning its layers' parameters and carrying the pools it writes."""
+    scanning its layers' parameters and carrying the pools it writes.
+
+    The sixteen attention reads go one of two ways, by the shape of the
+    call. A decode step (T == 1) reads each DECODING slot's visible
+    blocks where they lie in the pool (ops/pallas/paged_attention.py;
+    a slot whose row names the scratch state block reads nothing) and
+    returns, as a third result, ``(the blocks its sixteen reads
+    fetched,)``. A chunk (B == 1) gathers its one slot's
+    blocks and attends in one pass (:func:`_attend`): they are 0.2 % of
+    a chunk's bytes."""
     del window
     if write_pos is not None:
         refuse("speculative decoding (spec_k > 0)",
@@ -734,10 +748,24 @@ def forward_with_paged_cache(cfg: Phi4FlashConfig, params: Params,
         b, t, start_pos, valid_len)
     state_blk, table_g, table_w = _tables(table)
     bt = cache["global_k"].shape[3]
-    # A window of W keys ends in the query's block and starts at most
-    # W - 1 rows before the query: ceil(W / bt) + 1 blocks hold it.
-    first_w = jnp.maximum(start_pos - cfg.sliding_window + 1, 0) // bt
-    n_w = -(-cfg.sliding_window // bt) + 1
+    scale = cfg.head_dim ** -0.5
+    if t == 1:
+        reads_w, reads_g = (paged_attention.step_reads(
+            tbl, state_blk != 0, start_pos, valid_len, bt, w)
+            for tbl, w in ((table_w, cfg.sliding_window), (table_g, 0)))
+
+        def attend_w(q, wk, wv, li):
+            return paged_attention.attend(q[:, 0], wk, wv, li, reads_w,
+                                          scale)[:, None]
+    else:
+        first_w = jnp.maximum(start_pos - cfg.sliding_window + 1, 0) // bt
+        n_w = paged_attention.window_blocks(cfg.sliding_window, bt)
+
+        def attend_w(q, wk, wv, li):
+            kb, vb, kpos = _gather(wk, wv, li, table_w, first_w, n_w)
+            return _attend(cfg, q, kb, vb, _paged_mask(
+                kpos, positions, valid_len, cfg.sliding_window))
+
     x = llama._decode_embed(cfg, params, tokens)
 
     def ssm(x, lp, li, state):
@@ -753,10 +781,7 @@ def forward_with_paged_cache(cfg: Phi4FlashConfig, params: Params,
             y = layer_norm(x, ap["norm1_w"], ap["norm1_b"], cfg.norm_eps)
             q, k, v = _qkv(cfg, y, ap)
             wk, wv = _write_rows(wk, wv, li, table_w, bt, start_pos, k, v)
-            kb, vb, kpos = _gather(wk, wv, li, table_w, first_w, n_w)
-            o = _attend(cfg, q, kb, vb, _paged_mask(
-                kpos, positions, valid_len, cfg.sliding_window))
-            x = _diff_out(cfg, x, o, ap, lam_init)
+            x = _diff_out(cfg, x, attend_w(q, wk, wv, li), ap, lam_init)
         return (mlp_block(cfg, x, ap), state, (wk, wv)), None
 
     (x, state, (wk, wv)), _ = jax.lax.scan(
@@ -773,27 +798,41 @@ def forward_with_paged_cache(cfg: Phi4FlashConfig, params: Params,
         q, k, v = _qkv(cfg, y, ap)
         gk, gv = _write_rows(cache["global_k"], cache["global_v"], 0,
                              table_g, bt, start_pos, k, v)
-        # The one full layer's keys and values, gathered ONCE: this
-        # layer and every cross layer read them.
-        kb, vb, kpos = _gather(gk, gv, 0, table_g,
-                               jnp.zeros((b,), jnp.int32), table_g.shape[1])
-        mask = _paged_mask(kpos, positions, valid_len)
-        o = _attend(cfg, q, kb, vb, mask)
-        x = _diff_out(cfg, x, o, ap, lambda_init(cfg.n_layers // 2 + 1))
+        # The one full layer's keys and values: this layer and every
+        # cross layer read them (a chunk gathers them ONCE).
+        if t == 1:
+            def attend_g(q):
+                return paged_attention.attend(q[:, 0], gk, gv, 0, reads_g,
+                                              scale)[:, None]
+        else:
+            kb, vb, kpos = _gather(gk, gv, 0, table_g,
+                                   jnp.zeros((b,), jnp.int32),
+                                   table_g.shape[1])
+            mask = _paged_mask(kpos, positions, valid_len)
+
+            def attend_g(q):
+                return _attend(cfg, q, kb, vb, mask)
+
+        x = _diff_out(cfg, x, attend_g(q), ap,
+                      lambda_init(cfg.n_layers // 2 + 1))
     x = mlp_block(cfg, x, ap)
 
     def back(x, scanned):
         gp, cp, lam_init = scanned
         x = mlp_block(cfg, gmu_block(cfg, x, gp, m), gp)
-        x = cross_block(cfg, x, cp, lam_init, kb, vb, mask)
+        x = cross_block(cfg, x, cp, lam_init, attend_g)
         return mlp_block(cfg, x, cp), None
 
     x, _ = jax.lax.scan(back, x, (
         params["back"]["gmu"], params["back"]["cross"],
         _lambda_inits(cfg, "back", "cross")))
     logits = lm_head(cfg, params, llama.read_out(x, logits_at))
-    return logits, {"global_k": gk, "global_v": gv, "window_k": wk,
-                    "window_v": wv, "state_h": sh, "state_conv": sc}
+    cache = {"global_k": gk, "global_v": gv, "window_k": wk,
+             "window_v": wv, "state_h": sh, "state_conv": sc}
+    if t > 1:
+        return logits, cache
+    return logits, cache, (cfg.n_front * reads_w.fetched()
+                           + (1 + cfg.n_back) * reads_g.fetched(),)
 
 
 def verify_step_paged(cfg: Phi4FlashConfig, *args, **kwargs):

@@ -288,6 +288,13 @@ _MOE_COMPUTED = metrics.counter(
     "program computed: what its expert loop ran over, read back "
     "beside the tokens. Equals stpu_moe_experts_hit_total where the "
     "program's decoding rows are the scheduler's.")
+_ATTN_BLOCKS_READ = metrics.counter(
+    "stpu_attn_blocks_read_total",
+    "Key/value blocks that decode steps' attention reads fetched from "
+    "the pool, summed over the layers that attend, counted by the "
+    "step's program from the per-slot counts that bound its kernel's "
+    "loops and read back beside the tokens (families whose step reads "
+    "the decoding slots' visible blocks in place).")
 _STEPS = metrics.counter(
     "stpu_engine_steps_total",
     "Device programs the engine loop dispatched, by kind: decode "
@@ -528,7 +535,8 @@ class _Unread:
     its last (the slot was retired when the program was dispatched),
     and no index means no token, only the end (a cancel). ``routing``
     is a step's two held-expert arrays beside the tokens (deepseek:
-    chosen by each row, computed by the program), ``t0`` a decode
+    chosen by each row, computed by the program) or the one count of
+    key/value blocks its attention read (phi4flash), ``t0`` a decode
     step's dispatch instant (None for a prefill chunk)."""
 
     __slots__ = ("toks", "routing", "rows", "t0")
@@ -621,15 +629,19 @@ def _paged_step(cfg, params, cache, toks, pos, table, window, temps,
     and which of them it computed (deepseek: a third result, (B, T,
     sparse layers, held) and (sparse layers, held) bool) gets them
     back beside the tokens, ``((nxt, (chosen, computed)), pool)``, so
-    that the step's one blocking fetch brings all three."""
+    that the step's one blocking fetch brings all three. A family whose
+    step counts the key/value blocks its attention read (phi4flash: a
+    third result of one scalar, ``(blocks,)``) gets that back the same
+    way: the last of a report is the whole step's, what comes before it
+    has a row a slot."""
     api = model_api(cfg)
     logits, cache, *routing = api.forward_with_paged_cache(
         cfg, params, toks[:, None], cache, table, pos, window=window)
     logits = logits[:, -1]
     nxt = _sample(logits, seeds, pos + 1, temps)
     if routing:
-        chosen, computed = routing[0]
-        nxt = (nxt, (chosen[:, 0], computed))
+        *by_row, whole = routing[0]
+        nxt = (nxt, (*(rows[:, 0] for rows in by_row), whole))
     return nxt, cache
 
 
@@ -1997,7 +2009,9 @@ class DecodeEngine:
             rows = entry.rows
             if entry.t0 is not None:
                 live = [i for i, _, _ in rows if i is not None]
-                if routing is not None:
+                if routing is not None and len(routing) == 1:
+                    _ATTN_BLOCKS_READ.inc(int(routing[0]))
+                elif routing is not None:
                     chosen, computed = routing
                     chosen = chosen[live]      # (live, layers, held)
                     _MOE_ROUTED.inc(int(chosen.sum()))

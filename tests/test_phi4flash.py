@@ -18,6 +18,7 @@ import pytest
 from benchmarks.reference import phi4flash_arch as ref
 from skypilot_tpu.models import family_name, model_api, phi4flash
 from skypilot_tpu.observability import metrics
+from skypilot_tpu.ops.pallas import paged_attention
 from skypilot_tpu.serve import decode_engine, gang_replica, kv_pool
 from skypilot_tpu.serve.decode_engine import DecodeEngine
 
@@ -389,6 +390,49 @@ def test_block_accounting_of_a_served_run(params):
         pass
     for kind, pool in pools.items():
         assert pool.in_use() == 0 and pool.available() == free[kind]
+
+
+def test_the_steps_counter_is_the_blocks_its_reads_fetched(params):
+    """``stpu_attn_blocks_read_total`` over a served run against a plain
+    count from what each dispatched step was given: for every row whose
+    table names a state block of its own, the window layers' blocks from
+    the one that holds position ``pos - 127`` and the full layer's from
+    0, to the one that holds ``pos``, two to an entry of the kernel's
+    list (``paged_attention.FOLD``; an odd count reads one block twice),
+    times the layers that read them (2 window layers; the full layer
+    and 1 cross layer). A request that joins while another decodes makes
+    steps with one and with two decoding rows, and rows that ride with
+    a table of zeros count nothing."""
+    fold, calls = paged_attention.FOLD, []
+    step = decode_engine._paged_step
+
+    def spy(cfg, weights, cache, toks, pos, table, *rest):
+        calls.append((np.asarray(pos), np.asarray(table)))
+        return step(cfg, weights, cache, toks, pos, table, *rest)
+
+    before = _counter("stpu_attn_blocks_read_total")
+    engine = DecodeEngine(TAP_CFG, params, slots=3, max_seq=512,
+                          use_manifest=False)
+    with mock.patch.object(decode_engine, "_paged_step", spy):
+        first = engine.submit(_tokens(150, seed=21), max_tokens=70)
+        for _ in range(12):
+            _drive(engine)
+        second = engine.submit(_tokens(40, seed=22), max_tokens=30)
+        while _drive(engine):
+            pass
+        engine._land(everything=True)
+    assert len(first.result(timeout=5.0)) == 70
+    assert len(second.result(timeout=5.0)) == 30
+    want, rows_decoding = 0, set()
+    for pos, table in calls:
+        decoding = np.flatnonzero(table[:, 0])
+        rows_decoding.add(len(decoding))
+        for p in pos[decoding]:
+            full = p // CHUNK + 1
+            window = p // CHUNK - max(p - 127, 0) // CHUNK + 1
+            want += (2 * -(-window // fold) + 2 * -(-full // fold)) * fold
+    assert rows_decoding >= {1, 2} and len(calls) >= 69   # 1st: prefill
+    assert _counter("stpu_attn_blocks_read_total") - before == want > 0
 
 
 def test_a_hit_pins_and_aliases_by_kind_and_releases_drop_refcounts(params):

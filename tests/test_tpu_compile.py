@@ -440,6 +440,27 @@ def test_state_pool_programs_stay_in_place_on_v5e(topo, for_the_chip,
     assert ("stpu_retention_step" in text) == (entry == "_paged_step")
 
 
+def _copies_of(text, dims):
+    """Instructions of a compiled program that copy an array of shape
+    ``dims``: a ``copy`` yields the array, a ``copy-start`` a tuple of
+    source, destination and a context word."""
+    return re.findall(
+        rf"^\s*(?:ROOT )?(\S+ = (?:\w+\[{dims}\]\S* copy"
+        rf"|\(\w+\[{dims}\].* copy-start))\(", text, re.M)
+
+
+def test_a_copy_and_a_fetch_ahead_of_a_shape_are_both_found():
+    text = """
+  %copy.7 = bf16[8,625,10,64,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%p.1)
+  %copy-start.9 = (bf16[8,625,10,64,128]{4,3,2,1,0:T(8,128)(2,1)}, bf16[8,625,10,64,128]{4,3,2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%p.2)
+  %copy-done.9 = bf16[8,625,10,64,128]{4,3,2,1,0} copy-done(%copy-start.9)
+  %copy.8 = f32[64,200064]{1,0} copy(%logits)
+"""
+    found = _copies_of(text, "8,625,10,64,128")
+    assert [f.split()[0] for f in found] == ["%copy.7", "%copy-start.9"]
+    assert not _copies_of(text, "1,1329,10,64,128")
+
+
 @pytest.mark.parametrize("entry", ["_paged_step", "_paged_prefill_chunk"])
 def test_three_kinds_of_pool_stay_in_place_on_v5e(topo, for_the_chip,
                                                   entry):
@@ -447,11 +468,17 @@ def test_three_kinds_of_pool_stay_in_place_on_v5e(topo, for_the_chip,
     of weights), 64 slots and the engine's own pools for them: 1,329
     blocks of the full layer, 625 of the eight window layers, 113
     states, 2.44 GB. Every pool leaf is ONE buffer in both programs,
-    aliased from entry to exit, with no copy of its shape; what the
-    programs keep beside them (rehearsal 3, PR 36): the step 0.63 GB,
-    which is the keys and values it gathers for 64 slots (layer 17's
-    once for its eight readers, 0.42 GB, and a window layer's 0.19 GB),
-    the chunk 0.01 GB. Two things this compile refused before any chip
+    aliased from entry to exit, with no copy of its shape, synchronous
+    or fetched ahead (``copy-start``: what a kernel that takes a pool
+    through a ``BlockSpec`` costs, PERF.md section 7). What the
+    programs keep beside them (rehearsal 3, PR 37): the step 54.8 MB, of
+    which 51.2 are the float32 logits of its 64 rows, the chunk 0.01 GB. Until PR 37 the step kept 0.63 GB, the keys and
+    values it gathered for all 64 slots (layer 17's once for its eight
+    readers, 0.42 GB, and a window layer's 0.19 GB); now its sixteen
+    attention reads are calls of ``stpu_paged_diff_attn``, which copies
+    single blocks of the decoding slots out of the pools where they lie,
+    and the chunk, one slot's 9 + 20 blocks, gathers as before and
+    holds no such call. Two things this compile refused before any chip
     call, both the TPU's choice of layouts: with a key/value pair's 10
     pairs next to the lanes (no multiple of 8 sublanes) every pool was
     converted to (rows, lanes) tiles and back around each program (3.7
@@ -492,14 +519,13 @@ def test_three_kinds_of_pool_stay_in_place_on_v5e(topo, for_the_chip,
                      for a in jax.tree.leaves(pool))
     assert pool_bytes == 2_438_379_520
     memory = compiled.memory_analysis()
-    bound = {"_paged_step": 0.70e9, "_paged_prefill_chunk": 0.02e9}[entry]
+    bound = {"_paged_step": 0.06e9, "_paged_prefill_chunk": 0.02e9}[entry]
     assert memory.temp_size_in_bytes < bound, memory.temp_size_in_bytes
     assert memory.alias_size_in_bytes >= pool_bytes
+    assert ("stpu_paged_diff_attn" in text) == (entry == "_paged_step")
     for name, leaf in pool.items():
         dims = ",".join(map(str, leaf.shape))
-        copies = re.findall(
-            rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(", text,
-            re.M)
+        copies = _copies_of(text, dims)
         assert not copies, (name, copies)
         # One layout from entry to exit: the one the leaf arrives in.
         layouts = set(re.findall(rf"\w+\[{dims}\](\{{[\d,]*)", text))
