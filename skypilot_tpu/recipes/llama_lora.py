@@ -38,6 +38,7 @@ import numpy as np
 import optax
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.observability import phases
 from skypilot_tpu.observability import trainstats
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.parallel import mesh as mesh_lib
@@ -133,6 +134,9 @@ def main(argv=None) -> dict:
     return run_lora(llama, cfg, args, recipe_name="llama_lora")
 
 
+_STARTUP_PHASES = ("weights", "compile", "first_loss")
+
+
 def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     """LoRA finetune loop, generic over the dense model families (llama
     and gemma share forward/param_specs/lora_dense; gemma_lora.py passes
@@ -140,6 +144,14 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     setup_t0 = time.perf_counter()
     ctx = distributed.initialize_from_env()
     compile_cache.enable()
+    # Start-up by phase, on the clock a server's start-up uses
+    # (observability/phases.py): ``import`` ended with the process's
+    # first device query (the line above, or an earlier caller's),
+    # ``weights`` runs to the first step's call, ``compile`` is that
+    # call, ``first_loss`` until its loss is on the host.
+    startup = phases.startup_clock(_STARTUP_PHASES)
+    startup_before = phases.startup_seconds()
+    startup.enter("weights")
     if args.seq_len > cfg.max_seq_len:
         raise SystemExit(f"--seq-len {args.seq_len} exceeds model max "
                          f"{cfg.max_seq_len}")
@@ -247,7 +259,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     def record_loss(value: float) -> None:
         nonlocal first_loss_s
         if not losses:
-            first_loss_s = time.perf_counter() - setup_t0
+            first_loss_s = startup.enter(None) - setup_t0
         losses.append(value)
 
     # One-step-delayed loss fetch: each iteration fetches the PREVIOUS
@@ -272,9 +284,12 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
                                            skip=data_start)):
                 data_wait = time.perf_counter() - mark
                 step = start_step + i + 1
-                step_t0 = time.perf_counter()
+                step_t0 = (startup.enter("compile") if i == 0
+                           else time.perf_counter())
                 lora, opt_state, loss = step_fn(base, lora, opt_state,
                                                 jnp.asarray(tokens))
+                if i == 0:
+                    startup.enter("first_loss")
                 dispatch_s = time.perf_counter() - step_t0
                 fetched = None
                 prev = delayed.rotate(loss)
@@ -333,6 +348,7 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
         if trainstats.ENABLED:
             trainstats.dump_flight("train_crash", error=repr(e))
         raise
+    startup.enter(None)     # a run of no step never left ``weights``
     if saver is not None:
         saver.wait()
 
@@ -342,6 +358,11 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
     # Host copy for reporting: the adapters are tiny, and counting the
     # device tree directly would sync it into the metrics print.
     lora_host = jax.device_get(lora)
+    took = phases.startup_seconds()
+    startup_took = {p: round(took[p] - startup_before[p], 3)
+                    for p in _STARTUP_PHASES}
+    if "import" in took:        # the process's, not this call's
+        startup_took["import"] = round(took["import"], 3)
     metrics = {
         "recipe": recipe_name,
         "model": args.model,
@@ -359,6 +380,12 @@ def run_lora(model_lib, cfg, args, recipe_name: str) -> dict:
         # init, the step's compile and one step.
         "start_to_first_loss_seconds": (
             round(first_loss_s, 2) if first_loss_s is not None else None),
+        # The same by phase: this call's weights, compile and
+        # first_loss add up to the line above less what the call spent
+        # before ``weights`` (the gang's rendezvous and, in a process
+        # whose first device query this call made, the back end's
+        # start, which are the process's ``import``).
+        "startup_seconds": startup_took,
         # Which attention implementation the step was traced into
         # (ops/attention.py TRACES): a shape that fell back to the
         # O(S^2) reference shows here, not only in the step time.

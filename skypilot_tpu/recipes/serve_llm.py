@@ -41,6 +41,7 @@ from skypilot_tpu.agent import constants as agent_constants
 from skypilot_tpu.models import (brumby, deepseek, family_name, gemma,
                                  llama, mixtral, model_api, phi4flash)
 from skypilot_tpu.observability import metrics
+from skypilot_tpu.observability import phases
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
 from skypilot_tpu.observability import tracing
@@ -690,6 +691,7 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
         spec_ngram = ENGINE_SPEC_NGRAM
     if spec_min_accept is None:
         spec_min_accept = ENGINE_SPEC_MIN_ACCEPT
+    phases.import_done()
     ctx = {"ready": ready_event or threading.Event(),
            "stream_timeout": float(stream_timeout), "gang": gang,
            "warmup_error": None, "device": mesh_lib.device_info(),
@@ -708,18 +710,21 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
             # lockstep or the gang serves from desynced caches.
             gang.broadcast_restart()
         first_build[0] = False
-        return decode_engine.DecodeEngine(
-            cfg, params, slots=engine_slots,
-            max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
-            prefix_cache_mb=prefix_cache_mb,
-            mesh=mesh, rules=rules,
-            kv_pool_blocks=int(kv_pool_blocks),
-            kv_block_tokens=int(kv_block_tokens),
-            kv_quant=bool(kv_quant),
-            weight_quant=bool(weight_quant),
-            spec_k=int(spec_k),
-            spec_ngram=int(spec_ngram),
-            spec_min_accept=float(spec_min_accept))
+        # Start-up phase ``engine``: the pool laid out and allocated,
+        # the trie. A restart comes through here again and adds to it.
+        with phases.startup_phase("engine"):
+            return decode_engine.DecodeEngine(
+                cfg, params, slots=engine_slots,
+                max_seq=MAX_PROMPT_TOKENS + MAX_GEN_TOKENS,
+                prefix_cache_mb=prefix_cache_mb,
+                mesh=mesh, rules=rules,
+                kv_pool_blocks=int(kv_pool_blocks),
+                kv_block_tokens=int(kv_block_tokens),
+                kv_quant=bool(kv_quant),
+                weight_quant=bool(weight_quant),
+                spec_k=int(spec_k),
+                spec_ngram=int(spec_ngram),
+                spec_min_accept=float(spec_min_accept))
 
     ctx["engine"] = decode_engine.EngineSupervisor(
         _engine_factory, max_restarts=engine_max_restarts,
@@ -732,11 +737,14 @@ def serve(cfg: llama.LlamaConfig, params, port: int,
 
     def warmup():
         try:
-            if gang is not None and not gang.wait_ready():
-                raise gang_replica.GangError(
-                    f"the serving gang of {gang.topology.hosts} hosts "
-                    f"did not form: {gang.members_info()}")
-            ctx["engine"].warmup()
+            # Start-up phase ``warmup``: the chunk's and the step's
+            # programs built (or read from the cache) and run once.
+            with phases.startup_phase("warmup"):
+                if gang is not None and not gang.wait_ready():
+                    raise gang_replica.GangError(
+                        f"the serving gang of {gang.topology.hosts} "
+                        f"hosts did not form: {gang.members_info()}")
+                ctx["engine"].warmup()
         except Exception as e:  # noqa: BLE001 — thread boundary: a
             # warm-up that dies in silence leaves /health "warming"
             # for ever; record the cause where probes read it.
@@ -827,13 +835,20 @@ def init_params(cfg, seed: int, mesh=None, rules=None):
     each leaf is generated directly into its sharding, so a model
     larger than one chip (gemma-7b over tp=4) never materialises on
     one. The values do not depend on the sharding."""
+    phases.import_done()
     api = model_api(cfg)
-    shardings = None
-    if mesh is not None:
-        shardings = mesh_lib.tree_shardings(mesh, rules,
-                                            api.param_specs(cfg))
-    return jax.jit(functools.partial(api.init, cfg),
-                   out_shardings=shardings)(jax.random.PRNGKey(seed))
+    # Start-up phase ``weights``: the init traced, built (or read from
+    # the cache) and dispatched. It ends on the host's return; what
+    # the device still has to fill runs under the engine's build and
+    # the warm-up's compile, which wait for it where they need it.
+    with phases.startup_phase("weights"):
+        shardings = None
+        if mesh is not None:
+            shardings = mesh_lib.tree_shardings(mesh, rules,
+                                                api.param_specs(cfg))
+        return jax.jit(functools.partial(api.init, cfg),
+                       out_shardings=shardings)(
+                           jax.random.PRNGKey(seed))
 
 
 def _spawn_follower_cmd(args, rank: int, topology, leader_port: int):

@@ -114,6 +114,7 @@ import numpy as np
 from skypilot_tpu.models import family_name, model_api
 from skypilot_tpu.observability import events
 from skypilot_tpu.observability import metrics
+from skypilot_tpu.observability import phases
 from skypilot_tpu.observability import reqlog
 from skypilot_tpu.observability import stepstats
 from skypilot_tpu.observability import tracing
@@ -188,11 +189,6 @@ _STATE_SNAPSHOTS = metrics.counter(
     "(the block a later chunk read and did not write), restored = an "
     "admission started from one, evicted = LRU took one back.",
     ("event",))
-_STATE_BLOCKS = metrics.gauge(
-    "stpu_engine_state_blocks",
-    "State pool blocks by holder (state families only): slot = a live "
-    "sequence's own state, snapshot = a prefix-trie node, free.",
-    ("kind",))
 _CACHE_BLOCKS = metrics.gauge(
     "stpu_engine_cache_blocks",
     "Pool blocks in use by kind: global / window = distinct blocks of "
@@ -240,11 +236,6 @@ _SPEC_ACCEPT_RATE = metrics.histogram(
     "stpu_engine_spec_accept_rate",
     "Per-verify-step draft acceptance rate (accepted / drafted).",
     buckets=(0.0, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0))
-_RESUME_ADMITS = metrics.counter(
-    "stpu_engine_resume_admissions_total",
-    "Requests admitted with a resume extension (prior-emitted tokens "
-    "prefilled as prompt, emission continuing at the original "
-    "absolute position).")
 _RESTARTS = metrics.counter(
     "stpu_engine_restarts_total",
     "Engine restarts by the supervisor after a compute-loop crash.")
@@ -312,46 +303,43 @@ _LOOP_SECONDS = metrics.counter(
     "stpu_engine_loop_seconds_total",
     "Engine-thread seconds by loop phase; the phases partition an "
     "iteration, so their sum is the thread's time.", ("phase",))
+# The loop's clock is observability/phases.py's: ``schedule.*`` ends
+# where its program is dispatched, ``fetch`` is every blocking read of
+# a device value, ``emit`` what the host does with the tokens, ``wait``
+# the idle condition wait.
 _PHASE_SECONDS = {p: _LOOP_SECONDS.labels(phase=p) for p in (
     "schedule.admit", "schedule.prefill", "schedule.decode", "fetch",
     "emit", "wait")}
-
-
-class _PhaseClock:
-    """Which phase of its iteration the engine loop is in, for the
-    engine thread alone: :meth:`enter` closes the phase before and
-    opens the named one at the same instant, as a
-    ``stpu.engine.<phase>`` span on the host plane of whatever
-    ``jax.profiler`` trace is running (an inactive-tracer test
-    otherwise) and as seconds on ``stpu_engine_loop_seconds_total``.
-    A switch and not a ``with`` block, so that the phases partition
-    the thread's time by construction: what Python does between two
-    blocks (a returning frame drops the step's device arrays and the
-    handler threads take the GIL: 1-2 ms a step on the chip, PERF.md
-    PR 26) belongs to the phase it ends.
-    ``schedule.*`` ends where its program is dispatched, ``fetch`` is
-    every blocking read of a device value, ``emit`` what the host does
-    with the tokens, ``wait`` the idle condition wait."""
-
-    __slots__ = ("_seconds", "_span", "_t0")
-
-    def __init__(self):
-        self._span = None
-
-    def enter(self, phase: Optional[str]) -> float:
-        """End the open phase and begin ``phase`` (None: the loop is
-        over, begin none); returns the instant of the switch."""
-        now = time.perf_counter()
-        if self._span is not None:
-            self._seconds.inc(now - self._t0)
-            self._span.__exit__(None, None, None)
-            self._span = None
-        if phase is not None:
-            self._seconds, self._t0 = _PHASE_SECONDS[phase], now
-            # A TraceMe starts when it is built.
-            self._span = jax.profiler.TraceAnnotation(
-                "stpu.engine." + phase)
-        return now
+# Why the device waited, counted by the loop itself: the device is
+# idle in live traffic exactly when a program is dispatched and the
+# newest program dispatched before it has already ended, which the
+# host sees without blocking (``is_ready()`` of that program's
+# result; 0.2-0.3 us a poll on the chip, PERF.md PR 38).
+_STARVED = metrics.counter(
+    "stpu_engine_starved_dispatches_total",
+    "Programs dispatched into a device whose queue had drained (the "
+    "newest program dispatched before had already ended), by kind; "
+    "over stpu_engine_steps_total it says how often the host kept "
+    "the device waiting.", ("kind",))
+_STARVED_KIND = {k: _STARVED.labels(kind=k)
+                 for k in ("decode", "verify", "prefill")}
+_DRAINED_SECONDS = metrics.counter(
+    "stpu_engine_drained_seconds_total",
+    "Engine-thread seconds, by loop phase, from the first phase "
+    "switch that found the device's queue drained to the next "
+    "dispatch: a LOWER bound on the device's idle time (the queue "
+    "drained somewhere inside the phase before that switch). Seconds "
+    "under wait are an engine with no live work.", ("phase",))
+_DRAINED = {p: _DRAINED_SECONDS.labels(phase=p) for p in _PHASE_SECONDS}
+_LONG_PHASES = metrics.counter(
+    "stpu_engine_long_phases_total",
+    "Instances of a loop phase that lasted phases.LONG_PHASE_S (0.06 "
+    "s) or more: a pause of the engine thread, named by the phase it "
+    "struck (wait, whose condition wait times out, is never counted).",
+    ("phase",))
+_LONG_PHASE_SECONDS = metrics.counter(
+    "stpu_engine_long_phase_seconds_total",
+    "Seconds of those instances, whole.", ("phase",))
 
 
 _DONE = object()          # end-of-stream sentinel on a request's queue
@@ -376,7 +364,6 @@ class Request:
         # continues the stream bit-identically from position
         # len(prompt) + len(resume). max_tokens stays "tokens still to
         # generate" — the caller subtracts what was already emitted.
-        self.resume_len = len(resume) if resume else 0
         if resume:
             self.prompt.extend(int(t) for t in resume)
         self.max_tokens = int(max_tokens)
@@ -1095,7 +1082,17 @@ class DecodeEngine:
         _WEIGHT_QUANT_ENABLED.set(int(self._weight_quant))
         self._waiting: "collections.deque[Request]" = collections.deque()
         self._cond = threading.Condition()
-        self._phase = _PhaseClock()     # engine thread only
+        # Engine thread only, both: the loop's phase and, beside it,
+        # whether the device's queue is known to have drained
+        # (:meth:`_enter`, :meth:`_dispatching`).
+        self._phase = phases._PhaseClock(
+            "stpu.engine.", _LOOP_SECONDS, tuple(_PHASE_SECONDS),
+            long_count=_LONG_PHASES, long_seconds=_LONG_PHASE_SECONDS,
+            long_phases=[p for p in _PHASE_SECONDS if p != "wait"])
+        self._newest = None         # a result of the newest program
+        self._drained_in: Optional[str] = None   # the phase it is
+        self._drained_t0 = 0.0      # charged to, and from when
+        self._starved_span = None
         self._stop = False
         self._draining = False
         self._thread: Optional[threading.Thread] = None
@@ -1137,8 +1134,6 @@ class DecodeEngine:
             raise EngineError("empty prompt")
         if req.max_tokens < 1:
             raise EngineError("max_tokens must be >= 1")
-        if req.resume_len:
-            _RESUME_ADMITS.inc()
         # The admission bound is POOL CAPACITY, not a per-slot row
         # length: a request fits if its worst-case block count does.
         need = self._pool.blocks_for(len(req.prompt) + req.max_tokens)
@@ -1320,6 +1315,64 @@ class DecodeEngine:
         return out
 
     # ------------------------------------------------------------ internals
+    # ------------------------------------------ why the device waited
+    def _device_drained(self) -> bool:
+        """Has the newest program dispatched already ended? One
+        non-blocking poll of one of its results."""
+        return self._newest is not None and self._newest.is_ready()
+
+    def _enter(self, phase: Optional[str]) -> float:
+        """Switch the loop's phase and keep the drained account beside
+        it. At the first switch that finds the device's queue drained
+        the loop opens a ``stpu.engine.starved`` span and polls no
+        more; from that instant to the next dispatch
+        (:meth:`_dispatching`) each phase's seconds also go to
+        ``stpu_engine_drained_seconds_total{phase}``. A lower bound on
+        the device's idle time: the queue drained somewhere inside the
+        phase BEFORE that switch. Once the loop has waited with the
+        queue drained the engine had run dry, and everything up to the
+        next dispatch stays under ``wait``: an idle loop's looks for
+        work, and the admission that ends the spell, are no slow host
+        (they read as 0.18 % of an idle engine's time under
+        ``schedule`` before; PERF.md PR 38)."""
+        now = self._phase.enter(phase)
+        if self._drained_in is not None:
+            _DRAINED[self._drained_in].inc(now - self._drained_t0)
+            self._drained_t0 = now
+            if phase is None:
+                self._starved_span.__exit__(None, None, None)
+                self._drained_in = self._starved_span = None
+            elif self._drained_in != "wait":
+                self._drained_in = phase
+        elif phase is not None and self._device_drained():
+            self._drained_in, self._drained_t0 = phase, now
+            self._starved_span = jax.profiler.TraceAnnotation(
+                "stpu.engine.starved")
+        return now
+
+    def _dispatching(self, kind: Optional[str]) -> None:
+        """The next statement dispatches a program of ``kind`` (None:
+        one that is not counted by kind). If the device's queue has
+        drained, this is a STARVED dispatch: the device sat idle until
+        now. Close the drained account and the span here, before the
+        call, so that the span ends inside the device's idle gap (a
+        launch that follows an idle device is the anchor
+        benchmarks/host_spans.py needs between the two planes'
+        clocks)."""
+        if self._drained_in is not None:
+            _DRAINED[self._drained_in].inc(
+                time.perf_counter() - self._drained_t0)
+            self._starved_span.__exit__(None, None, None)
+            self._drained_in = self._starved_span = None
+        elif self._device_drained():
+            # Drained since the last switch: a span of an instant.
+            jax.profiler.TraceAnnotation(
+                "stpu.engine.starved").__exit__(None, None, None)
+        else:
+            return
+        if kind is not None:
+            _STARVED_KIND[kind].inc()
+
     def _live(self) -> List[int]:
         return [i for i, s in enumerate(self._slots) if s.request]
 
@@ -1770,10 +1823,6 @@ class DecodeEngine:
         if lay.state_blocks:
             snapshots = self.prefix_cache.stats()["chunks"]
             _CACHE_BLOCKS.labels(kind="snapshot").set(snapshots)
-            _STATE_BLOCKS.labels(kind="slot").set(len(named["state"]))
-            _STATE_BLOCKS.labels(kind="snapshot").set(snapshots)
-            _STATE_BLOCKS.labels(kind="free").set(
-                self._state_pool.free_blocks())
 
     def _slot_blocks(self, i: int) -> Dict[str, List[int]]:
         """The blocks slot ``i``'s table row names, by kind: its token
@@ -1852,7 +1901,7 @@ class DecodeEngine:
         (0 = no prefill work) — truthy exactly when work happened, and
         the per-step telemetry's prefill-token count when stepstats is
         armed."""
-        self._phase.enter("schedule.prefill")
+        self._enter("schedule.prefill")
         for i, slot in enumerate(self._slots):
             req = slot.request
             if req is None or slot.prefilled >= len(req.prompt):
@@ -1902,10 +1951,13 @@ class DecodeEngine:
             wb = self._ensure_block(i, start // self._chunk)
             if self._layout.state_blocks:
                 wb = self._chunk_target(i, start)
+            row = self._table_upload(i)
+            self._dispatching("prefill")
             self._toks, self._cache = _paged_prefill_chunk(
-                self._cfg, self._params, self._cache, buf,
-                self._table_upload(i), jnp.int32(start),
-                jnp.int32(valid), jnp.int32(wb), self._window, *first)
+                self._cfg, self._params, self._cache, buf, row,
+                jnp.int32(start), jnp.int32(valid), jnp.int32(wb),
+                self._window, *first)
+            self._newest = self._toks
             if self._layout.state_blocks:
                 self._table[i, 0] = wb     # the slot's state, from now
             req.prefill_chunks += 1
@@ -1938,8 +1990,10 @@ class DecodeEngine:
             self.prefix_cache.promote(node, block)
             parts = {k: jnp.asarray(v) for k, v in payload.items()}
             _STEP_KIND["restore"].inc()
+            self._dispatching(None)
             self._cache = _host_restore_block(
                 self._cache, jnp.int32(block), parts)
+            self._newest = next(iter(self._cache.values()))
             self._readmitted_blocks += 1
             _KV_HOST_READMITS.inc()
         else:
@@ -2002,9 +2056,9 @@ class DecodeEngine:
         due = self._behind + self._fresh if everything else self._behind
         if not due:
             return
-        self._phase.enter("fetch")
+        self._enter("fetch")
         fetched = jax.device_get([(e.toks, e.routing) for e in due])
-        now = self._phase.enter("emit")
+        now = self._enter("emit")
         for entry, (toks, routing) in zip(due, fetched):
             rows = entry.rows
             if entry.t0 is not None:
@@ -2157,15 +2211,18 @@ class DecodeEngine:
                            (slot.pos + int(spec_np[i]))
                            // self._chunk + 1):
                 self._ensure_block(i, j)
+        table = self._step_table(live)
+        self._dispatching("verify")
         targets, accepts, self._toks, self._cache = _paged_spec_step(
             self._cfg, self._params, self._cache, self._toks,
-            jnp.asarray(drafts_np), pos, jnp.asarray(spec_np),
-            self._step_table(live), self._window, temps, seeds)
+            jnp.asarray(drafts_np), pos, jnp.asarray(spec_np), table,
+            self._window, temps, seeds)
+        self._newest = self._toks
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, accepts)
-        self._phase.enter("fetch")
+        self._enter("fetch")
         targets, accepts = jax.device_get((targets, accepts))
-        now = self._landed_at = self._phase.enter("emit")
+        now = self._landed_at = self._enter("emit")
         dt = max(now - t0, 1e-9)
         if reqlog.ENABLED:
             # Per-request device-time share (see _land).
@@ -2251,13 +2308,13 @@ class DecodeEngine:
         Returns the number of rows dispatched; when 0, nothing is
         left unread either (whoever drives the engine by hand loops
         until this and ``_prefill_one`` return 0)."""
-        self._phase.enter("schedule.decode")
+        self._enter("schedule.decode")
         drafting = self._spec_k and any(
             s.request is not None and not s.spec_off
             for s in self._slots)
         if drafting and (self._behind or self._fresh):
             self._land(everything=True)
-            self._phase.enter("schedule.decode")
+            self._enter("schedule.decode")
         live = []
         for i, slot in enumerate(self._slots):
             req = slot.request
@@ -2292,13 +2349,16 @@ class DecodeEngine:
         # admission is preemption-free).
         for i in live:
             self._ensure_block(i, self._slots[i].pos // self._chunk)
+        table = self._step_table(live)
+        self._dispatching("decode")
         nxt, self._cache = _paged_step(
             self._cfg, self._params, self._cache, self._toks, pos,
-            self._step_table(live), self._window, temps, seeds)
+            table, self._window, temps, seeds)
         if stepstats.ENABLED:
             self._stamp_dispatch(t0, nxt)
         self._toks, routing = nxt if isinstance(nxt, tuple) \
             else (nxt, None)
+        self._newest = self._toks
         rows = []
         for i in live:
             slot = self._slots[i]
@@ -2350,16 +2410,16 @@ class DecodeEngine:
                 # What the iteration before dispatched is read by this
                 # one whatever else it does: that is work too.
                 owed = bool(self._behind)
-                self._phase.enter("schedule.admit")
+                self._enter("schedule.admit")
                 self._admit()
                 pf = self._prefill_one()
                 dc = self._decode_step()
                 did = bool(pf or dc or owed)
                 if armed and did:
-                    self._phase.enter("emit")
+                    self._enter("emit")
                     self._record_step(t0, pf, dc)
                 if not did:
-                    self._phase.enter("wait")
+                    self._enter("wait")
                     with self._cond:
                         if not self._waiting and not self._stop:
                             self._cond.wait(timeout=0.05)
@@ -2383,7 +2443,7 @@ class DecodeEngine:
             self._land(everything=True)
         except Exception:  # noqa: stpu-except — the device is gone and the unread tokens with it; their requests end below
             pass
-        self._phase.enter(None)
+        self._enter(None)
         for entry in self._behind + self._fresh:
             for _, req, last in entry.rows:
                 if last is not None:

@@ -17,6 +17,7 @@ compile:
 """
 from __future__ import annotations
 
+import gc
 import os
 import pathlib
 import threading
@@ -72,16 +73,25 @@ def cache_dir() -> str:
 
 def enable() -> str:
     """Turn the persistent cache on by the rule above and start
-    counting compilations (once per process); returns the directory.
-    Programs that compile in under half a second are not worth a file
-    each; any size is."""
+    counting compilations and the garbage collector's seconds (once
+    per process); returns the directory. Programs that compile in
+    under half a second are not worth a file each; any size is. The
+    process's first device query is made here if nothing made it
+    before (start-up phase ``import`` ends with it,
+    observability/phases.py), so a gang calls
+    ``distributed.initialize_from_env()`` first, as every recipe
+    does."""
     import jax
+
+    from skypilot_tpu.observability import phases
     global _listening
     if not _listening:
         _listening = True
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        gc.callbacks.append(phases.on_gc)
     if ENV not in os.environ:
         jax.config.update("jax_compilation_cache_dir", str(_IN_CHECKOUT))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    phases.import_done()
     return cache_dir()

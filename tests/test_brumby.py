@@ -433,9 +433,10 @@ def test_pool_accounting_and_gauges(params):
             break
     assert len(req.result(timeout=5.0)) == 3
     engine._admit()
-    kinds = {k: _counter("stpu_engine_state_blocks", kind=k)
-             for k in ("slot", "snapshot", "free")}
-    assert kinds == {"slot": 0.0, "snapshot": 1.0, "free": 3.0}
+    kinds = {k: _counter("stpu_engine_cache_blocks", kind=k)
+             for k in ("state", "snapshot")}
+    assert kinds == {"state": 0.0, "snapshot": 1.0}
+    assert engine._state_pool.free_blocks() == 3
 
 
 def test_unknown_family_gets_the_default_tuning_silently(tmp_path,
