@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.ops.pallas import paged_attention, paged_mla_attention
 
 Params = Dict[str, Any]
 
@@ -563,13 +564,17 @@ def paged_attention_block(cfg: DeepseekV3Config, x: jax.Array,
                           start_pos: jax.Array, valid_len: jax.Array,
                           window: int,
                           write_block: Optional[jax.Array],
-                          write_pos: Optional[jax.Array]):
+                          write_pos: Optional[jax.Array],
+                          reads: Optional[paged_attention.Reads] = None):
     """Pre-norm MLA residual block against the latent paged pool,
     carried whole: the new rows scatter to ``[li, block, offset]``
     (llama's three ways of naming the targets), then the context is
-    gathered through the table ``window`` rows at a time. A chunk
-    (B == 1, T == block_tokens) attends in the expanded form, a decode
-    step or a verify window in the absorbed form. Returns
+    read. A chunk (B == 1, T == block_tokens) attends in the expanded
+    form, a verify window in the absorbed form, both over the context
+    gathered through the table ``window`` rows at a time. A decode step
+    (``reads``: what :func:`paged_attention.step_reads` lists) attends
+    in the absorbed form over the decoding slots' own blocks where they
+    lie (ops/pallas/paged_mla_attention.py). Returns
     (x + attention, pool)."""
     b, t = x.shape[0], x.shape[1]
     bt = pool["c_kv"].shape[2]
@@ -587,6 +592,14 @@ def paged_attention_block(cfg: DeepseekV3Config, x: jax.Array,
             "k_r": pool["k_r"].at[li, blk, off].set(
                 _to_bytes(k_r.astype(cfg.dtype)))}
 
+        if reads is not None:
+            q_latent = jnp.einsum("bthn,hnc->bthc", q_nope, lp["w_uk"])
+            out = paged_mla_attention.attend(
+                q_latent[:, 0], q_rope[:, 0], pool["c_kv"], pool["k_r"],
+                li, reads, softmax_scale(cfg))
+            attn = jnp.einsum("bthc,hcv->bthv", out[:, None], lp["w_uv"])
+            return x + attn.reshape(b, t, -1) @ lp["wo"], pool
+
         def tiles(s0):
             phys = jax.lax.dynamic_slice(
                 table, (jnp.int32(0), s0 // bt), (b, nb_win))
@@ -602,6 +615,42 @@ def paged_attention_block(cfg: DeepseekV3Config, x: jax.Array,
                        jnp.minimum(limit, table.shape[1] * bt),
                        absorbed=t == 1 or write_pos is not None)
         return x + attn @ lp["wo"], pool
+
+
+def _decode_reads(table: jax.Array, pos: jax.Array, valid_len: jax.Array,
+                  block_tokens: int) -> paged_attention.Reads:
+    """What a decode step's kernel calls read: ``step_reads`` over the
+    decoding rows (``table[:, 0] != 0``). The engine's table is as wide
+    as the pool (1,280 columns for 64 slots of 20), and a list over all
+    of it is 64 x 640 entries, whose making cost 0.84 ms a step on the
+    chip (PERF.md). So the list is made over a slot's share of
+    the columns, ``ceil(width / slots)``, and padded to the whole
+    table's list, whenever no decoding slot holds a block past that
+    share; else over the whole table. The kernel walks ``total``
+    entries either way."""
+    b, span = table.shape
+    share = -(-span // b)
+    live = table[:, 0] != 0
+
+    def over(cols):
+        reads = paged_attention.step_reads(table[:, :cols], live, pos,
+                                           valid_len, block_tokens)
+        if cols == span:
+            return reads
+        # Entries past ``total``: the last slot, block 0, position 0.
+        entries = b * -(-span // paged_attention.FOLD)
+        return reads._replace(
+            slot=jnp.pad(reads.slot, (0, entries - reads.slot.shape[0]),
+                         constant_values=b - 1),
+            phys=jnp.pad(reads.phys, (0, paged_attention.FOLD * entries
+                                      - reads.phys.shape[0])),
+            kbase=jnp.pad(reads.kbase, (0, entries - reads.kbase.shape[0])))
+
+    if share >= span:
+        return over(span)
+    newest = jnp.max(jnp.where(live, jnp.minimum(pos, valid_len - 1), 0))
+    return jax.lax.cond(newest < share * block_tokens,
+                        lambda: over(share), lambda: over(span))
 
 
 # ------------------------------------------------------- forward passes
@@ -656,6 +705,12 @@ def forward_with_paged_cache(cfg: DeepseekV3Config, params: Params,
     the program computed; the engine counts both per decode step. Two
     scans, dense layers then sparse ones, both carrying the pool.
 
+    A decode step (T == 1, no ``write_pos``) reads each DECODING slot's
+    latent blocks where they lie in the pool, one kernel call a layer
+    over one list (:func:`_decode_reads`), and its third
+    result ends in one more element: the blocks its calls fetched,
+    int32. A chunk and a verify window gather the context tile by tile.
+
     The rows that COUNT for the expert layer: the positions under
     ``valid_len`` (a chunk's padded tail does not), and in a decode
     step or a verify window only the rows whose table names a block of
@@ -671,6 +726,10 @@ def forward_with_paged_cache(cfg: DeepseekV3Config, params: Params,
     counts = positions < valid_len[:, None]
     if t == 1 or write_pos is not None:
         counts &= table[:, :1] != 0
+    reads = None
+    if t == 1 and write_pos is None:
+        reads = _decode_reads(table, start_pos, valid_len,
+                              cache["c_kv"].shape[2])
     x = llama._decode_embed(cfg, params, tokens)
 
     def stack_scan(x, pool, stack, first, mlp):
@@ -681,7 +740,8 @@ def forward_with_paged_cache(cfg: DeepseekV3Config, params: Params,
             lp, i = scanned
             x, pool = paged_attention_block(
                 cfg, x, lp, first + i, pool, table, positions,
-                start_pos, valid_len, window, write_block, write_pos)
+                start_pos, valid_len, window, write_block, write_pos,
+                reads)
             x, chosen = mlp(cfg, x, {**lp, **whole}, counts, i)
             return (x, pool), chosen
         n = stack["attn_norm"].shape[0]
@@ -694,7 +754,10 @@ def forward_with_paged_cache(cfg: DeepseekV3Config, params: Params,
     computed = jax.vmap(experts_asked, (0, None))(chosen, counts)
     logits = llama.lm_head(cfg, params, llama.read_out(x, logits_at),
                            lambda a, _spec: a)
-    return logits, pool, (chosen.transpose(1, 2, 0, 3), computed)
+    report = (chosen.transpose(1, 2, 0, 3), computed)
+    if reads is not None:
+        report += (reads.fetched() * cfg.n_layers,)
+    return logits, pool, report
 
 
 def verify_step_paged(cfg: DeepseekV3Config, params: Params,

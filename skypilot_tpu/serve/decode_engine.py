@@ -529,10 +529,11 @@ class _Unread:
     vector is the request's next token, an outcome says that it is
     its last (the slot was retired when the program was dispatched),
     and no index means no token, only the end (a cancel). ``routing``
-    is a step's two held-expert arrays beside the tokens (deepseek:
-    chosen by each row, computed by the program) or the one count of
-    key/value blocks its attention read (phi4flash), ``t0`` a decode
-    step's dispatch instant (None for a prefill chunk)."""
+    is a step's report beside the tokens (:func:`_paged_step`): the
+    count of key/value blocks its attention read, after deepseek's two
+    held-expert arrays (chosen by each row, computed by the program);
+    ``t0`` a decode step's dispatch instant (None for a prefill
+    chunk)."""
 
     __slots__ = ("toks", "routing", "rows", "t0")
 
@@ -624,23 +625,21 @@ def _paged_step(cfg, params, cache, toks, pos, table, window, temps,
     scratch block; :meth:`DecodeEngine._step_table`) and are ignored
     host-side. The pool is donated (in-place update).
 
-    A family whose forward reports which held experts each token chose
-    and which of them it computed (deepseek: a third result, (B, T,
-    sparse layers, held) and (sparse layers, held) bool) gets them
-    back beside the tokens, ``((nxt, (chosen, computed)), pool)``, so
-    that the step's one blocking fetch brings all three. A family whose
-    step counts the key/value blocks its attention read (phi4flash: a
-    third result of one scalar, ``(blocks,)``) gets that back the same
-    way: the last of a report is the whole step's, what comes before it
-    has a row a slot."""
+    A family whose step reports what it did gets the report back beside
+    the tokens, ``((nxt, report), pool)``, so that the step's one
+    blocking fetch brings it: a report ends in the key/value blocks the
+    step's attention fetched (one scalar; phi4flash's is that alone),
+    and deepseek's holds before it which held experts each token chose
+    ((B, T, sparse layers, held) bool, handed on with a row a slot) and
+    which of them the program computed ((sparse layers, held) bool)."""
     api = model_api(cfg)
     logits, cache, *routing = api.forward_with_paged_cache(
         cfg, params, toks[:, None], cache, table, pos, window=window)
     logits = logits[:, -1]
     nxt = _sample(logits, seeds, pos + 1, temps)
     if routing:
-        *by_row, whole = routing[0]
-        nxt = (nxt, (*(rows[:, 0] for rows in by_row), whole))
+        first, *rest = routing[0]
+        nxt = (nxt, (first[:, 0], *rest) if rest else (first,))
     return nxt, cache
 
 
@@ -2062,8 +2061,8 @@ class DecodeEngine:
         """Read what the iteration before this one left on the device
         (``everything``: this one's dispatches too) in ONE blocking
         fetch, and hand the tokens on: the FETCH half of a slot's
-        life — client queues, the latency histograms, deepseek's
-        routing counters, the end of every request whose last token
+        life — client queues, the latency histograms, the step's
+        report's counters, the end of every request whose last token
         this brings. It touches no slot but to keep a drafting slot's
         history."""
         due = self._behind + self._fresh if everything else self._behind
@@ -2076,10 +2075,11 @@ class DecodeEngine:
             rows = entry.rows
             if entry.t0 is not None:
                 live = [i for i, _, _ in rows if i is not None]
-                if routing is not None and len(routing) == 1:
-                    _ATTN_BLOCKS_READ.inc(int(routing[0]))
-                elif routing is not None:
-                    chosen, computed = routing
+                *moe, blocks = routing or (None,)
+                if blocks is not None:
+                    _ATTN_BLOCKS_READ.inc(int(blocks))
+                if moe:
+                    chosen, computed = moe
                     chosen = chosen[live]      # (live, layers, held)
                     _MOE_ROUTED.inc(int(chosen.sum()))
                     _MOE_HIT.inc(int(chosen.any(axis=0).sum()))

@@ -17,6 +17,7 @@ import pytest
 
 from benchmarks.reference import deepseek_arch as ref
 from skypilot_tpu.models import deepseek, family_name, model_api
+from skypilot_tpu.ops.pallas import paged_attention
 from skypilot_tpu.observability import metrics
 from skypilot_tpu.serve import decode_engine, gang_replica, kv_pool
 from skypilot_tpu.serve.decode_engine import DecodeEngine
@@ -153,6 +154,113 @@ def test_verify_window_equals_decode_steps():
             cfg, params, jnp.asarray(toks[8:12])[None], pool, table,
             jnp.asarray([8]), jnp.asarray([3]), window=16)
     assert float(jnp.abs(got[0] - want[8:12]).max()) < 1e-4
+
+
+def _loop_step(cfg, params, toks, pool, table, pos):
+    """The decode step as the tile loop computes it: the same 1-token
+    forward with its write positions named, which takes the absorbed
+    tile loop over every slot to the longest one's length."""
+    return deepseek.forward_with_paged_cache(
+        cfg, params, toks, pool, table, pos, window=16,
+        write_pos=pos[:, None])
+
+
+def test_decode_steps_give_the_tile_loops_tokens_and_pool():
+    """Four slots, one of which does not decode, prefilled to 8, 16 and
+    16 tokens in chunks, then ten greedy steps through the kernel and
+    through the tile loop, each feeding its own tokens: the same tokens,
+    logits within float32's rounding, the first layer's latent rows and
+    roped keys equal to the bit (they depend on the tokens alone) and
+    the later layers' within rounding. The step reports the blocks its
+    kernel calls fetched: ``step_reads``' list once a layer. A slot's
+    share of the 12 columns is 3 blocks: the first eight steps make
+    the list over 3 columns, the last two, at positions 24 and 25, over
+    all 12."""
+    cfg = _tiny()
+    params = deepseek.init(cfg, jax.random.key(0))
+    bt, slots = 8, 4
+    pool = deepseek.init_paged_cache(cfg, 64, bt)
+    table = np.zeros((slots, 12), np.int32)
+    for i, n in enumerate((1, 2, 0, 2)):      # prompt blocks a slot
+        table[i] = np.arange(1 + 12 * i, 13 + 12 * i) if n else 0
+    table = jnp.asarray(table)
+    pos = jnp.asarray([8, 16, 0, 16], jnp.int32)
+    for i in (0, 1, 3):
+        for c in range(int(pos[i]) // bt):
+            _, pool, _ = deepseek.forward_with_paged_cache(
+                cfg, params,
+                jnp.asarray(_tokens(bt, seed=10 * i + c))[None], pool,
+                table[i:i + 1], jnp.int32(c * bt), window=16,
+                write_block=table[i, c])
+    step = jax.jit(lambda toks, pool, pos: deepseek.forward_with_paged_cache(
+        cfg, params, toks, pool, table, pos, window=16))
+    loop = jax.jit(lambda toks, pool, pos: _loop_step(
+        cfg, params, toks, pool, table, pos))
+    toks = jnp.asarray(_tokens(slots, seed=99))[:, None]
+    got, want = (toks, pool), (toks, pool)
+    live = np.asarray(table[:, 0] != 0)
+    for k in range(10):
+        g_logits, g_pool, report = step(got[0], got[1], pos + k)
+        w_logits, w_pool, _ = loop(want[0], want[1], pos + k)
+        g_logits, w_logits = np.asarray(g_logits), np.asarray(w_logits)
+        scale = np.abs(w_logits[live]).max()
+        assert np.abs(g_logits - w_logits)[live].max() < 1e-5 * scale
+        reads = paged_attention.step_reads(table, table[:, 0] != 0,
+                                           pos + k, pos + k + 1, bt)
+        assert int(report[-1]) == int(reads.fetched()) * cfg.n_layers
+        got = (jnp.argmax(g_logits, -1).astype(jnp.int32), g_pool)
+        want = (jnp.argmax(w_logits, -1).astype(jnp.int32), w_pool)
+        np.testing.assert_array_equal(np.asarray(got[0])[live],
+                                      np.asarray(want[0])[live])
+    # Block 0 is the scratch block, which the slot that does not decode
+    # writes with whatever its row sampled.
+    for name in ("c_kv", "k_r"):
+        np.testing.assert_array_equal(got[1][name][0, 1:],
+                                      want[1][name][0, 1:])
+    np.testing.assert_allclose(got[1]["c_kv"][:, 1:],
+                               want[1]["c_kv"][:, 1:], rtol=1e-5, atol=1e-5)
+
+
+def test_only_the_decode_step_reads_through_the_kernel(monkeypatch):
+    """Which attention each program traces, by the shapes alone: the
+    1-token step calls ``stpu_paged_mla_attn`` once a scanned stack and
+    never the tile loop; a prefill chunk and a verify window call the
+    tile loop and never the kernel."""
+    cfg = _tiny()
+    params = jax.eval_shape(lambda: deepseek.init(cfg, jax.random.key(0)))
+    pool = jax.eval_shape(lambda: deepseek.init_paged_cache(cfg, 32, 8))
+    calls = {"kernel": 0, "loop": 0}
+
+    def spy(name, fn):
+        def wrapped(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return wrapped
+
+    monkeypatch.setattr(deepseek.paged_mla_attention, "attend",
+                        spy("kernel", deepseek.paged_mla_attention.attend))
+    monkeypatch.setattr(deepseek, "_attend", spy("loop", deepseek._attend))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    programs = {
+        "step": (lambda p, c, toks, table, pos:
+                 deepseek.forward_with_paged_cache(
+                     cfg, p, toks, c, table, pos, window=16),
+                 (i32(3, 1), i32(3, 8), i32(3))),
+        "chunk": (lambda p, c, toks, table, pos, block:
+                  deepseek.forward_with_paged_cache(
+                      cfg, p, toks, c, table, pos, window=16,
+                      write_block=block),
+                  (i32(1, 8), i32(1, 8), i32(), i32())),
+        "verify": (lambda p, c, toks, table, pos, spec:
+                   deepseek.verify_step_paged(cfg, p, toks, c, table, pos,
+                                              spec, window=16),
+                   (i32(3, 4), i32(3, 8), i32(3), i32(3))),
+    }
+    for name, (program, args) in programs.items():
+        calls.update(kernel=0, loop=0)
+        jax.eval_shape(program, params, pool, *args)
+        assert calls == ({"kernel": 2, "loop": 0} if name == "step"
+                         else {"kernel": 0, "loop": 2}), name
 
 
 # -------------------------------------------------------------- routing
@@ -383,7 +491,8 @@ def test_a_row_that_does_not_count_never_makes_an_expert_computed():
     pos = jnp.asarray([3, 5], jnp.int32)
     for seed in range(40):
         toks = jnp.asarray(_tokens(2, seed=seed))[:, None]
-        want, _, (chosen, computed) = step(params, toks, pool, both, pos)
+        want, _, (chosen, computed, _) = step(params, toks, pool, both,
+                                              pos)
         chosen = np.asarray(chosen)[:, 0]             # (row, layer, held)
         np.testing.assert_array_equal(computed, chosen.any(axis=0))
         if (chosen[1] & ~chosen[0]).any() and chosen[0].any():
@@ -393,8 +502,8 @@ def test_a_row_that_does_not_count_never_makes_an_expert_computed():
     only_row0 = both.at[1].set(0)
     poisoned = {**params, "moe_layers": _poisoned(params["moe_layers"],
                                                   chosen[0])}
-    got, _, (got_chosen, computed) = step(poisoned, toks, pool, only_row0,
-                                          pos)
+    got, _, (got_chosen, computed, _) = step(poisoned, toks, pool,
+                                             only_row0, pos)
     np.testing.assert_array_equal(np.asarray(got_chosen)[0, 0], chosen[0])
     np.testing.assert_array_equal(computed, chosen[0])
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
@@ -499,7 +608,9 @@ def test_routing_counters_move_by_what_the_batch_chose():
     ran over, grows as ``stpu_moe_experts_hit_total`` does — in this
     step, and over the rest of a served run in which a third request
     prefills in four chunks beside the decoding two and slots retire
-    at different steps."""
+    at different steps. ``stpu_attn_blocks_read_total`` grows by the
+    blocks the step's attention kernel fetched, the last of its
+    report."""
     cfg = _tiny()
     params = deepseek.init(cfg, jax.random.key(0))
     engine = DecodeEngine(cfg, params, slots=3, max_seq=64,
@@ -520,16 +631,20 @@ def test_routing_counters_move_by_what_the_batch_chose():
         for i in live:
             engine._ensure_block(i, engine._slots[i].pos // 8)
         # What this step's forward chooses, on a copy of the pool.
-        _, _, (chosen, computed) = deepseek.forward_with_paged_cache(
+        _, _, (chosen, computed, blocks) = deepseek.forward_with_paged_cache(
             cfg, params, toks[:, None],
             jax.tree.map(jnp.copy, engine._cache),
             engine._step_table(live), pos, window=engine._window)
         chosen = np.asarray(chosen)[live, 0]      # (live, layers, held)
         np.testing.assert_array_equal(computed, chosen.any(axis=0))
+        # Two slots at positions 5 and 7: one entry of two blocks each,
+        # in each of the three layers.
+        assert int(blocks) == 2 * 2 * cfg.n_layers
         names = ("stpu_moe_tokens_routed_total",
                  "stpu_moe_experts_hit_total",
                  "stpu_moe_experts_computed_total")
         routed0, hit0, computed0 = map(_counter, names)
+        blocks0 = _counter("stpu_attn_blocks_read_total")
         assert engine._decode_step() == 2
         # The step's tokens and routing are read one iteration later,
         # and the step dispatched then is not in the counters yet.
@@ -541,6 +656,7 @@ def test_routing_counters_move_by_what_the_batch_chose():
             chosen.any(axis=0).sum()
         assert _counter("stpu_moe_experts_computed_total") - computed0 \
             == chosen.any(axis=0).sum()
+        assert _counter("stpu_attn_blocks_read_total") - blocks0 == blocks
         assert chosen.sum() > 0
         engine.submit(list(map(int, _tokens(30, seed=30))), max_tokens=6)
         for _ in range(60):

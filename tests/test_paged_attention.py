@@ -1,8 +1,11 @@
-"""The decode step's paged attention kernel (ops/pallas/paged_attention.py,
-interpret mode on the CPU) against the one-pass form it replaces in the
-step, ``phi4flash._attend`` over ``phi4flash._gather``'s blocks, at tiny
-widths (2 key/value pairs of 32 lanes) and the served geometry (blocks of
-64 tokens, a window of 512, a table of 20 blocks a kind)."""
+"""The decode steps' paged attention kernels (interpret mode on the CPU)
+against the forms they replace in the step, at tiny widths and the served
+geometry (blocks of 64 tokens, a table of 20 blocks): phi4flash's
+(ops/pallas/paged_attention.py: 2 key/value pairs of 32 lanes, a window
+of 512) against ``phi4flash._attend`` over ``phi4flash._gather``'s
+blocks, and deepseek's (ops/pallas/paged_mla_attention.py: 4 heads over
+latent rows of 32 and a roped key of 8) against the absorbed tile loop
+``deepseek._attend``."""
 import dataclasses
 
 import jax
@@ -10,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from skypilot_tpu.models import phi4flash
-from skypilot_tpu.ops.pallas import paged_attention
+from skypilot_tpu.models import deepseek, phi4flash
+from skypilot_tpu.ops.pallas import paged_attention, paged_mla_attention
 
 BT, SPAN, WINDOW = 64, 20, 512
 CFG = dataclasses.replace(phi4flash.Phi4FlashConfig.tiny(),
@@ -117,4 +120,93 @@ def test_the_kernel_equals_the_one_pass_form_on_the_blocks_it_reads(
             assert not got[i].any()
         else:
             assert np.abs(want[i]).max() > 1e-2
-            assert np.abs(got[i] - want[i]).max() < TOL[dtype], (i, p)
+            err = np.abs(got[i] - want[i]).max() / np.abs(want[i]).max()
+            assert err < MLA_TOL[dtype], (i, p, err)
+
+
+# ------------------------------------------------- deepseek's latent rows
+# nope = v = the latent's 32: W_UK and W_UV as identities make the tile
+# loop's output the softmax-weighted latent rows the kernel returns.
+MLA = dataclasses.replace(deepseek.DeepseekV3Config.tiny(),
+                          qk_nope_head_dim=32, v_head_dim=32)
+# A slot's first block (0, 63), a second that starts (64, 65), an odd
+# count (130: three blocks), the table's end (1279), one slot that does
+# not decode.
+MLA_POSITIONS = [0, 63, None, 64, 65, 130, 1279]
+# Relative to a slot's largest output. Float32: the two forms' sums in
+# another order (3e-7 seen). Bfloat16: both round the output once, from
+# float32 values that differ by the kernel's rounding of the
+# probabilities: an ulp or two of the output (2^-8 of its size).
+MLA_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2.0 ** -7}
+
+
+def _latent_pools(dtype, key, table, layer, blocks=80):
+    """Two layers of seeded latent rows and roped keys (the pool's planar
+    bytes). In the copy the kernel reads, every block that no decoding
+    slot of layer ``layer`` lists is NaN, the other layer's too (bytes
+    0xff make a NaN of either width)."""
+    shape = (2, blocks, BT)
+    kc, kr = jax.random.split(key)
+    c = jax.random.normal(kc, shape + (MLA.kv_lora_rank,),
+                          jnp.float32).astype(dtype)
+    k = deepseek._to_bytes(jax.random.normal(
+        kr, shape + (MLA.qk_rope_head_dim,), jnp.float32).astype(dtype))
+    listed = np.zeros((2, blocks), bool)
+    listed[layer, table[table[:, 0] > 0]] = True
+    listed = jnp.asarray(listed)[..., None, None]
+    return (c, k), (jnp.where(listed, c, jnp.nan),
+                    jnp.where(listed, k, jnp.uint8(0xff)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_mla_kernel_equals_the_tile_loop_on_the_blocks_it_reads(dtype):
+    positions = MLA_POSITIONS
+    b, layer = len(positions), 1
+    table = _table(positions, False)
+    live = jnp.asarray([p is not None for p in positions])
+    pos = jnp.asarray([p or 0 for p in positions], jnp.int32)
+    (c, k), (c_bad, k_bad) = _latent_pools(dtype, jax.random.key(3),
+                                           table, layer)
+    heads, rank = MLA.n_heads, MLA.kv_lora_rank
+    kq, kr = jax.random.split(jax.random.key(11))
+    q_latent = jax.random.normal(kq, (b, 1, heads, rank),
+                                 jnp.float32).astype(dtype)
+    q_rope = jax.random.normal(kr, (b, 1, heads, MLA.qk_rope_head_dim),
+                               jnp.float32).astype(dtype)
+    scale = deepseek.softmax_scale(MLA)
+
+    reads = paged_attention.step_reads(jnp.asarray(table), live, pos,
+                                       pos + 1, BT)
+    got = np.asarray(jax.jit(
+        lambda ql, qr, c, k, reads: paged_mla_attention.attend(
+            ql[:, 0], qr[:, 0], c, k, jnp.int32(layer), reads, scale))(
+        q_latent, q_rope, c_bad, k_bad, reads), np.float32)
+    blocks = [0 if p is None else p // BT + 1 for p in positions]
+    assert int(reads.fetched()) == sum(-(-n // 2) * 2 for n in blocks)
+
+    eye = jnp.broadcast_to(jnp.eye(rank, dtype=dtype), (heads, rank, rank))
+    window = 4 * BT
+
+    def tiles(s0):
+        phys = jax.lax.dynamic_slice(jnp.asarray(table), (0, s0 // BT),
+                                     (b, window // BT))
+        kpos = s0 + jnp.arange(window)
+        msk = kpos[None, None, :] <= pos[:, None, None]
+        return (c[layer, phys].reshape(b, window, -1),
+                deepseek._from_bytes(k[layer, phys].reshape(b, window, -1),
+                                     dtype), msk)
+
+    want = np.asarray(deepseek._attend(
+        MLA, q_latent, q_rope, {"w_uk": eye, "w_uv": eye}, tiles,
+        jnp.int32(SPAN * BT), absorbed=True), np.float32).reshape(
+            b, heads, rank)
+    assert got.shape == want.shape
+    assert np.isfinite(got).all()          # no block outside the list
+    for i, p in enumerate(positions):
+        if p is None:
+            assert not got[i].any()
+        else:
+            assert np.abs(want[i]).max() > 1e-2
+            err = np.abs(got[i] - want[i]).max() / np.abs(want[i]).max()
+            assert err < MLA_TOL[dtype], (i, p, err)

@@ -168,8 +168,9 @@ def _compile_paged(topo, family, entry, *, llama_layers=2,
     """One of the engine's three paged programs compiled for one
     described chip at a benchmark cell's widths and slots (Mistral-7B
     at ``llama_layers``, Mixtral-8x7B at the cell's four, DeepSeek-V3's
-    five), 20 blocks of 64 rows a slot. Returns (compiled, params,
-    pool), the last two as shapes."""
+    five), 20 blocks of 64 rows a slot, and the block table as wide as
+    the engine makes it (one column a pool block). Returns (compiled,
+    params, pool), the last two as shapes."""
     if family == "deepseek":
         model, slots = deepseek, 64
         cfg = deepseek.DeepseekV3Config.v3_5l_ep16()
@@ -198,7 +199,10 @@ def _compile_paged(topo, family, entry, *, llama_layers=2,
     params = on_chip(jax.eval_shape(weights))
     pool = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
         cfg, slots * max_seq // bt + 1, bt, quantized=kv_quant)))
-    i32, table_len = jnp.int32, max_seq // bt
+    i32 = jnp.int32
+    table_len = decode_engine.resolve_kv_geometry(
+        slots=slots, max_seq=max_seq, prefill_chunk=bt,
+        use_manifest=False)["table_len"]
     sampling = (arg(jnp.float32, slots), arg(jnp.uint32, slots))
     args = {
         "_paged_step": (arg(i32, slots), arg(i32, slots),
@@ -244,20 +248,34 @@ def test_paged_programs_keep_the_pool_in_place_on_v5e(topo, for_the_chip,
     ({4,2,3,1,0}: the projection's output, bitcast) and converted the
     whole pool back for the attention's gather in every layer. Written
     as a row scatter, like the decode step's, the pool keeps the
-    layout it arrived in."""
+    layout it arrived in.
+
+    DeepSeek's decode step attends through ``stpu_paged_mla_attn``
+    (PERF.md), which copies the decoding slots' blocks out of the pool
+    where they lie: the step holds the kernel and no tile loop under
+    ``stpu.mla`` over tiles gathered for all 64 slots (``[64,256,…]``,
+    4-5 of them a layer at the cell's lengths); the chunk holds no such
+    call and keeps its loop."""
     compiled, _, pool = _compile_paged(topo, family, entry,
                                        kv_quant=quantized)
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pool))
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.25 * pool_bytes, (temp, pool_bytes)
+    text = compiled.as_text()
     # Rows of tokens, not the int8 pool's scale a block and head.
     for leaf in (a for a in pool.values() if a.ndim > 3):
         dims = ",".join(map(str, leaf.shape))
         copies = re.findall(
             rf"^\s*(?:ROOT )?(\S+ = \w+\[{dims}\]\S* copy)\(",
-            compiled.as_text(), re.M)
+            text, re.M)
         assert not copies, copies
+    if family == "deepseek":
+        step = entry == "_paged_step"
+        loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+        assert ("stpu_paged_mla_attn" in text) == step
+        assert any("stpu.mla/while" in name for name in loops) != step
+        assert not step or not re.search(r"\[64,256,", text)
 
 
 def _standing_alone(text, shapes):
